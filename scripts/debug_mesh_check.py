@@ -8,7 +8,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.compat import set_mesh
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ShapeConfig
 from repro.launch import steps as STEPS
@@ -38,7 +37,7 @@ for multi_pod in (False, True):
                 else:
                     fn, args, _ = STEPS.build_decode_step(
                         cfg, mesh, shape, multi_pod=multi_pod)
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     compiled = fn.lower(*args).compile()
                 print(f"OK  {tag}  ({time.time()-t0:.1f}s)", flush=True)
             except Exception as e:

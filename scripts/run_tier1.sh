@@ -103,14 +103,14 @@ EOF
         python -m benchmarks.serve_bench --smoke
     echo "=== smoke: observability (traced sim -> report CLI, overhead gate) ==="
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python - <<'EOF'
-import json, subprocess, sys, tempfile
+import contextlib, io, json, tempfile
 from pathlib import Path
 
 from repro.core import FedSTIL
 from repro.core.edge_model import EdgeModelConfig
 from repro.data import FederatedReIDBenchmark
 from repro.federated import run_simulation
-from repro.obs.report import summarize
+from repro.obs.report import main as report_main, summarize
 from repro.obs.trace import RunLog
 
 out = Path(tempfile.mkdtemp()) / "obs_run.jsonl"
@@ -126,11 +126,12 @@ assert s["events"]["spans"] > 0, "traced sim recorded no spans"
 assert "round.server" in s["phases"], sorted(s["phases"])
 assert "server.relevance" in s["stages"], sorted(s["stages"])
 assert isinstance(s["clients"].get("staleness"), list), s["clients"]
-# the report CLI must parse the same JSONL end-to-end
-cli = subprocess.run(
-    [sys.executable, "-m", "repro.obs.report", str(out), "--json"],
-    capture_output=True, text=True, check=True)
-parsed = json.loads(cli.stdout)
+# the report CLI must parse the same JSONL end-to-end; it runs in this
+# process (a child would be a second process that may reach for the chip)
+buf = io.StringIO()
+with contextlib.redirect_stdout(buf):
+    assert report_main([str(out), "--json"]) == 0
+parsed = json.loads(buf.getvalue())
 assert parsed["events"] == s["events"]
 print(f"obs smoke OK: {s['events']['spans']} spans, "
       f"{s['events']['metrics']} metrics, report CLI parses")

@@ -15,7 +15,7 @@ tracing, no data, no execution) and returns ``Finding`` records:
   * ``host-transfer``   — ``device_put`` inside a loop body.
   * ``undonated-carry`` — a declared round-carried input the program does
     not donate: at C ≫ 1000 the stacked (C, ...) state doubles in memory
-    every round. Checked against the declaration AND the traced pjit's
+    every round. Checked against the declaration AND the traced jit's
     ``donated_invars``.
   * ``dead-code``       — equations whose outputs never reach a program
     output (XLA DCEs them, but they are trace/compile churn and usually
@@ -32,7 +32,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Iterator, List, Optional, Tuple
 
-from jax import core
+from jax.core import DropVar
+from jax.extend import core
 
 from repro.analysis.registry import ProgramSpec
 from repro.sharding.analysis import aval_bytes
@@ -189,11 +190,11 @@ def lint_donation(spec: ProgramSpec,
                    f"(memory doubles at C >> 1000)")
            for i in spec.carry if i not in spec.donate]
     if spec.donate and closed is not None:
-        # the traced pjit records donation per flattened invar — if the
+        # the traced jit eqn records donation per flattened invar — if the
         # registered callable is the production jit, this is ground truth
-        pjits = [e for e in closed.jaxpr.eqns if e.primitive.name == "pjit"]
-        if len(pjits) == 1 and not any(pjits[0].params.get("donated_invars",
-                                                           ())):
+        jits = [e for e in closed.jaxpr.eqns if e.primitive.name == "jit"]
+        if len(jits) == 1 and not any(jits[0].params.get("donated_invars",
+                                                         ())):
             out.append(Finding(
                 "undonated-carry", spec.name,
                 f"declares donate={spec.donate} but the traced jit has no "
@@ -207,7 +208,7 @@ def _dead_eqns(jaxpr: core.Jaxpr):
     live = {v for v in jaxpr.outvars if isinstance(v, core.Var)}
     dead = []
     for eqn in reversed(jaxpr.eqns):
-        outs = [v for v in eqn.outvars if not isinstance(v, core.DropVar)]
+        outs = [v for v in eqn.outvars if not isinstance(v, DropVar)]
         if eqn.effects or any(v in live for v in outs):
             for v in eqn.invars:
                 if isinstance(v, core.Var):
@@ -258,7 +259,7 @@ def peak_bytes_estimate(jaxpr: core.Jaxpr) -> int:
                         for s in _sub_jaxprs(eqn)),
                        default=0)
         for v in eqn.outvars:
-            if not isinstance(v, core.DropVar):
+            if not isinstance(v, DropVar):
                 alive[v] = _nbytes(v.aval)
         peak = max(peak, sum(alive.values()) + sub_peak)
         for v in [v for v, last in last_use.items() if last == i]:

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.registry import register_runtime
+from repro.sharding.specs import engine_mesh
 
 _SDS = jax.ShapeDtypeStruct
 _F32 = jnp.float32
@@ -100,7 +101,7 @@ def _register_fedstil() -> None:
     # boundary in wire_dtype: the f32->bf16->f32 pair is the sanctioned
     # wire cast of common/precision.py, not convert churn.
     from repro.common.precision import WIRE_CASTS
-    strat.mesh = jax.make_mesh((1, 1), ("data", "model"))
+    strat.mesh = engine_mesh(jax.devices()[:1])
     flatten_wire, aggregate = strat._sharded_server_fns(theta_example)
 
     def sharded_server_round(buf, valid, stale, feats, mask, theta):
@@ -116,7 +117,10 @@ def _register_fedstil() -> None:
             ring_args + (_stretch(_sds_like(theta_example)),), {}),
         module="repro.core.fedstil",
         oracle="repro.core.fedstil.FedSTIL.server_round",
-        carry=(0, 1, 2), donate=(0, 1, 2), budget_bytes=128 << 20,
+        # 32 MiB over the stacked round: the shard_map'd aggregate holds
+        # the all-gathered (Cp, P) f32 Θ next to the shard's input block
+        # (23 MB at C=100); estimated 139 MB
+        carry=(0, 1, 2), donate=(0, 1, 2), budget_bytes=160 << 20,
         sanctioned_casts=WIRE_CASTS)
 
     epochs, batch = strat.epochs, strat.batch
@@ -181,7 +185,7 @@ def _register_sharded() -> None:
     from repro.core.fedstil import sharded_fused_aggregate
     from repro.federated.base import sharded_eval_fn
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = engine_mesh(jax.devices()[:1])
     register_runtime(
         "federated.sharded_aggregate",
         functools.partial(sharded_fused_aggregate, mesh=mesh),

@@ -21,17 +21,22 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.comm.codec import PipelineCodec
+from repro.common import compat
+from repro.common.precision import sum_of_squares
 from repro.kernels import ops
 
 
 class BatchedCodec:
     """One direction's (C, P) encode/decode program, built from the host
-    codec's stage parameters. Stateful only when delta is on (device ref)."""
+    codec's stage parameters. Stateful only when delta is on (device ref).
+    ``mesh``: the sharded engine's mesh — the programs then run per
+    shard of payload rows."""
 
     def __init__(self, like: PipelineCodec, p: int, *,
-                 backend: Optional[str] = None):
+                 backend: Optional[str] = None, mesh=None):
         if like.topk and like.group is None:
             raise ValueError(
                 "BatchedCodec needs the grouped top-k stage (group=N); "
@@ -53,6 +58,17 @@ class BatchedCodec:
         chunk, quant, topk = self.chunk, self.quant, self.topk
         group, kg = self.group, self.kg
 
+        def program(fn):
+            """jit ``fn``; on a mesh (the sharded engine, payload rows on
+            "data") run it per shard inside ``shard_map`` — every stage
+            is row-local, and a Pallas call cannot be partitioned by the
+            compiler."""
+            if mesh is None:
+                return jax.jit(fn)
+            rows = P("data")
+            return jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=rows,
+                                            out_specs=rows, check_vma=False))
+
         def _quant(vals, buffers):
             if quant == "int8":
                 q, scales = ops.batched_quantize(vals, chunk=chunk,
@@ -72,15 +88,17 @@ class BatchedCodec:
         # (decoder-reference staleness — grows as the ref drifts), the
         # fraction of residual energy the wire kept, and the effective
         # keep-rate. Tiny (C,) outputs of a program that already runs; the
-        # host only reads them back when a tracer is active.
+        # host only reads them back when a tracer is active. The sums run
+        # in a fixed order, so a row's metrics do not depend on how many
+        # rows the program holds (sharded and stacked runs compare equal).
         def _enc_metrics(x, vals):
-            r2 = jnp.sum(jnp.square(x), axis=1)
-            k2 = jnp.sum(jnp.square(vals), axis=1)
+            r2 = sum_of_squares(x, 1)
+            k2 = sum_of_squares(vals, 1)
             return {"residual_norm": jnp.sqrt(r2),
                     "kept_energy": k2 / jnp.maximum(r2, 1e-12),
                     "keep_rate": jnp.sum(vals != 0, axis=1) / pp}
 
-        @jax.jit
+        @program
         def _enc_sparse(x):
             vals, idx = ops.batched_topk_pack(x, group=group, kg=kg,
                                               backend=backend)
@@ -88,7 +106,7 @@ class BatchedCodec:
                                              backend=backend)
             return _quant(vals, {"idx_bits": packed}), _enc_metrics(x, vals)
 
-        @jax.jit
+        @program
         def _enc_dense(x):
             x = x.astype(jnp.float32)
             return _quant(x, {}), _enc_metrics(x, x)
@@ -100,7 +118,7 @@ class BatchedCodec:
                                               chunk=chunk, backend=backend)
             return v.astype(jnp.float32)
 
-        @jax.jit
+        @program
         def _dec_sparse(buffers):
             idx = ops.batched_idx_bitunpack(buffers["idx_bits"], k=kk,
                                             group=group, kg=kg,
@@ -109,7 +127,7 @@ class BatchedCodec:
                                            group=group, kg=kg,
                                            backend=backend)
 
-        @jax.jit
+        @program
         def _dec_dense(buffers):
             return _dequant(buffers)
 

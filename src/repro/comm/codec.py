@@ -43,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.precision import INV127
+
 DEFAULT_KEEP_FRAC = 0.35
 DEFAULT_CHUNK = 256
 DEFAULT_GROUP = 8
@@ -176,7 +178,7 @@ def quantize_host(v: np.ndarray, chunk: int) -> Tuple[np.ndarray, np.ndarray]:
     vp[:n] = v
     vc = vp.reshape(nc, chunk)
     absmax = np.max(np.abs(vc), axis=1, keepdims=True)
-    scale = (absmax / 127.0).astype(np.float32)
+    scale = (absmax * np.float32(INV127)).astype(np.float32)
     scale = np.where(scale > 0, scale, np.float32(1.0))   # 0 / subnormal
     q = np.clip(np.rint(vc / scale), -127.0, 127.0).astype(np.int8)
     return q.reshape(-1)[:n], scale[:, 0]

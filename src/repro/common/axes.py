@@ -13,7 +13,7 @@ from typing import Optional
 
 from jax import lax
 
-from repro.common.compat import axis_size, pcast_varying
+from repro.common.compat import pcast_varying
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,11 +31,11 @@ class AxisCtx:
 
     @property
     def tp_size(self) -> int:
-        return axis_size(self.tp) if self.tp else 1
+        return lax.axis_size(self.tp) if self.tp else 1
 
     @property
     def dp_size(self) -> int:
-        return axis_size(self.dp) if self.dp else 1
+        return lax.axis_size(self.dp) if self.dp else 1
 
     def tp_index(self):
         return lax.axis_index(self.tp) if self.tp else 0

@@ -1,86 +1,39 @@
-"""JAX version-compat shims.
+"""Small helpers over the JAX sharding API (JAX 0.9).
 
-The launch stack targets the modern public API (``jax.shard_map``,
-``jax.set_mesh``); on 0.4.x those live under ``jax.experimental`` (with a
-``check_rep`` kwarg instead of ``check_vma``) or do not exist at all. Every
-call site imports from here so one module owns the version probing.
+Every ``shard_map`` and varying-cast call site goes through here, so the
+one place that knows the API's defaults and sharp edges is this module.
 """
 from __future__ import annotations
 
-import contextlib
-import inspect
-
 import jax
-
-
-def resolve_shard_map(mod=jax):
-    """Return the shard_map callable for a given jax module layout.
-
-    New layout: ``mod.shard_map``. Old layout (<= 0.4.x): fall back to
-    ``jax.experimental.shard_map.shard_map``.
-    """
-    fn = getattr(mod, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as fn
-    return fn
-
-
-def adapt_check_kwarg(param_names, check_vma):
-    """Map the modern ``check_vma`` kwarg onto whatever the resolved
-    shard_map accepts. None -> library default on the new layout. On 0.4.x
-    the replication checker predates the vma type system and rejects valid
-    gradient programs (psum-transposed grads of replicated params infer as
-    unreplicated), while transposes are correct with or without it — so
-    ``check_rep`` is always disabled there."""
-    if "check_vma" in param_names:
-        return {} if check_vma is None else {"check_vma": check_vma}
-    if "check_rep" in param_names:
-        return {"check_rep": False}
-    return {}
-
-
-_SHARD_MAP = resolve_shard_map()
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(_SHARD_MAP).parameters)
+from jax import lax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` on any supported JAX version."""
-    kwargs.update(adapt_check_kwarg(_SHARD_MAP_PARAMS, check_vma))
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    """``jax.shard_map``; ``check_vma=None`` keeps the library default.
 
-
-def set_mesh(mesh):
-    """Mesh context manager: ``jax.set_mesh`` / ``jax.sharding.use_mesh``
-    where available. On 0.4.x shard_map takes the mesh explicitly and jit
-    reshards uncommitted inputs itself, so a null context is sufficient."""
-    setter = getattr(jax, "set_mesh", None)
-    if setter is None:
-        setter = getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return contextlib.nullcontext(mesh)
-
-
-def axis_size(name):
-    """``lax.axis_size`` fallback: psum of a unit constant is folded to the
-    static axis size on versions that predate the public helper."""
-    from jax import lax
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(name)
-    return lax.psum(1, name)
+    Bodies that call Pallas kernels pass ``check_vma=False``: under the
+    check, ``pallas_call`` wants every output shape annotated with how it
+    varies over the mesh, which the kernels (written per device) do not
+    carry."""
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def pcast_varying(x, axes):
-    """``lax.pcast(..., to="varying")`` where vma typing exists; identity on
-    0.4.x, whose shard_map (check_rep) has no varying-mark requirement."""
-    from jax import lax
-    fn = getattr(lax, "pcast", None)
-    if fn is None:
-        return x
-    return jax.tree.map(lambda l: fn(l, axes, to="varying"), x)
+    """Mark every leaf of ``x`` device-varying over ``axes``: the scan
+    carries and literals that shard_map's vma typing needs to see as
+    varying. Leaves already varying over an axis are left alone on that
+    axis (``lax.pcast`` rejects a varying -> varying cast)."""
+    axes = (axes,) if isinstance(axes, str) else tuple(axes)
+
+    def cast(leaf):
+        todo = tuple(a for a in axes if a not in jax.typeof(leaf).vma)
+        return lax.pcast(leaf, todo, to="varying") if todo else leaf
+
+    return jax.tree.map(cast, x)
 
 
 def default_interpret() -> bool:

@@ -17,8 +17,17 @@ convert-churn lint knows it is a wire cast, not churn.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+# int8 scales are absmax * (1/127) in every implementation (kernel, jnp
+# oracle, numpy host codec): XLA rewrites a division by a constant into a
+# multiply by its reciprocal, so "absmax / 127" would round differently on
+# device and in numpy
+INV127 = 1.0 / 127.0
 
 # the (src, dst) convert pairs the analysis convert-churn lint accepts in
 # programs that declare them: the wire cast down and its matching upcast
@@ -39,3 +48,62 @@ def to_bf16(tree):
 def to_f32(tree):
     """Cast every floating leaf to float32 (state / accumulate form)."""
     return jax.tree.map(lambda x: _cast_floating(x, jnp.float32), tree)
+
+
+def pairwise_sum(x, axis: int, *, keepdims: bool = False):
+    """Sum over ``axis`` in one fixed order, on numpy and jax arrays alike.
+
+    The axis is zero-padded to a power of two and its two halves are added
+    until one slice is left. A backend reduction (``jnp.sum``) picks its
+    own association order — XLA's CPU order changed between JAX releases
+    and never matched numpy's — while elementwise float adds are exact
+    IEEE ops that XLA does not reassociate. So a program and its numpy
+    oracle that both reduce through here agree bit for bit on any backend.
+    """
+    xp = np if isinstance(x, np.ndarray) else jnp
+    x = xp.moveaxis(x, axis, 0)
+    n = x.shape[0]
+    m = 1 << max(n - 1, 0).bit_length()
+    if m > n:
+        x = xp.concatenate([x, xp.zeros((m - n,) + x.shape[1:], x.dtype)])
+    while m > 1:
+        m //= 2
+        x = x[:m] + x[m:]
+    out = x[0]
+    return xp.expand_dims(out, axis) if keepdims else out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def broadcast_rows(v, n: int):
+    """``v`` repeated as ``n`` leading rows, (d...) -> (n, d...), whose
+    cotangent is summed back over the rows by ``pairwise_sum``.
+
+    Autodiff would transpose the broadcast into a backend reduction, and
+    the TPU compiler lays that reduction out by the shape of the whole
+    program: a vmapped client step holding 100 clients and one holding 25
+    sum the same client's bias gradient in different orders. Through here
+    a client's gradient is the same at any client count — the sharded
+    engine's 25 clients per chip match the stacked engine's 100 on one."""
+    return jnp.broadcast_to(v, (n,) + v.shape)
+
+
+def _broadcast_rows_fwd(v, n):
+    return broadcast_rows(v, n), None
+
+
+def _broadcast_rows_bwd(n, _, g):
+    return (pairwise_sum(g, 0),)
+
+
+broadcast_rows.defvjp(_broadcast_rows_fwd, _broadcast_rows_bwd)
+
+
+def sum_of_squares(x, axis: int, *, keepdims: bool = False):
+    """``pairwise_sum`` of ``x ** 2``. On jax arrays the squares pass
+    through an identity ``maximum(., 0)``: a multiply fed straight into
+    the first add is contracted into an FMA by XLA's CPU backend, which
+    rounds once where numpy rounds twice."""
+    if isinstance(x, np.ndarray):
+        return pairwise_sum(np.square(x), axis, keepdims=keepdims)
+    return pairwise_sum(jnp.maximum(jnp.square(x), 0.0), axis,
+                        keepdims=keepdims)

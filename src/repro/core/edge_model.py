@@ -17,6 +17,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro.common.precision import broadcast_rows, pairwise_sum
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,26 +64,40 @@ def init_adaptive_layers(key, cfg: EdgeModelConfig):
     }
 
 
+# Every per-feature vector that meets a batch of rows (biases, BN
+# statistics and affine) does so through ``broadcast_rows``: its gradient
+# is then a ``pairwise_sum`` over the rows, whose order does not depend on
+# how many clients one vmapped train program holds.
+
+
 def adaptive_pre_bn(theta, protos):
     """The head up to (not including) BN: protos (N, D) -> (N, feat_dim)."""
-    h = jax.nn.relu(protos @ theta["l1"]["w"] + theta["l1"]["b"])
-    return h @ theta["l2"]["w"] + theta["l2"]["b"]
+    n = protos.shape[0]
+    h = jax.nn.relu(protos @ theta["l1"]["w"]
+                    + broadcast_rows(theta["l1"]["b"], n))
+    return h @ theta["l2"]["w"] + broadcast_rows(theta["l2"]["b"], n)
 
 
 def adaptive_bn_stats(f, mask):
     """BN statistics (mu, sd) of a pre-BN batch over ``mask``-valid rows
     only (zero-padded rows contribute nothing). f: (N, feat_dim);
-    mask: (N,) 1.0 = valid. Returns (feat_dim,) each."""
+    mask: (N,) 1.0 = valid. Returns (feat_dim,) each. The row sums run in
+    ``pairwise_sum``'s fixed order, which the serving index's numpy oracle
+    shares bit for bit."""
     m = mask.astype(f.dtype)[:, None]
     n = jnp.maximum(jnp.sum(m), 1.0)
-    mu = jnp.sum(f * m, 0) / n
-    sd = jnp.sqrt(jnp.sum(jnp.square(f - mu[None, :]) * m, 0) / n) + 1e-5
+    mu = pairwise_sum(f * m, 0) / n
+    dev = f - broadcast_rows(mu, f.shape[0])
+    sd = jnp.sqrt(pairwise_sum(jnp.square(dev) * m, 0) / n) + 1e-5
     return mu, sd
 
 
 def adaptive_bn_apply(theta, f, mu, sd):
     """BN affine with the given statistics: (N, feat_dim) -> features."""
-    return (f - mu) / sd * theta["bn"]["scale"] + theta["bn"]["bias"]
+    n = f.shape[0]
+    return ((f - broadcast_rows(mu, n)) / broadcast_rows(sd, n)
+            * broadcast_rows(theta["bn"]["scale"], n)
+            + broadcast_rows(theta["bn"]["bias"], n))
 
 
 def adaptive_forward_masked(theta, protos, mask):
@@ -113,8 +128,17 @@ def adaptive_forward(theta, protos):
         theta, protos, jnp.ones((protos.shape[0],), jnp.float32))
 
 
+def log_softmax_rows(z):
+    """``jax.nn.log_softmax`` over the classes of (N, K) logits, with the
+    class sums (forward and gradient) in ``pairwise_sum`` order."""
+    k = z.shape[1]
+    s = z - broadcast_rows(jax.lax.stop_gradient(jnp.max(z, axis=1)), k).T
+    lse = jnp.log(pairwise_sum(jnp.exp(s), 1))
+    return s - broadcast_rows(lse, k).T
+
+
 def ce_loss(theta, protos, labels):
     feats, logits = adaptive_forward(theta, protos)
-    logp = jax.nn.log_softmax(logits)
+    logp = log_softmax_rows(logits)
     nll = -jnp.take_along_axis(logp, labels[:, None], 1)[:, 0]
-    return jnp.mean(nll)
+    return pairwise_sum(nll, 0) / nll.shape[0]

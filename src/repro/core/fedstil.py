@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import compat
 from repro.common.precision import to_bf16, to_f32
 from repro.common.pytree import (tree_bytes, tree_flatten_stacked,
                                  tree_unflatten_stacked)
@@ -36,34 +37,41 @@ from repro.kernels import ops
 from repro.obs import trace as obs
 
 
-def sharded_fused_aggregate(w, thetas, mesh, *, backend=None):
-    """The engine's jit-with-NamedSharding Eq. 5→6 aggregate over a real
-    mesh — layouts come from ``sharding.specs.stacked_aggregate_specs``
-    (the single source of truth; the old ``launch/fed_round`` demo that
-    re-derived them privately is gone).
+def sharded_aggregate_fn(mesh, *, backend=None):
+    """The engine's Eq. 5→6 aggregate over a mesh, as one jitted
+    ``shard_map`` program: ``(W (Cp, Cp), Θ (Cp, P)) -> (B (Cp, P), Wn)``
+    with layouts from ``sharding.specs.stacked_aggregate_specs``.
 
-    Θ (C, P) rows live on the "data" axis, W contracts its columns against
-    them, and the output base matrix keeps the client-row sharding so the
-    per-device footprint stays C/d × P at every stage. GSPMD lowers the
-    contraction to per-shard partial products plus one reduce over "data"
-    (the relevance normalizer inside the kernel is the one psum). Values
-    are bit-identical to ``ops.fused_relevance_aggregate`` on one device —
-    tier-1 asserts it.
+    W, Θ, B and Wn all keep their client rows on "data". Each shard
+    all-gathers Θ over "data" (the collective Eq. 6 needs: every base row
+    mixes every client) and runs the fused Eq. 5→6 kernel on its own rows
+    of W — the rows a row block needs are all local, so every row of B and
+    Wn is computed exactly as the one-device kernel computes it. The
+    kernel runs per shard because a Pallas call cannot be partitioned by
+    the compiler: a jit over sharded operands would not compile on chips.
     """
-    from jax.sharding import NamedSharding
-    from repro.sharding.specs import stacked_aggregate_specs
-    sp = stacked_aggregate_specs()
     key = (mesh, backend)
     if key not in _SHARDED_AGG_CACHE:
-        _SHARDED_AGG_CACHE[key] = jax.jit(
-            functools.partial(ops.fused_relevance_aggregate,
-                              backend=backend),
-            out_shardings=(NamedSharding(mesh, sp["out"]),
-                           NamedSharding(mesh, sp["wn"])))
-    w = jax.device_put(jnp.asarray(w), NamedSharding(mesh, sp["w"]))
-    thetas = jax.device_put(jnp.asarray(thetas),
-                            NamedSharding(mesh, sp["thetas"]))
-    return _SHARDED_AGG_CACHE[key](w, thetas)
+        from repro.sharding.specs import stacked_aggregate_specs
+        sp = stacked_aggregate_specs()
+
+        def shard(w, theta):
+            row0 = jax.lax.axis_index("data") * w.shape[0]
+            theta_all = jax.lax.all_gather(theta, "data", tiled=True)
+            return ops.fused_relevance_aggregate(w, theta_all, row0,
+                                                 backend=backend)
+
+        _SHARDED_AGG_CACHE[key] = jax.jit(compat.shard_map(
+            shard, mesh=mesh, in_specs=(sp["w"], sp["thetas"]),
+            out_specs=(sp["out"], sp["wn"]), check_vma=False))
+    return _SHARDED_AGG_CACHE[key]
+
+
+def sharded_fused_aggregate(w, thetas, mesh, *, backend=None):
+    """Run ``sharded_aggregate_fn`` on (W, Θ): values match
+    ``ops.fused_relevance_aggregate`` on one device (tier-1 asserts it)."""
+    return sharded_aggregate_fn(mesh, backend=backend)(
+        jnp.asarray(w), jnp.asarray(thetas))
 
 
 _SHARDED_AGG_CACHE: dict = {}
@@ -276,6 +284,7 @@ class FedSTIL(Strategy):
                        else self.server_backend)
             ratio = self.tracker.forgetting_ratio
             metric = self.tracker.metric
+            mesh = self.mesh
 
             # the ring buffer/validity/staleness are the round-carried
             # server state: the caller overwrites all three with the
@@ -295,7 +304,7 @@ class FedSTIL(Strategy):
                 buf, valid, stale = _ring_push(buf, valid, stale, feats,
                                                mask)
                 W = ring_relevance(buf, valid, forgetting_ratio=ratio,
-                                   metric=metric, backend=backend)
+                                   metric=metric, backend=backend, mesh=mesh)
                 mets = obsm.relevance_metrics(W, valid, stale)
                 return buf, valid, stale, W, mets
 
@@ -312,38 +321,34 @@ class FedSTIL(Strategy):
     def _sharded_server_fns(self, theta_example):
         """engine="sharded" variants of the flatten/aggregate stages, built
         once against ``self.mesh``. The relevance stage is shared with the
-        stacked engine (jit re-specializes on the sharded ring). Deltas:
+        stacked engine (on a mesh it scores each shard's rows inside
+        ``shard_map``; see ``ring_relevance``). Deltas:
 
           * the flatten emits the wire form — ``to_bf16`` of the (Cp, P)
             matrix (``common/precision.py``): that buffer is what crosses
             the shard boundary into the aggregate, at half the bytes;
-          * the aggregate is one jit-with-NamedSharding program that
-            upcasts to f32 (``to_f32``), runs the fused Eq. 5→6 kernel,
-            and pins B to the client-row sharding from ``sharding.specs``
-            so the per-device footprint stays Cp/d × P. The f32→bf16→f32
-            pair is the sanctioned wire cast the analysis lints accept.
+          * the aggregate upcasts to f32 (``to_f32``) and runs
+            ``sharded_aggregate_fn``: one all-gather of Θ over "data",
+            then the fused Eq. 5→6 kernel per shard on its own W rows,
+            leaving B client-row sharded (Cp/d × P per device). The
+            f32→bf16→f32 pair is the sanctioned wire cast the analysis
+            lints accept.
         """
         if "sharded_aggregate" not in self._jit_cache:
-            from jax.sharding import NamedSharding
-            from repro.sharding.specs import stacked_aggregate_specs
             backend = (None if self.server_backend == "loop"
                        else self.server_backend)
-            sp = stacked_aggregate_specs()
             wire = self.wire_dtype
+            agg = sharded_aggregate_fn(self.mesh, backend=backend)
 
             def flatten_wire(th):
                 flat = tree_flatten_stacked(th)[0]
                 return to_bf16(flat) if wire == "bfloat16" else flat
 
             def aggregate(W, flat):
-                return ops.fused_relevance_aggregate(W, to_f32(flat),
-                                                     backend=backend)
+                return agg(W, to_f32(flat))
 
             self._jit_cache["sharded_flatten_wire"] = jax.jit(flatten_wire)
-            self._jit_cache["sharded_aggregate"] = jax.jit(
-                aggregate,
-                out_shardings=(NamedSharding(self.mesh, sp["out"]),
-                               NamedSharding(self.mesh, sp["wn"])))
+            self._jit_cache["sharded_aggregate"] = jax.jit(aggregate)
         return (self._jit_cache["sharded_flatten_wire"],
                 self._jit_cache["sharded_aggregate"])
 

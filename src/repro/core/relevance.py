@@ -89,17 +89,39 @@ def _ring_push(buf, valid, stale, feats, mask):
 
 
 def ring_relevance(buf, valid, *, forgetting_ratio: float, metric: str = "kl",
-                   backend: Optional[str] = None):
+                   backend: Optional[str] = None, mesh=None):
     """Unnormalized (C, C) decayed relevance over a ring-buffer history:
     each client's latest feature (age 0) vs every history, rows without a
     current feature zeroed. Diagonal NOT masked — the fused aggregate
     kernel owns that. jit-traceable; shared by ``DeviceRingHistory`` and
-    the stacked FedSTIL server program."""
-    k = buf.shape[1]
-    decay = forgetting_ratio ** jnp.arange(k, dtype=jnp.float32)
-    W = decayed_relevance(buf[:, 0], buf, decay, valid,
-                          metric=metric, backend=backend)
-    return W * valid[:, 0][:, None]
+    the stacked FedSTIL server program.
+
+    With a ``mesh`` (the sharded engine, ring rows on "data") the rows are
+    computed per shard inside ``shard_map``: each shard all-gathers the
+    (small) histories and scores its own clients' current features
+    against them, so the similarity kernel runs on local blocks. W comes
+    back row-sharded."""
+    def rows(cur_buf, cur_valid, hist, hvalid):
+        k = hist.shape[1]
+        decay = forgetting_ratio ** jnp.arange(k, dtype=jnp.float32)
+        W = decayed_relevance(cur_buf[:, 0], hist, decay, hvalid,
+                              metric=metric, backend=backend)
+        return W * cur_valid[:, 0][:, None]
+
+    if mesh is None:
+        return rows(buf, valid, buf, valid)
+
+    from jax.sharding import PartitionSpec as P
+
+    from repro.common import compat
+
+    def shard(b, v):
+        return rows(b, v, jax.lax.all_gather(b, "data", tiled=True),
+                    jax.lax.all_gather(v, "data", tiled=True))
+
+    return compat.shard_map(
+        shard, mesh=mesh, in_specs=(P("data"), P("data")),
+        out_specs=P("data"), check_vma=False)(buf, valid)
 
 
 @dataclasses.dataclass

@@ -44,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.registry import register_program
+from repro.common.precision import pairwise_sum, sum_of_squares
 from repro.evalreid.retrieval import evaluate_retrieval
 from repro.kernels import ops
 
@@ -53,7 +54,9 @@ _PAD_QID = -2
 
 
 def _l2n(x, eps=1e-9):
-    n = jnp.linalg.norm(x, axis=-1, keepdims=True)
+    # fixed-order norm: a backend reduction would round a client's norms
+    # by the layout the whole (C, ...) program gets, i.e. by C
+    n = jnp.sqrt(sum_of_squares(x, -1, keepdims=True))
     return x / jnp.maximum(n, eps)
 
 
@@ -132,16 +135,17 @@ def batched_retrieval_metrics(qf, qids, gf, gids, *, qmask=None, gmask=None,
                  & (gdx < midx[..., None])))
     r = 1.0 + jnp.sum(before.astype(jnp.float32), -1)        # (C, T, Q, M)
 
-    # AP = mean over matches of (position among matches) / (full rank)
+    # AP = mean over matches of (position among matches) / (full rank);
+    # the float sums run in pairwise_sum order (counts above are exact)
     pos = jnp.arange(1, M + 1, dtype=jnp.float32)
-    ap = (jnp.sum(jnp.where(mvalid, pos / r, 0.0), -1)
+    ap = (pairwise_sum(jnp.where(mvalid, pos / r, 0.0), -1)
           / jnp.maximum(n_match, 1.0))                       # (C, T, Q)
 
     valid = n_match > 0
     vf = valid.astype(jnp.float32)
     cnt = jnp.maximum(jnp.sum(vf, -1), 1.0)                  # (C, T)
     best = r[..., 0]                                         # best match rank
-    out = {"mAP": jnp.sum(ap * vf, -1) / cnt}
+    out = {"mAP": pairwise_sum(ap * vf, -1) / cnt}
     for k in ranks:
         hit = (best <= k).astype(jnp.float32)
         out[f"R{k}"] = jnp.sum(hit * vf, -1) / cnt

@@ -33,6 +33,7 @@ import numpy as np
 from repro.analysis.registry import register_program
 from repro.comm.batched import BatchedCodec
 from repro.comm.codec import make_codec
+from repro.common import compat
 from repro.core import edge_model as EM
 from repro.evalreid.batched import batched_retrieval_metrics
 from repro.obs import trace as obs
@@ -118,12 +119,16 @@ def stacked_eval_program(theta, qp, qids, task_mask, gp, gids, gmask, *,
 
 
 # The engine's ONE sharded eval program: the same ``stacked_eval_program``
-# body the single-device engine jits, re-jitted with every leading-C input
-# row-sharded over the mesh's "data" axis (layouts from sharding/specs) and
-# the tiny (C, T) metric outputs replicated for the host readback. Cached
-# per (mesh, config) — both ``Strategy.eval_round_stacked`` under
-# ``engine="sharded"`` and the ``launch/eval_round`` CLI call this, so
-# there is exactly one sharded eval implementation in the repo.
+# body the single-device engine jits, run per shard inside ``shard_map``
+# over the mesh's "data" axis — every input leads with the client dim
+# (layouts from sharding/specs), retrieval eval never mixes clients, so
+# each shard scores its own client block with no collective, and the
+# distance kernel sees local blocks (a Pallas call cannot be partitioned
+# by the compiler). The tiny (C, T) metric outputs come back row-sharded
+# for the host readback. Cached per (mesh, config) — both
+# ``Strategy.eval_round_stacked`` under ``engine="sharded"`` and the
+# ``launch/eval_round`` CLI call this, so there is exactly one sharded
+# eval implementation in the repo.
 _SHARDED_EVAL_CACHE: Dict[Any, Callable] = {}
 
 
@@ -131,13 +136,13 @@ def sharded_eval_fn(mesh, *, ranks=(1, 3, 5), kernel_backend=None,
                     max_matches=None):
     key = (mesh, tuple(ranks), kernel_backend, max_matches)
     if key not in _SHARDED_EVAL_CACHE:
-        rep = jax.sharding.NamedSharding(
-            mesh, jax.sharding.PartitionSpec(None, None))
-        _SHARDED_EVAL_CACHE[key] = jax.jit(
+        rows = shard_specs.client_row_spec(1)
+        _SHARDED_EVAL_CACHE[key] = jax.jit(compat.shard_map(
             functools.partial(stacked_eval_program, ranks=tuple(ranks),
                               kernel_backend=kernel_backend,
                               max_matches=max_matches),
-            out_shardings=rep)
+            mesh=mesh, in_specs=(rows,) * 7, out_specs=rows,
+            check_vma=False))
     return _SHARDED_EVAL_CACHE[key]
 
 
@@ -212,7 +217,8 @@ class Strategy:
                     return (self.loss(th, protos, labels, extras)
                             + self.regularizer(th, extras))
                 loss, grads = jax.value_and_grad(lf)(trainable)
-                grads, _ = clip_by_global_norm(grads, 1.0)
+                grads, _ = clip_by_global_norm(grads, 1.0,
+                                               fixed_order=True)
                 updates, opt_state = self.opt.update(grads, opt_state, trainable)
                 return apply_updates(trainable, updates), opt_state, loss
             self._jit_cache["train"] = step
@@ -324,7 +330,8 @@ class Strategy:
         if key not in self._wire_programs:
             template = (self.upload_codec if which == "upload"
                         else self.dispatch_codec)
-            self._wire_programs[key] = BatchedCodec(template, p)
+            self._wire_programs[key] = BatchedCodec(template, p,
+                                                    mesh=self.mesh)
         return self._wire_programs[key]
 
     def _wire_roundtrip_stacked(self, which, tree, split, join):
@@ -512,7 +519,8 @@ class Strategy:
                             return (self.loss(th, x, y, ex)
                                     + self.regularizer(th, ex))
                         loss, grads = jax.value_and_grad(lf)(tr)
-                        grads, _ = clip_by_global_norm(grads, 1.0)
+                        grads, _ = clip_by_global_norm(grads, 1.0,
+                                               fixed_order=True)
                         updates, os = self.opt.update(grads, os, tr)
                         return (apply_updates(tr, updates), os), loss
                     (tr, os), losses = jax.lax.scan(estep, (tr, os), (px, py))

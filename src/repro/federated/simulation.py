@@ -38,7 +38,9 @@ Two interchangeable engines drive the rounds:
     relevance ring and all eval inputs are placed row-sharded over "data"
     (``sharding/specs.py`` is the layout source of truth; C is padded to a
     multiple of the device count, padding rows masked out of the relevance
-    ring) and the same jitted programs re-specialize into SPMD. Wire-bound
+    ring) and the same jitted programs re-specialize into SPMD; the
+    programs that call Pallas kernels (relevance, aggregate, codec, eval)
+    run them per shard inside ``shard_map``. Wire-bound
     buffers cross shards in bf16 (``common/precision.py``); optimizer/BN
     state stays f32. Metrics and measured comm bytes match the stacked
     engine (``benchmarks/run.py --bench mesh`` scales C → 10k).
@@ -86,6 +88,7 @@ class SimulationResult:
     storage_bytes: int
     rounds: List[Dict[str, float]]      # per-eval-round mean metrics
     server_time_s: float = 0.0          # wall time inside server_round
+    eval_on_device: bool = False        # batched device eval (else host)
 
     def final(self, key="mAP") -> float:
         return self.rounds[-1][key] if self.rounds else 0.0
@@ -458,7 +461,8 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
         storage = max(strategy.storage_bytes(strategy.client_view(stacked, c))
                       for c in range(C))
         return SimulationResult(strategy.name, tracker, comm, storage,
-                                eval_rounds, server_time_s=server_s)
+                                eval_rounds, server_time_s=server_s,
+                                eval_on_device=eval_dev)
 
     accepts_raw = "raw_images" in inspect.signature(strategy.local_train).parameters
 
@@ -523,4 +527,4 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
 
     storage = max(strategy.storage_bytes(states[c]) for c in range(C))
     return SimulationResult(strategy.name, tracker, comm, storage, eval_rounds,
-                            server_time_s=server_s)
+                            server_time_s=server_s, eval_on_device=eval_dev)

@@ -3,7 +3,8 @@
 kernels: flash_attention (backbone prefill), pairwise_dist (ReID retrieval),
 adaptive_combine (Eq. 2), relevance_aggregate (Eq. 6), kl_similarity (Eq. 4).
 Each has a pl.pallas_call + BlockSpec implementation validated in
-interpret=True mode against the pure-jnp oracle in ref.py.
+interpret mode against the pure-jnp oracle in ref.py, and compiled for a
+TPU v5e in tests/test_tpu_compile.py.
 """
 from repro.kernels.ops import (
     adaptive_combine,

@@ -8,9 +8,13 @@ the unfused mul+add). Arrays are flattened and tiled (8 x 1024) in VMEM.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.common.compat import default_interpret
 
 ROWS = 8
 COLS = 1024
@@ -23,8 +27,10 @@ def _combine_kernel(b_ref, al_ref, a_ref, o_ref):
                   + a_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def adaptive_combine(base, alpha, a, *, interpret: bool = True):
+def adaptive_combine(base, alpha, a, *, interpret: Optional[bool] = None):
     """Elementwise B*alpha + A for a single array of any shape."""
+    if interpret is None:
+        interpret = default_interpret()
     shape = base.shape
     n = base.size
     npad = (n + TILE - 1) // TILE * TILE
@@ -44,7 +50,8 @@ def adaptive_combine(base, alpha, a, *, interpret: bool = True):
     return jnp.ravel(out)[:n].reshape(shape)
 
 
-def adaptive_combine_tree(base_tree, alpha_tree, a_tree, *, interpret=True):
+def adaptive_combine_tree(base_tree, alpha_tree, a_tree, *,
+                          interpret: Optional[bool] = None):
     """Leaf-wise Eq. 2 over a full adaptive pytree."""
     return jax.tree.map(
         lambda b, al, a: adaptive_combine(b, al, a, interpret=interpret),

@@ -7,18 +7,21 @@ head_dim a multiple of 128 where the config allows; the lane dim is the
 head_dim so 64-wide heads still map cleanly onto the 8x128 VREG tiles).
 
 Validated against ref.flash_attention_ref in interpret mode on CPU
-(tests/test_kernels.py sweeps shapes and dtypes); on TPU, pass
-interpret=False for the compiled kernel.
+(tests/test_kernels.py sweeps shapes and dtypes); on TPU the compiled
+kernel runs (``interpret`` defaults to ``default_interpret()``).
 """
 from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+
+from repro.common.compat import default_interpret
 
 DEFAULT_Q_BLOCK = 128
 DEFAULT_KV_BLOCK = 128
@@ -34,10 +37,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block, causal, scale,
 
     def body(i, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(i * kv_block, kv_block), slice(None))
-                    ).astype(jnp.float32)                 # (kv_block, hd)
-        v = pl.load(v_ref, (pl.dslice(i * kv_block, kv_block), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(i * kv_block, kv_block), :].astype(jnp.float32)
+        v = v_ref[pl.ds(i * kv_block, kv_block), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if causal:
@@ -68,8 +69,11 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, kv_block, causal, scale,
 
 
 def flash_attention(q, k, v, *, causal: bool = True, q_block: int = DEFAULT_Q_BLOCK,
-                    kv_block: int = DEFAULT_KV_BLOCK, interpret: bool = True):
+                    kv_block: int = DEFAULT_KV_BLOCK,
+                    interpret: Optional[bool] = None):
     """q: (B,H,Sq,hd); k,v: (B,H,Sk,hd). Sq % q_block == Sk % kv_block == 0."""
+    if interpret is None:
+        interpret = default_interpret()
     B, H, Sq, hd = q.shape
     Sk = k.shape[2]
     q_block = min(q_block, Sq)

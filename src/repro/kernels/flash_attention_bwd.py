@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from repro.common.compat import default_interpret
 from repro.kernels.flash_attention import NEG_INF
 
 Q_BLOCK = 128
@@ -40,10 +41,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, kv_block, causal,
 
     def body(i, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(i * kv_block, kv_block), slice(None))
-                    ).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(i * kv_block, kv_block), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(i * kv_block, kv_block), :].astype(jnp.float32)
+        v = v_ref[pl.ds(i * kv_block, kv_block), :].astype(jnp.float32)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
         if causal:
@@ -81,10 +80,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     n_kv = seq_k // kv_block
 
     def body(i, dq):
-        k = pl.load(k_ref, (pl.dslice(i * kv_block, kv_block), slice(None))
-                    ).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(i * kv_block, kv_block), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(i * kv_block, kv_block), :].astype(jnp.float32)
+        v = v_ref[pl.ds(i * kv_block, kv_block), :].astype(jnp.float32)
         s = lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
         if causal:
@@ -116,12 +113,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def body(i, carry):
         dk, dv = carry
-        q = pl.load(q_ref, (pl.dslice(i * q_block, q_block), slice(None))
-                    ).astype(jnp.float32)
-        do = pl.load(do_ref, (pl.dslice(i * q_block, q_block), slice(None))
-                     ).astype(jnp.float32)
-        lse = pl.load(lse_ref, (pl.dslice(i * q_block, q_block),))
-        delta = pl.load(delta_ref, (pl.dslice(i * q_block, q_block),))
+        q = q_ref[pl.ds(i * q_block, q_block), :].astype(jnp.float32)
+        do = do_ref[pl.ds(i * q_block, q_block), :].astype(jnp.float32)
+        lse = lse_ref[pl.ds(i * q_block, q_block)]
+        delta = delta_ref[pl.ds(i * q_block, q_block)]
         s = lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
         if causal:
@@ -154,12 +149,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_vjp(q, k, v, causal=True, q_block=Q_BLOCK,
-                        kv_block=KV_BLOCK, interpret=True):
+                        kv_block=KV_BLOCK, interpret=None):
     out, _ = _fwd(q, k, v, causal, q_block, kv_block, interpret)
     return out
 
 
 def _fwd(q, k, v, causal, q_block, kv_block, interpret):
+    if interpret is None:
+        interpret = default_interpret()
     B, H, Sq, hd = q.shape
     Sk = k.shape[2]
     q_block = min(q_block, Sq)
@@ -196,6 +193,8 @@ def _fwd_rule(q, k, v, causal, q_block, kv_block, interpret):
 
 def _bwd_rule(causal, q_block, kv_block, interpret, res, do):
     q, k, v, out, lse = res
+    if interpret is None:
+        interpret = default_interpret()
     B, H, Sq, hd = q.shape
     Sk = k.shape[2]
     q_block = min(q_block, Sq)

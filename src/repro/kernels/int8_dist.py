@@ -28,12 +28,13 @@ G_BLOCK = 128
 def _i8dist_kernel(q_ref, g_ref, s_ref, n2_ref, o_ref):
     q = q_ref[0].astype(jnp.float32)            # (bb, F)
     g = g_ref[0].astype(jnp.float32)            # (gb, F) int8 -> f32 in VMEM
-    s = s_ref[0]                                # (gb,) per-row scales
-    n2 = n2_ref[0]                              # (gb,) dequantized |g|^2
+    s = s_ref[0]                                # (1, gb) per-row scales
+    n2 = n2_ref[0]                              # (1, gb) dequantized |g|^2
     qq = jnp.sum(q * q, -1, keepdims=True)      # (bb, 1)
     dot = jax.lax.dot_general(q, g, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
-    o_ref[0] = qq + n2[None, :] - 2.0 * (dot * s[None, :])
+    o_ref[0] = qq + n2 - 2.0 * (dot * s)
 
 
 def batched_int8_pairwise_dist(q, gq, gscale, gn2, *,
@@ -45,7 +46,9 @@ def batched_int8_pairwise_dist(q, gq, gscale, gn2, *,
 
     One client per leading grid step (the serving layout: every client's
     query batch scores its own resident gallery in a single launch). B, G
-    padded to block multiples internally.
+    padded to block multiples internally. The per-row scales and norms
+    ride as (C, 1, G) so each step's sidecar block is a lane-dense
+    (1, g_block) row.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -57,8 +60,8 @@ def batched_int8_pairwise_dist(q, gq, gscale, gn2, *,
     Gp = (G + g_block - 1) // g_block * g_block
     qp = jnp.pad(q, ((0, 0), (0, Bp - B), (0, 0)))
     gp = jnp.pad(gq, ((0, 0), (0, Gp - G), (0, 0)))
-    sp = jnp.pad(gscale, ((0, 0), (0, Gp - G)))
-    np_ = jnp.pad(gn2, ((0, 0), (0, Gp - G)))
+    sp = jnp.pad(gscale, ((0, 0), (0, Gp - G)))[:, None, :]
+    np_ = jnp.pad(gn2, ((0, 0), (0, Gp - G)))[:, None, :]
 
     out = pl.pallas_call(
         _i8dist_kernel,
@@ -66,8 +69,8 @@ def batched_int8_pairwise_dist(q, gq, gscale, gn2, *,
         in_specs=[
             pl.BlockSpec((1, b_block, F), lambda c, i, j: (c, i, 0)),
             pl.BlockSpec((1, g_block, F), lambda c, i, j: (c, j, 0)),
-            pl.BlockSpec((1, g_block), lambda c, i, j: (c, j)),
-            pl.BlockSpec((1, g_block), lambda c, i, j: (c, j)),
+            pl.BlockSpec((1, 1, g_block), lambda c, i, j: (c, 0, j)),
+            pl.BlockSpec((1, 1, g_block), lambda c, i, j: (c, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, b_block, g_block),
                                lambda c, i, j: (c, i, j)),

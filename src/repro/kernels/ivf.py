@@ -43,18 +43,20 @@ L_BLOCK = 128
 def _cdist_kernel(q_ref, c_ref, n2_ref, o_ref):
     q = q_ref[0]                                # (bb, F) fp32
     cent = c_ref[0]                             # (lb, F) fp32 centroids
-    n2 = n2_ref[0]                              # (lb,) |centroid|^2
+    n2 = n2_ref[0]                              # (1, lb) |centroid|^2
     qq = jnp.sum(q * q, -1, keepdims=True)      # (bb, 1)
     dot = jax.lax.dot_general(q, cent, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
-    o_ref[0] = qq + n2[None, :] - 2.0 * dot
+    o_ref[0] = qq + n2 - 2.0 * dot
 
 
 def batched_cluster_dist(qf, cent, cn2, *, b_block: int = B_BLOCK,
                          l_block: int = L_BLOCK,
                          interpret: Optional[bool] = None):
     """(C, B, F) fp32 queries x ((C, L, F) centroids, (C, L) sq-norms)
-    -> (C, B, L) squared distances. B, L padded to block multiples."""
+    -> (C, B, L) squared distances. B, L padded to block multiples; the
+    norms ride as (C, 1, L) so each step's block is a lane-dense row."""
     if interpret is None:
         interpret = default_interpret()
     C, B, F = qf.shape
@@ -65,7 +67,7 @@ def batched_cluster_dist(qf, cent, cn2, *, b_block: int = B_BLOCK,
     Lp = (L + l_block - 1) // l_block * l_block
     qp = jnp.pad(qf, ((0, 0), (0, Bp - B), (0, 0)))
     cp = jnp.pad(cent, ((0, 0), (0, Lp - L), (0, 0)))
-    np_ = jnp.pad(cn2, ((0, 0), (0, Lp - L)))
+    np_ = jnp.pad(cn2, ((0, 0), (0, Lp - L)))[:, None, :]
 
     out = pl.pallas_call(
         _cdist_kernel,
@@ -73,7 +75,7 @@ def batched_cluster_dist(qf, cent, cn2, *, b_block: int = B_BLOCK,
         in_specs=[
             pl.BlockSpec((1, b_block, F), lambda c, i, j: (c, i, 0)),
             pl.BlockSpec((1, l_block, F), lambda c, i, j: (c, j, 0)),
-            pl.BlockSpec((1, l_block), lambda c, i, j: (c, j)),
+            pl.BlockSpec((1, 1, l_block), lambda c, i, j: (c, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, b_block, l_block),
                                lambda c, i, j: (c, i, j)),
@@ -85,13 +87,16 @@ def batched_cluster_dist(qf, cent, cn2, *, b_block: int = B_BLOCK,
 
 def _shortlist_kernel(probe_ref, q_ref, bq_ref, pk_ref, o_ref):
     del probe_ref                               # consumed by the index maps
-    q = q_ref[0, 0].reshape(1, -1)              # (1, F)
+    b = pl.program_id(1)
+    j = pl.program_id(2)
+    q = q_ref[0, pl.ds(b, 1), :]                # (1, F) this query
     blk = bq_ref[0, 0].astype(jnp.float32)      # (bcap, F) int8 -> f32 VMEM
-    s = pk_ref[0, 0, 0]                         # (bcap,) per-row scales
-    n2 = pk_ref[0, 0, 1]                        # (bcap,) dequant |g|^2
-    dot = jax.lax.dot_general(blk, q, (((1,), (1,)), ((), ())),
+    s = pk_ref[0, 0, 0:1, :]                    # (1, bcap) per-row scales
+    n2 = pk_ref[0, 0, 1:2, :]                   # (1, bcap) dequant |g|^2
+    dot = jax.lax.dot_general(q, blk, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
-    o_ref[0, 0, 0] = n2 - 2.0 * (dot[:, 0] * s)
+    o_ref[0, 0, pl.ds(j, 1), :] = n2 - 2.0 * (dot * s)
 
 
 def batched_ivf_shortlist_scores(qf, probe, bq, pack, *,
@@ -102,7 +107,11 @@ def batched_ivf_shortlist_scores(qf, probe, bq, pack, *,
 
     One grid step per (client, query, probe); the probe ids are a
     scalar-prefetch operand so the bucket/sidecar BlockSpecs can index
-    blocks by ``probe[c, b, j]`` directly.
+    blocks by ``probe[c, b, j]`` directly. The query block is the
+    client's whole (B, F) batch (the step reads its one row) and the
+    output block is the query's whole (P, bcap) score tile, resident
+    across the probe axis (row j written at step j) — both keep every
+    block's last two dims whole.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -114,18 +123,20 @@ def batched_ivf_shortlist_scores(qf, probe, bq, pack, *,
         num_scalar_prefetch=1,
         grid=(C, B, P),
         in_specs=[
-            pl.BlockSpec((1, 1, F), lambda c, b, j, probe: (c, b, 0)),
+            pl.BlockSpec((1, B, F), lambda c, b, j, probe: (c, 0, 0)),
             pl.BlockSpec((1, 1, bcap, F),
                          lambda c, b, j, probe: (c, probe[c, b, j], 0, 0)),
             pl.BlockSpec((1, 1, 3, bcap),
                          lambda c, b, j, probe: (c, probe[c, b, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, bcap),
-                               lambda c, b, j, probe: (c, b, j, 0)),
+        out_specs=pl.BlockSpec((1, 1, P, bcap),
+                               lambda c, b, j, probe: (c, b, 0, 0)),
     )
     return pl.pallas_call(
         _shortlist_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, B, P, bcap), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(probe, qf, bq, pack)

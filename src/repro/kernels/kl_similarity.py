@@ -32,6 +32,7 @@ def _kl_kernel(a_ref, b_ref, o_ref):
     p = jnp.exp(logp)
     h = jnp.sum(p * logp, -1)                    # (nb,)
     cross = jax.lax.dot_general(p, logq, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
     o_ref[...] = jnp.exp(-(h[:, None] - cross))
 

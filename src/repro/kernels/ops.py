@@ -196,12 +196,13 @@ def relevance_aggregate(w, thetas, *, backend: str = None):
     oracle="repro.kernels.ref.fused_relevance_aggregate_ref",
     budget_bytes=16 << 20)
 @functools.partial(jax.jit, static_argnames=("backend",))
-def fused_relevance_aggregate(w, thetas, *, backend: str = None):
-    """Diag-mask + row-normalize + W @ Θ in one program -> (B, Wn)."""
+def fused_relevance_aggregate(w, thetas, row0=0, *, backend: str = None):
+    """Diag-mask + row-normalize + W @ Θ in one program -> (B, Wn). ``w``
+    may be a row block starting at row ``row0`` of the (C, C) matrix."""
     b = _dispatch(backend)
     if b == "ref":
-        return REF.fused_relevance_aggregate_ref(w, thetas)
-    return _fused_agg(w, thetas, interpret=(b == "interpret"))
+        return REF.fused_relevance_aggregate_ref(w, thetas, row0)
+    return _fused_agg(w, thetas, row0, interpret=(b == "interpret"))
 
 
 @register_program(

@@ -24,6 +24,7 @@ def _dist_kernel(q_ref, g_ref, o_ref):
     qq = jnp.sum(q * q, -1, keepdims=True)      # (qb, 1)
     gg = jnp.sum(g * g, -1)                     # (gb,)
     dot = jax.lax.dot_general(q, g, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     o_ref[...] = qq + gg[None, :] - 2.0 * dot
 
@@ -63,6 +64,7 @@ def _bdist_kernel(q_ref, g_ref, o_ref):
     qq = jnp.sum(q * q, -1, keepdims=True)      # (qb, 1)
     gg = jnp.sum(g * g, -1)                     # (gb,)
     dot = jax.lax.dot_general(q, g, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     o_ref[0] = qq + gg[None, :] - 2.0 * dot
 

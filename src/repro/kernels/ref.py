@@ -1,12 +1,19 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose ground truth).
 
 These are also the implementations the CPU benchmarks and the dry-run HLO
-use (identical math, no pallas_call in the lowered program).
+use (identical math, no pallas_call in the lowered program). Matmuls that
+a kernel mirrors run at ``Precision.HIGHEST``, as the kernels do: on the
+TPU both then accumulate full fp32 products (the default there is one
+bf16 pass), and on the CPU the flag changes nothing.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.common.precision import INV127
+
+_HI = jax.lax.Precision.HIGHEST
 
 
 def flash_attention_ref(q, k, v, *, causal: bool = True, scale=None):
@@ -29,7 +36,7 @@ def pairwise_dist_ref(q, g):
     g = g.astype(jnp.float32)
     qq = jnp.sum(q * q, -1, keepdims=True)
     gg = jnp.sum(g * g, -1)
-    return qq + gg[None, :] - 2.0 * (q @ g.T)
+    return qq + gg[None, :] - 2.0 * jnp.matmul(q, g.T, precision=_HI)
 
 
 def batched_pairwise_dist_ref(q, g):
@@ -38,7 +45,7 @@ def batched_pairwise_dist_ref(q, g):
     g = g.astype(jnp.float32)
     qq = jnp.sum(q * q, -1)[:, :, None]
     gg = jnp.sum(g * g, -1)[:, None, :]
-    return qq + gg - 2.0 * jnp.einsum("cqd,cgd->cqg", q, g)
+    return qq + gg - 2.0 * jnp.einsum("cqd,cgd->cqg", q, g, precision=_HI)
 
 
 def batched_int8_pairwise_dist_ref(q, gq, gscale, gn2):
@@ -48,7 +55,8 @@ def batched_int8_pairwise_dist_ref(q, gq, gscale, gn2):
     the dequantized rows. One-way int8 -> f32 dequant (no round-trip)."""
     q = q.astype(jnp.float32)
     qq = jnp.sum(q * q, -1)[:, :, None]
-    dot = jnp.einsum("cbf,cgf->cbg", q, gq.astype(jnp.float32))
+    dot = jnp.einsum("cbf,cgf->cbg", q, gq.astype(jnp.float32),
+                     precision=_HI)
     return qq + gn2[:, None, :] - 2.0 * (dot * gscale[:, None, :])
 
 
@@ -59,38 +67,42 @@ def adaptive_combine_ref(base, alpha, a):
 
 def relevance_aggregate_ref(w, thetas):
     """FedSTIL Eq. 6: (C,C) x (C,P) -> (C,P), fp32 accumulate."""
-    return (w.astype(jnp.float32) @ thetas.astype(jnp.float32)).astype(thetas.dtype)
+    return jnp.matmul(w.astype(jnp.float32), thetas.astype(jnp.float32),
+                      precision=_HI).astype(thetas.dtype)
 
 
-def fused_relevance_aggregate_ref(w, thetas):
+def fused_relevance_aggregate_ref(w, thetas, row0=0):
     """Fused FedSTIL server math (Eq. 5 post-processing + Eq. 6):
 
         Wm = w ⊙ (1 - I)                 (no self-relevance)
         Wn = Wm / rowsum(Wm)             (zero rows stay zero)
         B  = Wn @ thetas                 (fp32 accumulate)
 
-    w: (C, C) *raw* decayed relevance (diagonal may hold junk);
-    thetas: (C, P). Returns (B: (C, P) in thetas.dtype, Wn: (C, C) fp32).
+    w: (R, C) *raw* decayed relevance, rows row0..row0+R-1 of the (C, C)
+    matrix (diagonal may hold junk); thetas: (C, P). Returns (B: (R, P) in
+    thetas.dtype, Wn: (R, C) fp32).
     """
-    C = w.shape[0]
-    wm = w.astype(jnp.float32) * (1.0 - jnp.eye(C, dtype=jnp.float32))
+    R, C = w.shape
+    row = jnp.arange(R)[:, None] + row0
+    wm = jnp.where(row == jnp.arange(C)[None, :], 0.0, w.astype(jnp.float32))
     rows = jnp.sum(wm, axis=1, keepdims=True)
     wn = jnp.where(rows > 0, wm / jnp.where(rows > 0, rows, 1.0), 0.0)
-    b = (wn @ thetas.astype(jnp.float32)).astype(thetas.dtype)
+    b = jnp.matmul(wn, thetas.astype(jnp.float32),
+                   precision=_HI).astype(thetas.dtype)
     return b, wn
 
 
 def batched_quantize_ref(x, *, chunk: int = 256):
     """Per-chunk symmetric int8 quantization of stacked payload rows:
     (C, P) fp32 -> ((C, P) int8, (C, ceil(P/chunk)) fp32 scales). Chunks of
-    ``chunk`` contiguous elements share one scale = absmax/127 (1.0 for
+    ``chunk`` contiguous elements share one scale = absmax*(1/127) (1.0 for
     all-zero chunks); round-half-to-even, clip to [-127, 127]."""
     C, P = x.shape
     nc = (P + chunk - 1) // chunk
     xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, nc * chunk - P)))
     xc = xp.reshape(C, nc, chunk)
     absmax = jnp.max(jnp.abs(xc), axis=2, keepdims=True)
-    scale = absmax / 127.0
+    scale = absmax * INV127
     scale = jnp.where(scale > 0, scale, 1.0)   # all-zero / subnormal chunks
     q = jnp.clip(jnp.round(xc / scale), -127.0, 127.0).astype(jnp.int8)
     return q.reshape(C, nc * chunk)[:, :P], scale[..., 0]
@@ -219,7 +231,8 @@ def batched_cluster_assign_ref(qf, cent, cn2, *, nprobe: int):
     q = qf.astype(jnp.float32)
     qq = jnp.sum(q * q, -1)
     dc = (qq[..., None] + cn2[:, None, :]
-          - 2.0 * jnp.einsum("cbf,clf->cbl", q, cent.astype(jnp.float32)))
+          - 2.0 * jnp.einsum("cbf,clf->cbl", q, cent.astype(jnp.float32),
+                             precision=_HI))
     return jax.lax.top_k(-dc, nprobe)[1]
 
 
@@ -252,7 +265,7 @@ def batched_ivf_shortlist_ref(qf, probe, bq, pack):
                                         (1, 1, K, F))[0, 0]
             pk = jax.lax.dynamic_slice(pack, (ci, pi[j], 0, 0),
                                        (1, 1, 3, K))[0, 0]
-            dot = blk.astype(jnp.float32) @ qi
+            dot = jnp.matmul(blk.astype(jnp.float32), qi, precision=_HI)
             ds.append(pk[1] - 2.0 * (dot * pk[0]))
             ids.append(jax.lax.bitcast_convert_type(pk[2], jnp.int32))
         return None, (jnp.concatenate(ds), jnp.concatenate(ids))
@@ -267,5 +280,5 @@ def kl_similarity_ref(a, b):
     logp = jax.nn.log_softmax(a.astype(jnp.float32), -1)
     logq = jax.nn.log_softmax(b.astype(jnp.float32), -1)
     h = jnp.sum(p * logp, -1)                    # (N,)
-    cross = p @ logq.T                            # (N,M)
+    cross = jnp.matmul(p, logq.T, precision=_HI)  # (N,M)
     return jnp.exp(-(h[:, None] - cross))

@@ -5,13 +5,19 @@ contiguous elements, the ``kg`` largest-magnitude entries (exact, ties by
 lowest index) and ships them as (values, packed int32 indices) in
 magnitude-rank order. The group-local budget is what makes top-k
 hardware-friendly: selection is an O(group^2) counting compare per group
-(a (G, G) broadcast on the VPU), and packing is a one-hot reduction into a
-REGULAR output layout (group b's survivors occupy slots [b*kg, (b+1)*kg))
-— no global sort, no scatter, no cross-tile communication, so the grid is
-embarrassingly parallel over (client, tile). Global exact top-k lives in
-the host codec (``comm.codec.topk_select_host``) where numpy's introselect
-is the right tool; on the wire the two formats carry identical byte counts
-at the same keep fraction.
+and packing is a one-hot reduction into a REGULAR output layout (group
+b's survivors occupy slots [b*kg, (b+1)*kg)) — no global sort, no
+scatter, no cross-tile communication, so the grid is embarrassingly
+parallel over (client, tile). Global exact top-k lives in the host codec
+(``comm.codec.topk_select_host``) where numpy's introselect is the right
+tool; on the wire the two formats carry identical byte counts at the same
+keep fraction.
+
+TPU layout: the wrappers view each client row group-transposed, (group,
+n_groups) — a group's members share a lane and sit on consecutive
+sublanes — so ranking is a handful of whole-tile compares reduced over
+sublanes, and every block is (group | kg | 8 | bits rows) x (a multiple
+of 128 lanes). The transposes are plain XLA ops around the kernel.
 
 Semantics are bit-identical to ``ref.batched_topk_pack_ref`` and to the
 numpy host codec (same counting formulas), which the comm-round bench
@@ -29,85 +35,91 @@ from jax.experimental import pallas as pl
 from repro.common.compat import default_interpret
 
 GROUP = 8
-P_BLOCK = 2048
+LANES = 128
+NB_BLOCK = 2048      # groups (lanes) per grid step
 
 
-def _block_for(group: int, p: int, cap: int = P_BLOCK) -> int:
-    """Largest group-multiple tile <= cap (at least one group)."""
-    return group * max(1, min(cap, p) // group)
+def _lane_plan(n: int, cap: int = NB_BLOCK):
+    """(lane block, padded n): the block is a multiple of 128 lanes."""
+    nb = min(cap, -(-n // LANES) * LANES)
+    return nb, -(-n // nb) * nb
+
+
+def _grouped_t(x, n: int, group: int):
+    """(C, n*group) row-major -> (C, group, n) group-transposed."""
+    C = x.shape[0]
+    return x.reshape(C, n, group).transpose(0, 2, 1)
+
+
+def _ungrouped(x):
+    """(C, rows, n) -> (C, n*rows): inverse of ``_grouped_t``."""
+    C, r, n = x.shape
+    return x.transpose(0, 2, 1).reshape(C, n * r)
 
 
 def _pack_kernel(x_ref, v_ref, i_ref, *, group: int, kg: int):
     t = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)                     # (1, pb)
-    pb = x.shape[1]
-    nb = pb // group
-    xg = x.reshape(nb, group)
-    a = jnp.abs(xg)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (group, group), 0)  # i
-    jj = jax.lax.broadcasted_iota(jnp.int32, (group, group), 1)  # j
-    ai = a[:, :, None]
-    aj = a[:, None, :]
-    beats = jnp.logical_or(aj > ai, jnp.logical_and(aj == ai, jj < ii))
-    rank = jnp.sum(beats.astype(jnp.int32), axis=-1)       # (nb, G)
-    onehot = (rank[..., None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (nb, group, kg), 2))
-    vals = jnp.sum(xg[..., None] * onehot.astype(jnp.float32), axis=1)
-    base = (t * pb
-            + jax.lax.broadcasted_iota(jnp.int32, (nb, group), 0) * group
-            + jax.lax.broadcasted_iota(jnp.int32, (nb, group), 1))
-    idx = jnp.sum(base[..., None] * onehot.astype(jnp.int32), axis=1)
-    v_ref[...] = vals.reshape(1, nb * kg)
-    i_ref[...] = idx.reshape(1, nb * kg)
+    x = x_ref[0].astype(jnp.float32)                       # (group, nbb)
+    nbb = x.shape[1]
+    a = jnp.abs(x)
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)  # in-group index i
+    rank = jnp.zeros(x.shape, jnp.int32)
+    for j in range(group):                                 # j beats i?
+        aj = a[j:j + 1]
+        beats = jnp.logical_or(aj > a, jnp.logical_and(aj == a, j < row))
+        rank = rank + beats.astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    gidx = (t * nbb + lane) * group + row                  # absolute index
+    for r in range(kg):
+        oh = rank == r
+        v_ref[0, r:r + 1, :] = jnp.sum(x * oh.astype(jnp.float32), axis=0,
+                                       keepdims=True)
+        i_ref[0, r:r + 1, :] = jnp.sum(gidx * oh.astype(jnp.int32), axis=0,
+                                       keepdims=True)
 
 
 def batched_topk_pack(x, *, group: int = GROUP, kg: int,
-                      p_block: int = P_BLOCK,
+                      nb_block: int = NB_BLOCK,
                       interpret: Optional[bool] = None):
     """(C, P) -> (values (C, nb*kg) fp32, indices (C, nb*kg) int32),
     nb = ceil(P/group): every group keeps its kg largest magnitudes."""
     if interpret is None:
         interpret = default_interpret()
     C, P = x.shape
-    pb = _block_for(group, P, p_block)
-    Pp = (P + pb - 1) // pb * pb
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, Pp - P)))
-    nb_total = Pp // group
-    ob = (pb // group) * kg                                # out tile width
+    nb = -(-P // group)
+    nbb, nbp = _lane_plan(nb, nb_block)
+    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, nbp * group - P)))
 
     vals, idx = pl.pallas_call(
         functools.partial(_pack_kernel, group=group, kg=kg),
-        grid=(C, Pp // pb),
-        in_specs=[pl.BlockSpec((1, pb), lambda c, t: (c, t))],
+        grid=(C, nbp // nbb),
+        in_specs=[pl.BlockSpec((1, group, nbb), lambda c, t: (c, 0, t))],
         out_specs=[
-            pl.BlockSpec((1, ob), lambda c, t: (c, t)),
-            pl.BlockSpec((1, ob), lambda c, t: (c, t)),
+            pl.BlockSpec((1, kg, nbb), lambda c, t: (c, 0, t)),
+            pl.BlockSpec((1, kg, nbb), lambda c, t: (c, 0, t)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((C, nb_total * kg), jnp.float32),
-            jax.ShapeDtypeStruct((C, nb_total * kg), jnp.int32),
+            jax.ShapeDtypeStruct((C, kg, nbp), jnp.float32),
+            jax.ShapeDtypeStruct((C, kg, nbp), jnp.int32),
         ],
         interpret=interpret,
-    )(xp)
-    K = ((P + group - 1) // group) * kg
-    return vals[:, :K], idx[:, :K]
+    )(_grouped_t(xp, nbp, group))
+    K = nb * kg
+    return _ungrouped(vals)[:, :K], _ungrouped(idx)[:, :K]
 
 
 def _bitpack_kernel(i_ref, o_ref, *, group: int, kg: int, k: int,
                     bits: int):
-    ix = i_ref[...]                                        # (1, kp) int32
-    kp = ix.shape[1]
-    kb = kp // 8
-    s = jax.lax.broadcasted_iota(jnp.int32, (1, kp), 1)
+    t = pl.program_id(1)
+    ix = i_ref[0]                                          # (8, kbb) int32
+    kbb = ix.shape[1]
+    m = jax.lax.broadcasted_iota(jnp.int32, ix.shape, 0)   # bit within byte
+    s = (t * kbb + jax.lax.broadcasted_iota(jnp.int32, ix.shape, 1)) * 8 + m
     # local in-group index per pack slot; padding slots (s >= k) pack as 0
-    li = jnp.where(s < k, ix - (s // kg) * group, 0)
-    lib = li.reshape(kb, 8)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (kb, 8), 1)
-    weight = jnp.left_shift(jnp.ones((kb, 8), jnp.int32), lane)
-    planes = [jnp.sum(((lib >> j) & 1) * weight, axis=1)   # (kb,) per plane
-              for j in range(bits)]
-    o_ref[...] = jnp.concatenate(planes).reshape(1, bits * kb) \
-                    .astype(jnp.uint8)
+    li = jnp.where(s < k, ix - jax.lax.div(s, kg) * group, 0)
+    for j in range(bits):
+        o_ref[0, j:j + 1, :] = jnp.sum(((li >> j) & 1) << m, axis=0,
+                                       keepdims=True)
 
 
 def batched_idx_bitpack(x, *, group: int = GROUP, kg: int,
@@ -122,31 +134,32 @@ def batched_idx_bitpack(x, *, group: int = GROUP, kg: int,
         interpret = default_interpret()
     C, K = x.shape
     bits = (group - 1).bit_length()
-    kb = (K + 7) // 8
-    kp = kb * 8
-    xp = jnp.pad(x, ((0, 0), (0, kp - K)))
-    return pl.pallas_call(
+    kb = -(-K // 8)
+    kbb, kbp = _lane_plan(kb)
+    xp = jnp.pad(x, ((0, 0), (0, kbp * 8 - K)))
+    planes = pl.pallas_call(
         functools.partial(_bitpack_kernel, group=group, kg=kg, k=K,
                           bits=bits),
-        grid=(C,),
-        in_specs=[pl.BlockSpec((1, kp), lambda c: (c, 0))],
-        out_specs=pl.BlockSpec((1, bits * kb), lambda c: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, bits * kb), jnp.uint8),
+        grid=(C, kbp // kbb),
+        in_specs=[pl.BlockSpec((1, 8, kbb), lambda c, t: (c, 0, t))],
+        out_specs=pl.BlockSpec((1, bits, kbb), lambda c, t: (c, 0, t)),
+        out_shape=jax.ShapeDtypeStruct((C, bits, kbp), jnp.int32),
         interpret=interpret,
-    )(xp)
+    )(_grouped_t(xp, kbp, 8))
+    return planes[:, :, :kb].reshape(C, bits * kb).astype(jnp.uint8)
 
 
 def _bitunpack_kernel(p_ref, o_ref, *, group: int, kg: int, bits: int):
-    pk = p_ref[...].astype(jnp.int32)                      # (1, bits*kb)
-    kb = pk.shape[1] // bits
-    b = pk.reshape(bits, kb)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (bits, kb, 8), 2)
-    flat = ((b[..., None] >> lane) & 1).reshape(bits, kb * 8)
-    li = jnp.zeros((1, kb * 8), jnp.int32)
+    t = pl.program_id(1)
+    b = p_ref[0]                                           # (bits, kbb)
+    kbb = b.shape[1]
+    shape = (8, kbb)
+    m = jax.lax.broadcasted_iota(jnp.int32, shape, 0)      # bit within byte
+    li = jnp.zeros(shape, jnp.int32)
     for j in range(bits):
-        li = li + (flat[j].reshape(1, kb * 8) << j)
-    s = jax.lax.broadcasted_iota(jnp.int32, (1, kb * 8), 1)
-    o_ref[...] = (s // kg) * group + li
+        li = li + (((b[j:j + 1] >> m) & 1) << j)
+    s = (t * kbb + jax.lax.broadcasted_iota(jnp.int32, shape, 1)) * 8 + m
+    o_ref[0] = jax.lax.div(s, kg) * group + li
 
 
 def batched_idx_bitunpack(packed, *, k: int, group: int = GROUP, kg: int,
@@ -158,46 +171,46 @@ def batched_idx_bitunpack(packed, *, k: int, group: int = GROUP, kg: int,
     C = packed.shape[0]
     bits = (group - 1).bit_length()
     kb = packed.shape[1] // bits
+    kbb, kbp = _lane_plan(kb)
+    pk = jnp.pad(packed.astype(jnp.int32).reshape(C, bits, kb),
+                 ((0, 0), (0, 0), (0, kbp - kb)))
     out = pl.pallas_call(
         functools.partial(_bitunpack_kernel, group=group, kg=kg, bits=bits),
-        grid=(C,),
-        in_specs=[pl.BlockSpec((1, bits * kb), lambda c: (c, 0))],
-        out_specs=pl.BlockSpec((1, kb * 8), lambda c: (c, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, kb * 8), jnp.int32),
+        grid=(C, kbp // kbb),
+        in_specs=[pl.BlockSpec((1, bits, kbb), lambda c, t: (c, 0, t))],
+        out_specs=pl.BlockSpec((1, 8, kbb), lambda c, t: (c, 0, t)),
+        out_shape=jax.ShapeDtypeStruct((C, 8, kbp), jnp.int32),
         interpret=interpret,
-    )(packed)
-    return out[:, :k]
+    )(pk)
+    return _ungrouped(out)[:, :k]
 
 
 def _unpack_kernel(v_ref, i_ref, o_ref, *, group: int, kg: int):
     t = pl.program_id(1)
-    v = v_ref[...].astype(jnp.float32)                     # (1, ob)
-    ix = i_ref[...]                                        # (1, ob)
-    pb = o_ref.shape[1]
-    nb = pb // group
-    vb = v.reshape(nb, kg)
-    ib = ix.reshape(nb, kg)
-    base = (t * pb
-            + jax.lax.broadcasted_iota(jnp.int32, (nb, kg), 0) * group)
-    li = ib - base                                         # local 0..G-1
-    onehot = (li[..., None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (nb, kg, group), 2))
-    dense = jnp.sum(vb[..., None] * onehot.astype(jnp.float32), axis=1)
-    o_ref[...] = dense.reshape(1, pb)
+    v = v_ref[0].astype(jnp.float32)                       # (kg, nbb)
+    ix = i_ref[0]                                          # (kg, nbb)
+    nbb = v.shape[1]
+    shape = (group, nbb)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)    # in-group index
+    base = (t * nbb + jax.lax.broadcasted_iota(jnp.int32, shape, 1)) * group
+    dense = jnp.zeros(shape, jnp.float32)
+    for r in range(kg):
+        oh = (ix[r:r + 1] - base) == row
+        dense = dense + v[r:r + 1] * oh.astype(jnp.float32)
+    o_ref[0] = dense
 
 
 def batched_topk_unpack(vals, idx, *, p: int, group: int = GROUP, kg: int,
-                        p_block: int = P_BLOCK,
+                        nb_block: int = NB_BLOCK,
                         interpret: Optional[bool] = None):
     """Inverse of ``batched_topk_pack``: one-hot expand (C, nb*kg) values
     back into dense (C, p) fp32 rows (dropped entries zero)."""
     if interpret is None:
         interpret = default_interpret()
     C, K = vals.shape
-    pb = _block_for(group, p, p_block)
-    Pp = (p + pb - 1) // pb * pb
-    ob = (pb // group) * kg
-    Kp = (Pp // group) * kg
+    nb = -(-p // group)
+    nbb, nbp = _lane_plan(nb, nb_block)
+    Kp = nbp * kg
     vp = jnp.pad(vals.astype(jnp.float32), ((0, 0), (0, Kp - K)))
     # padded slots carry value 0 and index -1: -1 can never equal a local
     # in-group index (0..group-1), so they contribute nothing even in the
@@ -206,13 +219,13 @@ def batched_topk_unpack(vals, idx, *, p: int, group: int = GROUP, kg: int,
 
     out = pl.pallas_call(
         functools.partial(_unpack_kernel, group=group, kg=kg),
-        grid=(C, Pp // pb),
+        grid=(C, nbp // nbb),
         in_specs=[
-            pl.BlockSpec((1, ob), lambda c, t: (c, t)),
-            pl.BlockSpec((1, ob), lambda c, t: (c, t)),
+            pl.BlockSpec((1, kg, nbb), lambda c, t: (c, 0, t)),
+            pl.BlockSpec((1, kg, nbb), lambda c, t: (c, 0, t)),
         ],
-        out_specs=pl.BlockSpec((1, pb), lambda c, t: (c, t)),
-        out_shape=jax.ShapeDtypeStruct((C, Pp), jnp.float32),
+        out_specs=pl.BlockSpec((1, group, nbb), lambda c, t: (c, 0, t)),
+        out_shape=jax.ShapeDtypeStruct((C, group, nbp), jnp.float32),
         interpret=interpret,
-    )(vp, ip)
-    return out[:, :p]
+    )(_grouped_t(vp, nbp, kg), _grouped_t(ip, nbp, kg))
+    return _ungrouped(out)[:, :p]

@@ -20,7 +20,6 @@ import traceback
 import jax
 import numpy as np
 
-from repro.common.compat import set_mesh
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.launch import steps as STEPS
@@ -66,7 +65,7 @@ def run_combo(arch: str, shape_name: str, multi_pod: bool, verbose=True,
     else:
         fn, args, in_specs = STEPS.build_step(cfg, mesh, shape_name,
                                               multi_pod=multi_pod)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.lower(*args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -5,13 +5,12 @@ vmapped feature heads → all distance matrices → mAP/CMC on device) is
 embarrassingly parallel over clients: every input carries a leading C dim
 and no stage contracts it. The one sharded implementation is
 ``federated.base.sharded_eval_fn`` — the engine path that
-``run_simulation(engine="sharded")`` uses — which jits the "ref"-backend
-program (pallas_call-free, so the lowering compiles on any mesh backend)
-and lets computation follow the data: inputs are placed with
-``sharding.specs.stacked_eval_specs`` client-row shardings, GSPMD puts one
-block of clients per device along the client axis and emits no
-cross-client collectives. This CLI is just a demo/lowering harness around
-that function.
+``run_simulation(engine="sharded")`` uses — which runs the program per
+shard inside ``shard_map``: inputs are placed with
+``sharding.specs.stacked_eval_specs`` client-row shardings, each device
+scores one block of clients (the distance kernel sees local blocks) and
+there are no cross-client collectives. This CLI is just a demo/lowering
+harness around that function.
 
 Run a CPU demo:   PYTHONPATH=src python -m repro.launch.eval_round --demo
 """
@@ -27,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.federated.base import sharded_eval_fn, stacked_eval_program
-from repro.sharding.specs import (named_shardings, stacked_eval_specs,
+from repro.sharding.specs import (engine_mesh, named_shardings,
+                                  stacked_eval_specs,
                                   stacked_eval_theta_specs)
 
 
@@ -37,7 +37,7 @@ def _demo():
     from repro.core import edge_model as EM
     from repro.core.edge_model import EdgeModelConfig
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = engine_mesh(jax.devices()[:8], model=2)
     C, T, Q, G = 8, 3, 16, 96
     cfg = EdgeModelConfig()
     rng = np.random.default_rng(0)
@@ -51,8 +51,8 @@ def _demo():
     gids = jnp.asarray(rng.integers(0, 30, (C, G)), jnp.int32)
     gmask = jnp.asarray((rng.random((C, G)) < 0.9).astype(np.float32))
 
-    # computation follows data: place client rows along the data axis, then
-    # the engine's jitted eval program re-specializes SPMD on the layout
+    # place client rows along the data axis; the engine's shard_map'd eval
+    # program scores each device's block
     sp = stacked_eval_specs()
     sh = named_shardings(mesh, sp)
     theta_sh = jax.device_put(

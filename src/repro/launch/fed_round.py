@@ -41,11 +41,13 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.common.compat import set_mesh, shard_map
+from repro.common.compat import shard_map
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.pytree import tree_flatten_concat, tree_unflatten_concat
 from repro.core.fedstil import sharded_fused_aggregate
 from repro.core.relevance import decayed_relevance
 from repro.obs import trace as obs
+from repro.sharding.specs import engine_mesh
 
 
 def fed_round(theta_local, task_feature_local, hist_features_local, *,
@@ -119,7 +121,7 @@ def fed_round_hierarchical(theta_local, task_feature_local,
 
 def _demo():
     """8 host devices, 4 clients x TP2: verify against the numpy server."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = engine_mesh(jax.devices()[:8], model=2)
     C, D, Pn, k = 4, 16, 64, 3
     key = jax.random.PRNGKey(0)
     thetas = jax.random.normal(key, (C, Pn))
@@ -136,7 +138,7 @@ def _demo():
         step, mesh=mesh,
         in_specs=(P("data", "model"), P("data", None), P("data", None, None)),
         out_specs=(P("data", "model"), P("data", None))))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         B, W = fn(thetas, feats, hists)
 
     # reference server: the same batched code the parameter server runs
@@ -194,7 +196,7 @@ def _lower(arch: str, multi_pod: bool):
                            out_specs=out_specs, check_vma=False))
     feats = jax.ShapeDtypeStruct((C, D), jnp.float32)
     hists = jax.ShapeDtypeStruct((C, k, D), jnp.float32)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = fn.lower(theta, feats, hists).compile()
     from repro.sharding.analysis import parse_collectives
     coll = parse_collectives(compiled.as_text())
@@ -211,7 +213,7 @@ def _stacked_demo():
     sharded implementation) matches the single-device kernel path."""
     from repro.kernels import ops
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = engine_mesh(jax.devices()[:8], model=2)
     C, Pn = 64, 4096
     w = jnp.abs(jax.random.normal(jax.random.PRNGKey(0), (C, C)))
     thetas = jax.random.normal(jax.random.PRNGKey(1), (C, Pn))
@@ -235,6 +237,7 @@ def main():
                     help="write a repro.obs telemetry JSONL (one span per "
                          "action); read it with python -m repro.obs.report")
     args = ap.parse_args()
+    enable_compile_cache()
     tracer = obs.Tracer(path=args.trace) if args.trace else obs.NullTracer()
     try:
         with obs.active(tracer):

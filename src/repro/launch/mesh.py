@@ -12,20 +12,20 @@ Axis semantics (DESIGN.md §3):
 """
 from __future__ import annotations
 
-import jax
+from repro.sharding.specs import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_debug_mesh(tp: int = 2, dp: int = 2, multi_pod: bool = False):
     """Small mesh for CI-scale sharding tests (8 host devices)."""
     if multi_pod:
-        return jax.make_mesh((2, dp, tp), ("pod", "data", "model"))
-    return jax.make_mesh((dp, tp), ("data", "model"))
+        return auto_mesh((2, dp, tp), ("pod", "data", "model"))
+    return auto_mesh((dp, tp), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline §Roofline)

@@ -23,6 +23,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core import edge_model as EM
 from repro.obs import trace as obs
 from repro.obs.metrics import ServeStats
@@ -50,6 +51,7 @@ def main():
                     help="write a repro.obs telemetry JSONL (spans + serve "
                          "stats); read it with python -m repro.obs.report")
     args = ap.parse_args()
+    enable_compile_cache()
 
     tracer = obs.Tracer(path=args.trace) if args.trace else obs.NullTracer()
     with obs.active(tracer):

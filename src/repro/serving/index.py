@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.analysis.registry import register_program
+from repro.common.precision import INV127, pairwise_sum, sum_of_squares
 from repro.core import edge_model as EM
 from repro.kernels import ops
 
@@ -65,8 +66,8 @@ _EPS = 1e-12
 
 
 def _l2n(x):
-    return x / jnp.sqrt(jnp.maximum(jnp.sum(jnp.square(x), -1,
-                                            keepdims=True), _EPS))
+    return x / jnp.sqrt(jnp.maximum(
+        sum_of_squares(x, -1, keepdims=True), _EPS))
 
 
 def _refresh_abstract():
@@ -330,7 +331,8 @@ def ivf_refresh_host(theta, gp, gmask, gids, *, nlist: int, bcap: int,
 
 def refresh_host(theta, gp, gmask, *, backend: str = None):
     """Numpy oracle for ``index_refresh_program``: identical head math,
-    masked BN statistics, L2 normalization, and per-row symmetric int8
+    masked BN statistics and L2 norms (summed in ``pairwise_sum``'s fixed
+    order, as the program does), and per-row symmetric int8
     quantization (round-half-to-even, clip to ±127, scale 1.0 for empty
     rows) — allclose on dequantized rows, exact on shapes/masks."""
     del backend
@@ -345,13 +347,14 @@ def refresh_host(theta, gp, gmask, *, backend: str = None):
         f = h @ tc["l2"]["w"] + tc["l2"]["b"]
         m = gmask[c][:, None]
         n = max(float(gmask[c].sum()), 1.0)
-        mu = (f * m).sum(0) / n
-        sd = np.sqrt((np.square(f - mu[None, :]) * m).sum(0) / n) + 1e-5
+        mu = pairwise_sum(f * m, 0) / n
+        sd = np.sqrt(pairwise_sum(np.square(f - mu[None, :]) * m, 0) / n) \
+            + 1e-5
         fn = (f - mu) / sd * tc["bn"]["scale"] + tc["bn"]["bias"]
-        fn = fn / np.sqrt(np.maximum(np.sum(np.square(fn), -1,
-                                            keepdims=True), _EPS))
+        fn = fn / np.sqrt(np.maximum(
+            sum_of_squares(fn, -1, keepdims=True), _EPS))
         fn = (fn * m).astype(np.float32)
-        scale = np.abs(fn).max(-1) / 127.0
+        scale = np.abs(fn).max(-1) * np.float32(INV127)
         scale = np.where(scale > 0, scale, 1.0).astype(np.float32)
         q = np.clip(np.round(fn / scale[:, None]), -127, 127).astype(np.int8)
         n2 = (np.square(q.astype(np.float32)).sum(-1)

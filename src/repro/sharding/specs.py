@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -143,6 +144,17 @@ def tree_param_specs(cfg: ModelConfig, tree, **kw):
 ENGINE_AXES = ("data", "model")
 
 
+def auto_mesh(shape, names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: jit programs over it are
+    partitioned by the compiler. (``make_mesh`` defaults to ``Explicit``
+    axes, under which a dot over a sharded contraction — the Eq. 6
+    aggregate, the KL similarity — needs an explicit out_sharding.) Every
+    mesh in the repo is built here."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
+
+
 def engine_mesh(devices=None, *, model: int = 1):
     """The engine's Mesh(("data", "model")): all devices on the client
     axis by default. ``run_simulation(engine="sharded")`` builds exactly
@@ -152,9 +164,7 @@ def engine_mesh(devices=None, *, model: int = 1):
     n = len(devices)
     if n % model != 0:
         raise ValueError(f"{n} devices not divisible by model={model}")
-    import numpy as _np
-    return jax.sharding.Mesh(
-        _np.asarray(devices).reshape(n // model, model), ENGINE_AXES)
+    return auto_mesh((n // model, model), ENGINE_AXES, devices=devices)
 
 
 def padded_clients(C: int, mesh) -> int:
@@ -192,24 +202,22 @@ def named_shardings(mesh, spec_tree):
 
 def stacked_aggregate_specs(*, client_axis: str = "data",
                             param_axis: Optional[str] = "model"):
-    """PartitionSpecs for the fused server aggregate B = Wn @ Θ at C ≫ 100.
+    """PartitionSpecs for the sharded server aggregate B = Wn @ Θ
+    (``core.fedstil.sharded_aggregate_fn``).
 
-    Θ (C, P) shards its client rows over ``client_axis`` (each device holds
-    a client block resident between rounds) and optionally its parameter
-    columns over ``param_axis``; W (C, C) shards its *columns* over the
-    client axis to line up with Θ's contracted dim, so GSPMD lowers the
-    matmul to per-device partial products + one reduce over the client
-    axis. The (C, P) aggregate output B is *row*-sharded like Θ — a
-    reduce-scatter instead of an all-reduce — so each device ends the
-    round holding exactly its own clients' new bases (Cp/d × P live
-    bytes, never the full C × P). The (C, C) normalized-relevance
-    output is tiny and replicated (the host reads it back for last_W).
+    Everything keeps its client rows on ``client_axis``: Θ (C, P) (each
+    device holds a client block resident between rounds, parameter
+    columns optionally over ``param_axis``), the raw relevance W (C, C)
+    and its normalized Wn (a row needs only itself to normalize), and the
+    output B, so each device ends the round holding exactly its own
+    clients' new bases. Eq. 6 mixes every client into every row, so the
+    program all-gathers Θ over the client axis for the contraction.
     """
     return {
-        "w": P(None, client_axis),
+        "w": P(client_axis, None),
         "thetas": P(client_axis, param_axis),
         "out": P(client_axis, param_axis),
-        "wn": P(None, None),
+        "wn": P(client_axis, None),
     }
 
 

@@ -10,6 +10,8 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.common.precision import sum_of_squares
+
 
 class Optimizer(NamedTuple):
     init: Callable
@@ -67,9 +69,17 @@ def apply_updates(params, updates):
     return jax.tree.map(lambda p, u: (p + u).astype(p.dtype), params, updates)
 
 
-def clip_by_global_norm(grads, max_norm: float):
+def clip_by_global_norm(grads, max_norm: float, *, fixed_order: bool = False):
+    """``fixed_order`` sums each leaf's squares in ``pairwise_sum`` order,
+    so a federated client's clip scale does not depend on how many clients
+    its vmapped train program holds (see ``precision.broadcast_rows``)."""
     leaves = jax.tree.leaves(grads)
-    gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in leaves))
+    if fixed_order:
+        sq = [sum_of_squares(g.astype(jnp.float32).reshape(-1), 0)
+              for g in leaves]
+    else:
+        sq = [jnp.sum(jnp.square(g.astype(jnp.float32))) for g in leaves]
+    gn = jnp.sqrt(sum(sq))
     scale = jnp.minimum(1.0, max_norm / (gn + 1e-9))
     return jax.tree.map(lambda g: g * scale, grads), gn
 

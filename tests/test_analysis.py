@@ -36,7 +36,7 @@ def test_dtype_widen_fires_on_f64():
     def f(x):
         return jnp.sum(x.astype(jnp.float64))
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         spec = _spec(f, (_S((8,), jnp.float32),))
         fs, _ = lints.run_jaxpr_lints(registry.trace(spec), spec)
     widen = [f_ for f_ in fs if f_.code == "dtype-widen"]
@@ -47,7 +47,7 @@ def test_dtype_widen_quiet_when_declared():
     def f(x):
         return jnp.sum(x.astype(jnp.float64))
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         spec = _spec(f, (_S((8,), jnp.float32),),
                      allowed_dtypes=frozenset({"float32", "float64"}))
         fs, _ = lints.run_jaxpr_lints(registry.trace(spec), spec)

@@ -227,16 +227,25 @@ def bench():
 
 def test_fedstil_codec_fidelity_guard(bench):
     """FedSTIL with the default wire codec stays within tolerance of the
-    uncompressed run while moving < half the dense FedAvg payload."""
+    uncompressed run while moving < half the dense FedAvg payload.
+
+    The mAP guard compares means over three seeds: a single run's coded-
+    minus-uncompressed gap is dominated by the seed (measured over seeds
+    0-5: -0.017 to +0.060), so one seed says more about the draw than
+    about the codec."""
     cfg = EdgeModelConfig(n_classes=bench.n_classes)
-    base = run_simulation(FedSTIL(cfg, n_clients=3, epochs=3), bench,
-                          rounds=6, eval_every=3)
-    coded = run_simulation(
-        FedSTIL(cfg, n_clients=3, epochs=3, codec="topk+int8"), bench,
-        rounds=6, eval_every=3)
+    seeds = (0, 1, 2)
+    base = [run_simulation(FedSTIL(cfg, n_clients=3, epochs=3, seed=s),
+                           bench, rounds=6, eval_every=3, seed=s)
+            for s in seeds]
+    runs = [run_simulation(
+        FedSTIL(cfg, n_clients=3, epochs=3, codec="topk+int8", seed=s),
+        bench, rounds=6, eval_every=3, seed=s) for s in seeds]
+    coded = runs[0]
     avg = run_simulation(FedAvg(cfg, epochs=3), bench, rounds=6, eval_every=3)
     assert coded.comm.measured
-    assert coded.final("mAP") >= base.final("mAP") - 0.03
+    assert (np.mean([r.final("mAP") for r in runs])
+            >= np.mean([r.final("mAP") for r in base]) - 0.03)
     # measured wire strictly below dense FedAvg, and >= 50% below
     assert coded.comm.total < 0.5 * avg.comm.total
     # formulas keep reporting the dense payload as the cross-check oracle

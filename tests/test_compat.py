@@ -1,72 +1,47 @@
-"""JAX version-compat shim tests (run on whatever JAX is installed)."""
-import types
-
+"""Sharding-API helper tests (``repro.common.compat``)."""
 import jax
 import numpy as np
-import pytest
 
 from repro.common import compat
+from repro.sharding.specs import auto_mesh
 
-
-def test_resolve_shard_map_new_layout():
-    sentinel = object()
-    fake_jax = types.SimpleNamespace(shard_map=sentinel)
-    assert compat.resolve_shard_map(fake_jax) is sentinel
-
-
-def test_resolve_shard_map_old_layout():
-    fake_jax = types.SimpleNamespace()           # no public shard_map
-    fn = compat.resolve_shard_map(fake_jax)
-    assert callable(fn)
-
-
-def test_adapt_check_kwarg_layouts():
-    new = frozenset({"f", "mesh", "in_specs", "out_specs", "check_vma"})
-    old = frozenset({"f", "mesh", "in_specs", "out_specs", "check_rep"})
-    assert compat.adapt_check_kwarg(new, None) == {}
-    assert compat.adapt_check_kwarg(new, True) == {"check_vma": True}
-    assert compat.adapt_check_kwarg(new, False) == {"check_vma": False}
-    # 0.4.x checker rejects valid grad programs: always off there
-    for v in (None, True, False):
-        assert compat.adapt_check_kwarg(old, v) == {"check_rep": False}
-    assert compat.adapt_check_kwarg(frozenset({"f"}), True) == {}
+P = jax.sharding.PartitionSpec
 
 
 def test_shard_map_executes_on_installed_jax():
-    """The shimmed shard_map + set_mesh run a real collective program."""
-    if jax.device_count() < 1:
-        pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("x",))
-    P = jax.sharding.PartitionSpec
+    """shard_map runs a real collective program under a set mesh."""
+    mesh = auto_mesh((1,), ("x",))
 
     def f(a):
         return jax.lax.psum(a, "x")
 
     fn = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P("x"),
                                   out_specs=P(), check_vma=True))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = fn(np.arange(4.0, dtype=np.float32))
     np.testing.assert_allclose(np.asarray(out), np.arange(4.0))
 
 
-def test_set_mesh_is_context_manager():
-    mesh = jax.make_mesh((1,), ("x",))
-    with compat.set_mesh(mesh):
-        pass                                     # usable as a context
-
-
 def test_axis_size_and_pcast_inside_shard_map():
-    mesh = jax.make_mesh((1,), ("x",))
-    P = jax.sharding.PartitionSpec
+    """pcast_varying accepts values already varying over the axis (a
+    sharded input) and values that are not (a replicated input)."""
+    mesh = auto_mesh((1,), ("x",))
 
-    def f(a):
-        s = compat.axis_size("x")
-        return compat.pcast_varying(a, ("x",)) * s
+    def f(a, b):
+        s = jax.lax.axis_size("x")
+        bv = compat.pcast_varying(b, ("x",))
+        assert "x" in jax.typeof(bv).vma
+        return compat.pcast_varying(a, ("x",)) * s + bv
 
-    fn = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P("x"),
+    fn = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(P("x"), P()),
                                   out_specs=P("x")))
-    out = fn(np.ones(2, np.float32))
-    np.testing.assert_allclose(np.asarray(out), np.ones(2))
+    out = fn(np.ones(2, np.float32), np.ones(2, np.float32))
+    np.testing.assert_allclose(np.asarray(out), 2 * np.ones(2))
+
+
+def test_auto_mesh_axes_are_auto():
+    mesh = auto_mesh((1, 1), ("data", "model"))
+    assert all(t == jax.sharding.AxisType.Auto for t in mesh.axis_types)
 
 
 def test_default_interpret_matches_backend():

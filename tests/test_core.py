@@ -121,3 +121,60 @@ def test_tying_loss():
     prev = {"w": jnp.array([1.0, 1.0])}
     assert float(tying_loss(th, prev, lam_l1=1.0)) == pytest.approx(1.0)
     assert float(tying_loss(th, th, lam_l1=1.0)) == 0.0
+
+
+@pytest.mark.parametrize("n", [1, 5, 64, 100])
+def test_pairwise_sum_same_bits_in_numpy_and_jax(n):
+    x = np.random.default_rng(n).standard_normal((n, 7)).astype(np.float32)
+    from repro.common.precision import pairwise_sum
+    got = np.asarray(pairwise_sum(jnp.asarray(x), 0))
+    np.testing.assert_array_equal(got, pairwise_sum(x, 0))
+    np.testing.assert_allclose(got, x.sum(0), rtol=1e-5, atol=1e-5)
+
+
+def test_broadcast_rows_cotangent_is_pairwise_sum():
+    """The gradient of a broadcast over rows is summed in pairwise_sum's
+    order, bit for bit — not by a backend reduction."""
+    import jax
+    from repro.common.precision import broadcast_rows, pairwise_sum
+    rng = np.random.default_rng(0)
+    v = jnp.asarray(rng.standard_normal(6).astype(np.float32))
+    g = jnp.asarray(rng.standard_normal((37, 6)).astype(np.float32))
+    out, vjp = jax.vjp(lambda u: broadcast_rows(u, 37), v)
+    np.testing.assert_array_equal(np.asarray(out),
+                                  np.broadcast_to(np.asarray(v), (37, 6)))
+    np.testing.assert_array_equal(np.asarray(vjp(g)[0]),
+                                  np.asarray(pairwise_sum(g, 0)))
+
+
+def test_client_gradient_independent_of_client_count():
+    """A client's gradient in a vmapped step is the same whether the
+    program holds 3 clients or that one alone (the sharded engine's
+    per-chip client blocks against the stacked engine's whole C)."""
+    import jax
+    from repro.core import edge_model as EM
+    cfg = EM.EdgeModelConfig(n_classes=32)
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    theta = jax.vmap(lambda k: EM.init_adaptive_layers(k, cfg))(keys)
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.standard_normal((3, 16, cfg.proto_dim)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 32, (3, 16)), jnp.int32)
+    grad = jax.jit(jax.vmap(jax.grad(EM.ce_loss)))
+    whole = grad(theta, x, y)
+    one = grad(jax.tree.map(lambda l: l[1:2], theta), x[1:2], y[1:2])
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(one)):
+        np.testing.assert_array_equal(np.asarray(a[1:2]), np.asarray(b))
+
+
+def test_log_softmax_rows_matches_jax():
+    import jax
+    from repro.core.edge_model import log_softmax_rows
+    z = jnp.asarray(np.random.default_rng(2).standard_normal((9, 40)) * 5,
+                    jnp.float32)
+    np.testing.assert_allclose(np.asarray(log_softmax_rows(z)),
+                               np.asarray(jax.nn.log_softmax(z)),
+                               rtol=1e-5, atol=1e-5)
+    g = jax.grad(lambda u: log_softmax_rows(u)[:, 3].sum())(z)
+    gr = jax.grad(lambda u: jax.nn.log_softmax(u)[:, 3].sum())(z)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(gr),
+                               rtol=1e-5, atol=1e-6)

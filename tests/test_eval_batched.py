@@ -24,6 +24,7 @@ from repro.data import FederatedReIDBenchmark
 from repro.evalreid import evaluate_retrieval_batched
 from repro.evalreid.batched import max_match_bound
 from repro.federated import run_simulation
+from repro.sharding.specs import engine_mesh
 
 
 def _random_problem(rng, C=3, T=2, Q=6, G=40, F=8, n_ids=12):
@@ -192,7 +193,7 @@ def test_simulation_rejects_unknown_eval_backend(bench, cfg):
 def test_sharded_eval_round_matches_device_program():
     from repro.federated.base import sharded_eval_fn, stacked_eval_program
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = engine_mesh(jax.devices()[:1])
     cfg = EdgeModelConfig()
     rng = np.random.default_rng(5)
     C, T, Q, G = 4, 2, 6, 30

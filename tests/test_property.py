@@ -117,42 +117,6 @@ def test_checkpoint_roundtrip(seed):
             np.testing.assert_allclose(a, b)
 
 
-@settings(max_examples=40, deadline=None)
-@given(st.sets(st.sampled_from(
-    ["mesh", "in_specs", "out_specs", "auto", "check_rep", "check_vma"])),
-    st.one_of(st.none(), st.booleans()))
-def test_shard_map_shim_check_kwarg(extra_params, check_vma):
-    """The compat shim maps check_vma onto whatever signature the resolved
-    shard_map exposes: passthrough on the new layout, always-off check_rep
-    on the 0.4.x layout, nothing when neither kwarg exists."""
-    from repro.common.compat import adapt_check_kwarg
-    params = frozenset({"f"} | extra_params)
-    kw = adapt_check_kwarg(params, check_vma)
-    if "check_vma" in params:
-        assert kw == ({} if check_vma is None else {"check_vma": check_vma})
-    elif "check_rep" in params:
-        assert kw == {"check_rep": False}
-    else:
-        assert kw == {}
-    assert set(kw) <= params
-
-
-@settings(max_examples=20, deadline=None)
-@given(st.booleans())
-def test_shard_map_shim_resolves_both_layouts(new_layout):
-    """resolve_shard_map finds shard_map on a new-layout module (public
-    attribute) and falls back to jax.experimental on the old layout."""
-    import types
-    from repro.common.compat import resolve_shard_map
-    sentinel = object()
-    if new_layout:
-        mod = types.SimpleNamespace(shard_map=sentinel)
-        assert resolve_shard_map(mod) is sentinel
-    else:
-        mod = types.SimpleNamespace()        # 0.4.x: no jax.shard_map
-        assert callable(resolve_shard_map(mod))
-
-
 _payload = hnp.arrays(np.float32, st.integers(1, 200),
                       elements=st.floats(-100, 100, width=32))
 

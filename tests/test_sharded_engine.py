@@ -29,6 +29,7 @@ from repro.core import edge_model as EM
 from repro.core.edge_model import EdgeModelConfig
 from repro.data import FederatedReIDBenchmark
 from repro.federated import run_simulation
+from repro.sharding.specs import engine_mesh
 
 
 @pytest.fixture(scope="module")
@@ -115,7 +116,7 @@ def test_fedavg_sharded_matches_host(bench, cfg):
 
 def test_sharded_server_round_zero_mask_row_inert(cfg):
     strat = FedSTIL(cfg, n_clients=4, epochs=1, wire_dtype="float32")
-    strat.mesh = jax.make_mesh((1, 1), ("data", "model"))
+    strat.mesh = engine_mesh(jax.devices()[:1])
     C = 4
     theta = jax.vmap(lambda k: EM.init_adaptive_layers(k, cfg))(
         jax.random.split(jax.random.PRNGKey(0), C))

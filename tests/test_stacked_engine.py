@@ -23,6 +23,7 @@ from repro.data import FederatedReIDBenchmark
 from repro.federated import run_simulation
 from repro.kernels import ops
 from repro.lifelong import STL
+from repro.sharding.specs import engine_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -221,6 +222,25 @@ def test_fused_aggregate_fully_zero_w():
     assert (np.asarray(B) == 0).all() and (np.asarray(Wn) == 0).all()
 
 
+def test_fused_aggregate_row_block_matches_full():
+    """A row block (rows row0.., diagonal at column row0 + i) gives exactly
+    the whole-matrix kernel's rows — what each shard of the sharded
+    engine computes."""
+    rng = np.random.default_rng(11)
+    w = jnp.asarray(rng.random((12, 12)).astype(np.float32))
+    w = w.at[5].set(0.0)                              # a zero row stays zero
+    thetas = jnp.asarray(rng.standard_normal((12, 300)).astype(np.float32))
+    for backend in ("interpret", "ref"):
+        B, Wn = ops.fused_relevance_aggregate(w, thetas, backend=backend)
+        for row0, rows in ((0, 4), (4, 4), (8, 4)):
+            Bb, Wnb = ops.fused_relevance_aggregate(
+                w[row0:row0 + rows], thetas, row0, backend=backend)
+            np.testing.assert_array_equal(np.asarray(Wnb),
+                                          np.asarray(Wn[row0:row0 + rows]))
+            np.testing.assert_array_equal(np.asarray(Bb),
+                                          np.asarray(B[row0:row0 + rows]))
+
+
 # ---------------------------------------------------------------------------
 # sharded path (single-device mesh exercises the program + specs)
 # ---------------------------------------------------------------------------
@@ -229,7 +249,7 @@ def test_fused_aggregate_fully_zero_w():
 def test_sharded_fused_aggregate_matches_kernel():
     from repro.core.fedstil import sharded_fused_aggregate
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = engine_mesh(jax.devices()[:1])
     rng = np.random.default_rng(9)
     w = jnp.asarray(rng.random((8, 8)).astype(np.float32))
     thetas = jnp.asarray(rng.standard_normal((8, 512)).astype(np.float32))

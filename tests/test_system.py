@@ -57,7 +57,6 @@ def test_debug_mesh_sharding_subprocess():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np, functools
-from repro.common.compat import set_mesh
 from repro.configs import get_config
 from repro.launch import steps as STEPS
 from repro.launch.mesh import make_debug_mesh
@@ -72,7 +71,7 @@ st = TR.init_train_state(cfg, jax.random.PRNGKey(0), tp=2)
 rng = np.random.default_rng(0)
 batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 32)), jnp.int32),
          "labels": jnp.asarray(rng.integers(0, cfg.vocab_size, (4, 32)), jnp.int32)}
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     tr, opt, metrics = fn(st.frozen, st.B, st.trainable, st.opt_state, batch)
 step0 = TR.make_train_step(cfg, tie_lambda=1e-4)
 tr0, opt0, m0 = step0(st.frozen, st.B, st.trainable, st.opt_state, batch)
