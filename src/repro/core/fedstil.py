@@ -235,39 +235,56 @@ class FedSTIL(Strategy):
             return None
         return mem.sample(self.rng, self.batch)
 
+    def _rehearsal_rows(self, stacked):
+        if self.use_rehearsal and len(stacked.host["memory"][0]):
+            return self.batch // 2
+        return 0
+
     def local_train_stacked(self, stacked, bx, by, protos_list, labels_list,
                             rnd):
-        stacked, _ = super().local_train_stacked(stacked, bx, by,
-                                                 protos_list, labels_list, rnd)
-        # theta = B ⊙ alpha + A for all clients at once (leaf-wise, so the
-        # stacked leading dim passes straight through)
-        theta = combine(stacked.extras["reg_B"], stacked.trainable["alpha"],
-                        stacked.trainable["A"])
+        with obs.span("local.train", cat="stage", round=rnd) as sp:
+            stacked, _ = super().local_train_stacked(
+                stacked, bx, by, protos_list, labels_list, rnd)
+            # theta = B ⊙ alpha + A for all clients at once (leaf-wise, so
+            # the stacked leading dim passes straight through)
+            theta = combine(stacked.extras["reg_B"],
+                            stacked.trainable["alpha"], stacked.trainable["A"])
+            sp.sync((stacked.trainable, theta))
         stacked.extras["reg_prev_theta"] = theta
 
+        C = len(protos_list)
         if self.use_rehearsal:
             # host memories exist only for the C real clients; on a mesh
             # theta carries Cp >= C padded rows, so slice before the vmap
-            C = len(protos_list)
-            theta_real = jax.tree.map(lambda l: l[:C], theta)
-            protos = jnp.asarray(np.stack(protos_list))      # (C, N, D)
-            outputs = np.asarray(jax.vmap(
-                lambda th, p: EM.adaptive_forward(th, p)[0])(theta_real,
-                                                             protos))
-            for c, mem in enumerate(stacked.host["memory"]):
-                mem.add_task(protos_list[c], labels_list[c], outputs[c],
-                             task_id=rnd)
+            N = len(protos_list[0])
+            with obs.span("local.forward", cat="stage", round=rnd,
+                          h2d_bytes=obs.device_nbytes(*protos_list),
+                          d2h_bytes=C * N * self.cfg.feat_dim * 4):
+                theta_real = jax.tree.map(lambda l: l[:C], theta)
+                protos = jnp.asarray(np.stack(protos_list))  # (C, N, D)
+                outputs = np.asarray(jax.vmap(
+                    lambda th, p: EM.adaptive_forward(th, p)[0])(theta_real,
+                                                                 protos))
+            with obs.span("local.rehearsal", cat="stage", round=rnd,
+                          rows=C * N):
+                for c, mem in enumerate(stacked.host["memory"]):
+                    mem.add_task(protos_list[c], labels_list[c], outputs[c],
+                                 task_id=rnd)
 
-        feats = np.stack([np.asarray(p, np.float32).mean(0)
-                          for p in protos_list])
         lead = jax.tree.leaves(theta)[0].shape[0]
-        if lead > feats.shape[0]:
-            # mesh padding rows: zero features — the validity mask keeps
-            # them out of the relevance ring, so the values never matter
-            feats = np.concatenate(
-                [feats, np.zeros((lead - feats.shape[0], feats.shape[1]),
-                                 np.float32)])
-        return stacked, {"theta": theta, "task_feature": jnp.asarray(feats)}
+        D = np.shape(protos_list[0])[-1]
+        with obs.span("local.task_feature", cat="stage", round=rnd,
+                      h2d_bytes=lead * D * 4) as sp:
+            feats = np.stack([np.asarray(p, np.float32).mean(0)
+                              for p in protos_list])
+            if lead > C:
+                # mesh padding rows: zero features — the validity mask
+                # keeps them out of the relevance ring, so the values
+                # never matter
+                feats = np.concatenate(
+                    [feats, np.zeros((lead - C, D), np.float32)])
+            task_feature = sp.sync(jnp.asarray(feats))
+        return stacked, {"theta": theta, "task_feature": task_feature}
 
     def _stacked_server_fns(self, theta_example):
         """Staged jitted pieces of the stacked server round.
@@ -396,7 +413,9 @@ class FedSTIL(Strategy):
         # mass/density) — computed inside the relevance launch above;
         # this is a no-op readback unless a tracer is active
         obs.metric("server.relevance", mets, round=rnd)
-        self.last_W = np.asarray(Wn)
+        with obs.span("server.readback", cat="stage", round=rnd,
+                      d2h_bytes=obs.device_nbytes(Wn)):
+            self.last_W = np.asarray(Wn)
         # all-zero rows (no relevant neighbours yet) keep their old base
         nz = jnp.sum(Wn, axis=1) > 0
         with obs.span("server.unflatten", cat="stage", round=rnd) as sp:

@@ -341,8 +341,10 @@ class Strategy:
         from the encoded buffer shapes — zero host readbacks."""
         from repro.common.pytree import (tree_bytes, tree_flatten_stacked,
                                          tree_unflatten_stacked)
-        lossy, verbatim = split(tree)
-        mat, meta = tree_flatten_stacked(lossy)
+        with obs.span("comm.flatten", cat="stage") as sp:
+            lossy, verbatim = split(tree)
+            mat, meta = tree_flatten_stacked(lossy)
+            sp.sync(mat)
         C = mat.shape[0]
         prog = self._stacked_wire_program(which, int(mat.shape[1]))
         with obs.span(f"comm.{which}", cat="codec") as sp:
@@ -352,11 +354,13 @@ class Strategy:
         # reference staleness, kept energy, keep-rate); no-op readback
         # unless a tracer is active
         obs.metric("comm.encode", prog.last_metrics, direction=which)
-        per_client = prog.per_client_bytes(buffers)
-        if verbatim is not None:
-            per_client += tree_bytes(verbatim) // max(C, 1)
-        decoded = tree_unflatten_stacked(recon, meta)
-        return join(decoded, verbatim), per_client
+        with obs.span("comm.unflatten", cat="stage") as sp:
+            per_client = prog.per_client_bytes(buffers)
+            if verbatim is not None:
+                per_client += tree_bytes(verbatim) // max(C, 1)
+            decoded = join(tree_unflatten_stacked(recon, meta), verbatim)
+            sp.sync(decoded)
+        return decoded, per_client
 
     def wire_upload_stacked(self, upload):
         return self._wire_roundtrip_stacked(
@@ -455,42 +459,50 @@ class Strategy:
         exactly where the host path calls ``memory.sample``."""
         return None
 
+    def _rehearsal_rows(self, stacked: StackedClientState) -> int:
+        """Rehearsal rows each epoch batch of this round carries."""
+        return 0
+
     def gather_round_batches(self, stacked: StackedClientState,
                              protos_list, labels_list):
-        """Pre-gather every client's epoch minibatches as dense arrays:
-        (C, epochs, B, D) prototypes + (C, epochs, B) labels.
+        """Pre-gather every client's epoch minibatches as dense host
+        arrays: (C, epochs, B, D) prototypes + (C, epochs, B) labels (the
+        round loop uploads them).
 
         Draws from ``self.rng`` in the host engine's exact order (client-
         major, then epoch; rehearsal pool first, then per-epoch batch and
         rehearsal indices) so both engines train on identical batches.
         """
         C = len(protos_list)
-        bxs, bys = [], []
-        for c in range(C):
-            p, l = protos_list[c], labels_list[c]
-            n = len(p)
-            reh = self._gather_rehearsal(stacked, c)
-            ex, ey = [], []
-            for _ in range(self.epochs):
-                idx = self.rng.choice(n, size=min(self.batch, n),
-                                      replace=n < self.batch)
-                px, py = p[idx], l[idx]
-                if reh is not None:
-                    rx, ry = reh
-                    ridx = self.rng.choice(len(rx), size=self.batch // 2,
-                                           replace=True)
-                    px = np.concatenate([px, rx[ridx]])
-                    py = np.concatenate([py, ry[ridx]])
-                ex.append(px)
-                ey.append(py)
-            bxs.append(np.stack(ex))
-            bys.append(np.stack(ey))
-        shapes = {b.shape for b in bxs}
-        if len(shapes) > 1:
-            raise ValueError(
-                f"stacked engine needs uniform per-client batch shapes, "
-                f"got {sorted(shapes)} (ragged tasks/rehearsal pools)")
-        return jnp.asarray(np.stack(bxs)), jnp.asarray(np.stack(bys))
+        rows = C * self.epochs * (min(self.batch, len(protos_list[0]))
+                                  + self._rehearsal_rows(stacked))
+        with obs.span("gather.sample", cat="stage", rows=rows):
+            bxs, bys = [], []
+            for c in range(C):
+                p, l = protos_list[c], labels_list[c]
+                n = len(p)
+                reh = self._gather_rehearsal(stacked, c)
+                ex, ey = [], []
+                for _ in range(self.epochs):
+                    idx = self.rng.choice(n, size=min(self.batch, n),
+                                          replace=n < self.batch)
+                    px, py = p[idx], l[idx]
+                    if reh is not None:
+                        rx, ry = reh
+                        ridx = self.rng.choice(len(rx), size=self.batch // 2,
+                                               replace=True)
+                        px = np.concatenate([px, rx[ridx]])
+                        py = np.concatenate([py, ry[ridx]])
+                    ex.append(px)
+                    ey.append(py)
+                bxs.append(np.stack(ex))
+                bys.append(np.stack(ey))
+            shapes = {b.shape for b in bxs}
+            if len(shapes) > 1:
+                raise ValueError(
+                    f"stacked engine needs uniform per-client batch shapes, "
+                    f"got {sorted(shapes)} (ragged tasks/rehearsal pools)")
+            return np.stack(bxs), np.stack(bys)
 
     def _stacked_loss_extras(self, stacked: StackedClientState):
         ex = {k: v for k, v in stacked.extras.items() if k.startswith("reg_")}

@@ -62,7 +62,6 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -87,7 +86,6 @@ class SimulationResult:
     comm: CommLog
     storage_bytes: int
     rounds: List[Dict[str, float]]      # per-eval-round mean metrics
-    server_time_s: float = 0.0          # wall time inside server_round
     eval_on_device: bool = False        # batched device eval (else host)
 
     def final(self, key="mAP") -> float:
@@ -351,7 +349,6 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
     tracker = LifelongTracker(C)
     comm = CommLog()
     eval_rounds: List[Dict[str, float]] = []
-    server_s = 0.0
 
     protos = _pre_extract_prototypes(bench, g_params)
     cache = _EvalCache(bench, protos, device=eval_backend == "device")
@@ -381,7 +378,10 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
             with obs.span("round.gather", cat="phase", round=rnd):
                 bx, by = strategy.gather_round_batches(stacked, protos_list,
                                                        labels_list)
-                bx, by = strategy.place_batches(bx, by)
+                with obs.span("gather.upload", cat="stage", round=rnd,
+                              h2d_bytes=obs.device_nbytes(bx, by)) as sp:
+                    bx, by = sp.sync(strategy.place_batches(
+                        jnp.asarray(bx), jnp.asarray(by)))
             with obs.span("round.local_train", cat="phase", round=rnd) as sp:
                 stacked, upload = strategy.local_train_stacked(
                     stacked, bx, by, protos_list, labels_list, rnd)
@@ -402,18 +402,21 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
                     comm.log_c2s_many(rnd, formula, C)
 
             if strategy.uses_server and upload is not None:
-                t0 = time.perf_counter()
                 with obs.span("round.server", cat="phase", round=rnd) as sp:
                     dispatch = strategy.server_round_stacked(
                         rnd, upload, valid=valid_mask)
                     if dispatch is not None:
                         sp.sync(dispatch)   # dict shape is strategy-specific
-                server_s += time.perf_counter() - t0
                 if dispatch is not None:
                     per_client = strategy.stacked_dispatch_bytes(dispatch,
                                                                  lead)
-                    nz = np.asarray(dispatch["nz"])[:C] if "nz" in dispatch \
-                        else np.ones((C,), bool)
+                    if "nz" in dispatch:
+                        with obs.span("server.readback", cat="stage",
+                                      round=rnd, d2h_bytes=obs.device_nbytes(
+                                          dispatch["nz"])):
+                            nz = np.asarray(dispatch["nz"])[:C]
+                    else:
+                        nz = np.ones((C,), bool)
                     if strategy.dispatch_codec is not None:
                         # the stacked wire model is a BROADCAST stream: the
                         # codec encodes (and the delta refs advance for)
@@ -461,8 +464,7 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
         storage = max(strategy.storage_bytes(strategy.client_view(stacked, c))
                       for c in range(C))
         return SimulationResult(strategy.name, tracker, comm, storage,
-                                eval_rounds, server_time_s=server_s,
-                                eval_on_device=eval_dev)
+                                eval_rounds, eval_on_device=eval_dev)
 
     accepts_raw = "raw_images" in inspect.signature(strategy.local_train).parameters
 
@@ -495,10 +497,8 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
                     uploads[c] = up
 
         if strategy.uses_server and uploads:
-            t0 = time.perf_counter()
             with obs.span("round.server", cat="phase", round=rnd):
                 dispatches = strategy.server_round(rnd, uploads)
-            server_s += time.perf_counter() - t0
             with obs.span("round.apply", cat="phase", round=rnd):
                 for c, d in dispatches.items():
                     if d:
@@ -527,4 +527,4 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
 
     storage = max(strategy.storage_bytes(states[c]) for c in range(C))
     return SimulationResult(strategy.name, tracker, comm, storage, eval_rounds,
-                            server_time_s=server_s, eval_on_device=eval_dev)
+                            eval_on_device=eval_dev)

@@ -22,7 +22,11 @@ Dry-run at scale: PYTHONPATH=src python -m repro.launch.fed_round \
 ``--trace out.jsonl`` records a repro.obs span per action (demo /
 stacked-demo / lower, device-synced wall time each); inspect with
 ``python -m repro.obs.report out.jsonl`` or export a Perfetto trace via
-``--chrome``.
+``--chrome``. ``--profile DIR`` runs the traced actions inside
+``jax.profiler.trace(DIR)``: the device trace holds the spans on its own
+clock, and ``perf/trace_reduce.reduce(load(<DIR>/plugins/profile/*/
+*.xplane.pb), window="fed_round.demo", kernels={})`` reads device busy
+time and idle time by span from it.
 """
 import os as _os
 import sys as _sys
@@ -34,6 +38,7 @@ else:
         "XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
 import argparse
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -236,11 +241,17 @@ def main():
     ap.add_argument("--trace", default=None, metavar="OUT.jsonl",
                     help="write a repro.obs telemetry JSONL (one span per "
                          "action); read it with python -m repro.obs.report")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record a jax.profiler device trace of the traced "
+                         "actions into DIR")
     args = ap.parse_args()
     enable_compile_cache()
-    tracer = obs.Tracer(path=args.trace) if args.trace else obs.NullTracer()
+    tracer = (obs.Tracer(path=args.trace) if args.trace or args.profile
+              else obs.NullTracer())
+    profile = (jax.profiler.trace(args.profile) if args.profile
+               else contextlib.nullcontext())
     try:
-        with obs.active(tracer):
+        with obs.active(tracer), profile:
             if args.stacked_demo:
                 with obs.span("fed_round.stacked_demo", cat="phase"):
                     _stacked_demo()

@@ -13,11 +13,18 @@ With ``--trace out.jsonl`` the run executes under a live ``repro.obs``
 tracer: serve.batch / serve.index_refresh spans, bucket-exact latency
 histograms and rolling QPS from a ``ServeStats`` wired into the batcher,
 and IVF probe metrics when ``--mode ivf``. Inspect the sink with
-``python -m repro.obs.report out.jsonl``.
+``python -m repro.obs.report out.jsonl``. ``--profile DIR`` runs the
+traced serving inside ``jax.profiler.trace(DIR)``: the device trace holds
+the spans (``serve.admit`` / ``serve.upload`` / ``serve.launch`` /
+``serve.readback`` / ``serve.complete``) on its own clock, and
+``perf/trace_reduce.reduce(load(<DIR>/plugins/profile/*/*.xplane.pb),
+window="serve.run", kernels={})`` reads device busy time and idle time by
+span from it.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -50,12 +57,19 @@ def main():
     ap.add_argument("--trace", default=None, metavar="OUT.jsonl",
                     help="write a repro.obs telemetry JSONL (spans + serve "
                          "stats); read it with python -m repro.obs.report")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record a jax.profiler device trace of the traced "
+                         "serving into DIR")
     args = ap.parse_args()
     enable_compile_cache()
 
-    tracer = obs.Tracer(path=args.trace) if args.trace else obs.NullTracer()
-    with obs.active(tracer):
-        _serve(args)
+    tracer = (obs.Tracer(path=args.trace) if args.trace or args.profile
+              else obs.NullTracer())
+    profile = (jax.profiler.trace(args.profile) if args.profile
+               else contextlib.nullcontext())
+    with obs.active(tracer), profile:
+        with obs.span("serve.run", cat="phase", mode=args.mode):
+            _serve(args)
     if args.trace:
         tracer.close()
         print(f"telemetry: {args.trace}  "

@@ -9,8 +9,11 @@ Three layers, all off-by-default-cheap:
     the null tracer (one attribute load + a no-op context manager, no
     timestamps, no allocation), so instrumented hot loops stay untraced
     for free. ``Tracer`` buffers events in memory and writes JSONL on
-    ``close()``; ``chrome_trace`` converts a run to the Chrome-trace /
-    Perfetto ``traceEvents`` format.
+    ``close()``; each span carries its ``id`` and ``parent`` and enters a
+    ``jax.profiler.TraceAnnotation``, so a profiler session holds the
+    spans on the device trace's clock; host<->device byte counters ride
+    on spans as attributes; ``chrome_trace`` converts a run to the
+    Chrome-trace / Perfetto ``traceEvents`` format.
   * ``obs.metrics`` — device-side metric math that runs INSIDE existing
     jitted programs (relevance row mass/sparsity, ring staleness, codec
     keep-rate/residual-norm, IVF probe hit-rates) plus the host-side

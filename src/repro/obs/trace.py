@@ -21,16 +21,26 @@ When no tracer is active the helpers dispatch to the null tracer: the
 span context manager is a shared constant object, ``sp.sync(x)`` returns
 ``x`` WITHOUT blocking (async dispatch is preserved — tracing off must
 not add device-sync points), and ``metric()`` returns before touching
-its value dict. That is the off-by-default-cheap contract the server
-bench gates at <2% of stacked round wall-time.
+its value dict. Tracing off costs one attribute load and a no-op context
+manager per span; what tracing on costs on the chip is in ``PERF.md``.
 
 Timing semantics with a tracer active: a span records host wall time
 (``perf_counter``) from ``__enter__`` to ``__exit__``; calling
 ``sp.sync(arrays)`` inside the body blocks until the device work backing
 ``arrays`` is done, so the recorded duration covers execution, not just
-dispatch. Event schema (one JSON object per line):
+dispatch. Each span also opens a ``jax.profiler.TraceAnnotation`` of its
+name for its body, so a profiler session (``jax.profiler.trace``) holds
+the program's spans on the device trace's clock. Every span carries an
+``id`` (per tracer, in opening order) and its ``parent``: the id of the
+innermost span open on the same tracer when it opened, or ``None``.
 
-    {"kind": "span",   "name": ..., "t0": s, "dur": s, ...attrs}
+Counters ride on spans as keyword attributes given when the span opens,
+computed from shapes known before the copy: ``h2d_bytes`` / ``d2h_bytes``
+(bytes the body copies host to device / device to host), ``rows``,
+``slots`` and ``depth``. Event schema (one JSON object per line):
+
+    {"kind": "span",   "name": ..., "id": n, "parent": n|null,
+     "t0": s, "dur": s, ...attrs}
     {"kind": "metric", "name": ..., "values": {...}, "t0": s, ...attrs}
     {"kind": "meta",   ...}
 """
@@ -83,9 +93,9 @@ class RunLog:
 
 
 class _Span:
-    """One live span (reused API surface with ``_NULL_SPAN``)."""
+    """One live span of an active ``Tracer``."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0")
+    __slots__ = ("tracer", "name", "attrs", "t0", "id", "parent", "_annot")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self.tracer = tracer
@@ -100,13 +110,20 @@ class _Span:
         return jax.block_until_ready(value)
 
     def __enter__(self):
+        import jax
+        self.id, self.parent = self.tracer._open()
+        self._annot = jax.profiler.TraceAnnotation(self.name)
+        self._annot.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        self.tracer._emit({"kind": "span", "name": self.name,
-                           "t0": self.t0, "dur": t1 - self.t0, **self.attrs})
+        self._annot.__exit__(*exc)
+        self.tracer._open_ids.remove(self.id)
+        self.tracer._emit({"kind": "span", "name": self.name, "id": self.id,
+                           "parent": self.parent, "t0": self.t0,
+                           "dur": t1 - self.t0, **self.attrs})
         return False
 
 
@@ -161,7 +178,16 @@ class Tracer(NullTracer):
     def __init__(self, path=None):
         self.events: List[Dict[str, Any]] = []
         self.runlog = RunLog(path) if path is not None else None
+        self._open_ids: List[int] = []      # ids of the spans open now
+        self._next_id = 0
         self.meta(epoch=time.perf_counter())
+
+    def _open(self):
+        """(id, parent) for a span opening now; it becomes the innermost."""
+        sid, self._next_id = self._next_id, self._next_id + 1
+        parent = self._open_ids[-1] if self._open_ids else None
+        self._open_ids.append(sid)
+        return sid, parent
 
     def _emit(self, event: Dict[str, Any]) -> None:
         self.events.append(event)
@@ -183,6 +209,15 @@ class Tracer(NullTracer):
     def close(self) -> None:
         if self.runlog is not None:
             self.runlog.close()
+
+
+def device_nbytes(*arrays) -> int:
+    """Bytes ``arrays`` occupy on the device, from their shapes: a host
+    array counts at the dtype ``jnp.asarray`` gives it (64-bit types are
+    narrowed unless x64 is on). For ``h2d_bytes``/``d2h_bytes``."""
+    import jax
+    return sum(int(a.size) * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+               for a in arrays)
 
 
 def _jsonable(values: Dict[str, Any]) -> Dict[str, Any]:

@@ -126,10 +126,10 @@ class ContinuousBatcher:
         self._queues[client].append((t, np.asarray(proto, np.float32)))
         return t
 
-    def _admit(self) -> List[int]:
-        """Slots granted per client this step, honoring policy + budget."""
+    def _admit(self, want: List[int]) -> List[int]:
+        """Slots granted per client this step, honoring policy + budget;
+        ``want[c]`` is what client c could use (its backlog, up to B)."""
         C = len(self._queues)
-        want = [min(len(q), self.batch) for q in self._queues]
         grant = [0] * C
         left = self.step_budget
         order = [(self._rr + i) % C for i in range(C)]
@@ -148,7 +148,8 @@ class ContinuousBatcher:
         else:
             order = range(C)
         # work conserving: leftover budget goes to remaining backlog in
-        # order (fifo does all its granting here)
+        # order (fifo does all its granting here), so the step grants
+        # min(sum(want), step_budget) slots in all
         for c in order:
             n = min(want[c] - grant[c], left)
             grant[c] += n
@@ -159,18 +160,21 @@ class ContinuousBatcher:
         """Run one coalesced launch over the admitted pending queries.
         Returns the tickets completed by this launch (empty when idle)."""
         depth = self.pending
-        self._qp[:] = 0.0
-        self._qmask[:] = 0.0
-        grant = self._admit()
-        taken: List[List[Ticket]] = []
-        for c, q in enumerate(self._queues):
-            row = []
-            while q and len(row) < grant[c]:
-                t, proto = q.popleft()
-                self._qp[c, len(row)] = proto
-                self._qmask[c, len(row)] = 1.0
-                row.append(t)
-            taken.append(row)
+        want = [min(len(q), self.batch) for q in self._queues]
+        with obs.span("serve.admit", cat="stage", depth=depth,
+                      slots=min(sum(want), self.step_budget)):
+            self._qp[:] = 0.0
+            self._qmask[:] = 0.0
+            grant = self._admit(want)
+            taken: List[List[Ticket]] = []
+            for c, q in enumerate(self._queues):
+                row = []
+                while q and len(row) < grant[c]:
+                    t, proto = q.popleft()
+                    self._qp[c, len(row)] = proto
+                    self._qmask[c, len(row)] = 1.0
+                    row.append(t)
+                taken.append(row)
         if not any(taken):
             return []
         n_slots = sum(len(row) for row in taken)
@@ -179,19 +183,20 @@ class ContinuousBatcher:
             # query_batch returns numpy: the readback IS the sync boundary
             ids, dists = self.engine.query_batch(self._qp, self._qmask)
         done = time.perf_counter()
-        out = []
-        for c, row in enumerate(taken):
-            for b, t in enumerate(row):
-                t.t_launch = launch
-                t.t_done = done
-                t.ids = ids[c, b]
-                t.dists = dists[c, b]
-                out.append(t)
-        if self.stats is not None:
-            self.stats.record_launch(
-                depth, self._deficit if self.policy == "drr" else None)
-            for t in out:
-                self.stats.record_ticket(t)
+        with obs.span("serve.complete", cat="stage", slots=n_slots):
+            out = []
+            for c, row in enumerate(taken):
+                for b, t in enumerate(row):
+                    t.t_launch = launch
+                    t.t_done = done
+                    t.ids = ids[c, b]
+                    t.dists = dists[c, b]
+                    out.append(t)
+            if self.stats is not None:
+                self.stats.record_launch(
+                    depth, self._deficit if self.policy == "drr" else None)
+                for t in out:
+                    self.stats.record_ticket(t)
         return out
 
     def drain(self) -> List[Ticket]:

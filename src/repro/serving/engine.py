@@ -370,14 +370,24 @@ class RetrievalEngine:
         """(C, B, proto_dim) padded queries + (C, B) validity -> ((C, B, k)
         ids, distances) as numpy. ONE device launch for all clients."""
         k = self.k if k is None else k
+        with obs.span("serve.upload", cat="stage",
+                      h2d_bytes=4 * (np.size(qp) + np.size(qmask))) as sp:
+            qp, qmask = sp.sync((jnp.asarray(qp, jnp.float32),
+                                 jnp.asarray(qmask, jnp.float32)))
+        with obs.span("serve.launch", cat="stage"):
+            ids, d = self._launch(qp, qmask, k)
+        with obs.span("serve.readback", cat="stage",
+                      d2h_bytes=obs.device_nbytes(ids, d)):
+            return np.asarray(ids), np.asarray(d)
+
+    def _launch(self, qp, qmask, k):
+        """Dispatch the mode's query program (no sync): device (ids, d)."""
         ix = self.index
-        qp = jnp.asarray(qp, jnp.float32)
-        qmask = jnp.asarray(qmask, jnp.float32)
         if self.mode == "int8":
-            ids, d = query_int8_program(
+            return query_int8_program(
                 self.theta, ix.bn_mu, ix.bn_sd, qp, qmask,
                 ix.gq, ix.gscale, ix.gn2, ix.gids, k=k, backend=self.backend)
-        elif self.mode == "ivf":
+        if self.mode == "ivf":
             if obs.is_active():
                 # tracing specialization: same launch also returns probe
                 # hit-rates + rows-scored ("serving.query_ivf_metrics")
@@ -387,16 +397,14 @@ class RetrievalEngine:
                     nprobe=self.nprobe, backend=self.backend,
                     with_metrics=True)
                 obs.metric("serve.ivf", mets, nprobe=self.nprobe)
-            else:
-                ids, d = query_ivf_program(
-                    self.theta, ix.bn_mu, ix.bn_sd, qp, qmask,
-                    ix.cent, ix.cn2, ix.bq, ix.pack, k=k,
-                    nprobe=self.nprobe, backend=self.backend)
-        else:
-            ids, d = query_fp32_program(
+                return ids, d
+            return query_ivf_program(
                 self.theta, ix.bn_mu, ix.bn_sd, qp, qmask,
-                ix.gf, ix.gids, k=k, backend=self.backend)
-        return np.asarray(ids), np.asarray(d)
+                ix.cent, ix.cn2, ix.bq, ix.pack, k=k,
+                nprobe=self.nprobe, backend=self.backend)
+        return query_fp32_program(
+            self.theta, ix.bn_mu, ix.bn_sd, qp, qmask,
+            ix.gf, ix.gids, k=k, backend=self.backend)
 
     def query_host(self, qp, qmask, *, k: Optional[int] = None):
         """The numpy oracle at this engine's current state (always fp32)."""

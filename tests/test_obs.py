@@ -163,6 +163,149 @@ def test_chrome_trace_export():
 
 
 # ---------------------------------------------------------------------------
+# tracer: span ids and parents, profiler annotations, counter attributes
+# ---------------------------------------------------------------------------
+
+
+def _spans(tr):
+    return {e["name"]: e for e in tr.events if e["kind"] == "span"}
+
+
+def test_nested_and_sibling_spans_carry_id_and_parent():
+    tr = T.Tracer()
+    with T.active(tr):
+        with T.span("round.local_train"):
+            with T.span("local.train"):
+                pass
+            with T.span("local.rehearsal"):
+                with T.span("rehearsal.inner"):
+                    pass
+        with T.span("round.server"):
+            pass
+    s = _spans(tr)
+    outer = s["round.local_train"]
+    assert outer["parent"] is None and s["round.server"]["parent"] is None
+    assert s["local.train"]["parent"] == outer["id"]
+    assert s["local.rehearsal"]["parent"] == outer["id"]
+    assert s["rehearsal.inner"]["parent"] == s["local.rehearsal"]["id"]
+    ids = [e["id"] for e in s.values()]
+    assert len(set(ids)) == len(ids)
+    # ids in opening order
+    assert (outer["id"] < s["local.train"]["id"] < s["local.rehearsal"]["id"]
+            < s["rehearsal.inner"]["id"] < s["round.server"]["id"])
+
+
+def test_exception_in_child_pops_it():
+    tr = T.Tracer()
+    with T.active(tr):
+        with T.span("round.gather"):
+            with pytest.raises(RuntimeError):
+                with T.span("gather.sample"):
+                    raise RuntimeError("boom")
+            with T.span("gather.upload"):
+                pass
+        with T.span("round.server"):
+            pass
+    s = _spans(tr)
+    assert s["gather.sample"]["parent"] == s["round.gather"]["id"]
+    assert s["gather.upload"]["parent"] == s["round.gather"]["id"]
+    assert s["round.server"]["parent"] is None
+    assert tr._open_ids == []
+
+
+class _Closed(Exception):
+    pass
+
+
+def test_raise_from_span_call_before_enter_leaves_no_open_span():
+    class Clock(T.Tracer):
+        def span(self, name, **attrs):
+            if name == "round.gather":
+                raise _Closed
+            return super().span(name, **attrs)
+
+    tr = Clock()
+    with T.active(tr):
+        with pytest.raises(_Closed):
+            with T.span("round.local_train"):
+                T.span("round.gather")
+        with T.span("round.server"):
+            pass
+    assert _spans(tr)["round.server"]["parent"] is None
+    assert tr._open_ids == []
+
+
+class _Annotation:
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+def test_active_span_enters_one_trace_annotation(monkeypatch):
+    jax = pytest.importorskip("jax")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    _Annotation.log = []
+    tr = T.Tracer()
+    with T.active(tr):
+        with T.span("round.server", cat="phase"):
+            with T.span("server.readback", cat="stage", d2h_bytes=8):
+                pass
+    assert _Annotation.log == [("enter", "round.server"),
+                               ("enter", "server.readback"),
+                               ("exit", "server.readback"),
+                               ("exit", "round.server")]
+
+
+def test_null_tracer_enters_no_annotation_and_records_nothing(monkeypatch):
+    jax = pytest.importorskip("jax")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    _Annotation.log = []
+    null = T.get_tracer()
+    assert not null.active
+    with T.span("round.server", cat="phase") as sp:
+        with T.span("server.readback", h2d_bytes=4) as inner:
+            x = object()
+            assert inner.sync(x) is x
+    assert sp is inner                       # the one shared null span
+    assert _Annotation.log == []
+    assert not hasattr(null, "events")
+
+
+def test_counter_attributes_survive_jsonl_and_chrome_trace(tmp_path):
+    path = tmp_path / "run.jsonl"
+    tr = T.Tracer(path=path)
+    attrs = {"h2d_bytes": 24_576_000, "d2h_bytes": 3_686_400, "rows": 14_400,
+             "slots": 256, "depth": 311}
+    with T.active(tr):
+        with T.span("serve.admit", cat="stage", **attrs):
+            pass
+    tr.close()
+    (span,) = [e for e in T.RunLog.read(path) if e["kind"] == "span"]
+    assert {k: span[k] for k in attrs} == attrs
+    assert span["parent"] is None and span["id"] == 0
+    (x,) = [e for e in T.chrome_trace(tr.events)["traceEvents"]
+            if e["ph"] == "X"]
+    assert {k: x["args"][k] for k in attrs} == attrs
+
+
+def test_device_nbytes_counts_device_dtypes():
+    jnp = pytest.importorskip("jax.numpy")
+    host = np.zeros((3, 5), np.int64)          # narrowed to int32 on device
+    assert T.device_nbytes(host) == 3 * 5 * 4
+    assert T.device_nbytes(np.zeros((2, 2), np.float32),
+                           jnp.zeros((7,), jnp.bool_)) == 16 + 7
+
+
+# ---------------------------------------------------------------------------
 # report aggregation + device metric helpers
 # ---------------------------------------------------------------------------
 
