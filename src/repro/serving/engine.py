@@ -10,9 +10,17 @@ numpy host oracle):
   2. score: squared euclidean distance to every resident row (int8 path
      dequantizes via per-row scale + precomputed norms inside the
      ``batched_int8_pairwise_dist`` kernel);
-  3. rank: empty slots pushed to +inf, ``lax.top_k`` on negated distances
-     (ties resolve to the lowest gallery index — the same deterministic
-     order as the numpy oracle's stable argsort);
+  3. rank: empty slots pushed to +inf, then top-k on negated distances,
+     exact and in two stages that never sort all G rows: each block of
+     128 contiguous rows (the lane width) is reduced to its best element,
+     ``lax.top_k`` picks the k best blocks, and a second ``lax.top_k``
+     ranks those blocks' k·128 rows, gathered in ascending row order.
+     Blocks compare in ``lax.top_k``'s own total float order (-0.0 below
+     +0.0), ties to the lower block, so ids and distances are
+     bit-identical to one ``lax.top_k`` over all G rows: ties resolve to
+     the lowest gallery index — the same deterministic order as the numpy
+     oracle's stable argsort. Shapes with ``G % 128 != 0`` or
+     ``G // 128 <= k`` take that one ``lax.top_k`` (``topk_rows``);
   4. mask: invalid query slots (padding from the continuous batcher)
      return id -1.
 """
@@ -33,17 +41,50 @@ from repro.serving.index import GalleryIndex, _l2n
 
 _PAD_DIST = 1e30
 _K = 10                                    # abstract / default top-k
+_TOPK_BLOCK = 128                          # rows per block of the top-k
 
 
 def _featurize(theta, bn_mu, bn_sd, qp):
     return _l2n(jax.vmap(EM.adaptive_forward_frozen)(theta, qp, bn_mu, bn_sd))
 
 
+def topk_rows(G: int, k: int) -> int:
+    """Rows per query that enter the final ``lax.top_k`` of ``_rank_topk``:
+    k·``_TOPK_BLOCK`` on the blocked path, G on the plain one."""
+    if G % _TOPK_BLOCK == 0 and G // _TOPK_BLOCK > k:
+        return k * _TOPK_BLOCK
+    return G
+
+
+def _order_key(x):
+    """f32 -> s32 whose signed order is ``lax.top_k``'s total float order
+    (-0.0 below +0.0, NaNs at the ends)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+
+
+def _blocked_top_k(x, k):
+    """``lax.top_k(x, k)`` over the last axis of (C, B, G), bit for bit,
+    sorting G/128 block keys and k·128 candidates instead of G rows."""
+    C, B, G = x.shape
+    L = _TOPK_BLOCK
+    xb = x.reshape(C, B, G // L, L)
+    _, blk = jax.lax.top_k(jnp.max(_order_key(xb), axis=-1), k)
+    blk = jnp.sort(blk, axis=-1)             # candidates in global order
+    cand = jax.vmap(jax.vmap(lambda r, b: r[b]))(xb, blk)   # (C, B, k, L)
+    gidx = blk[..., None] * L + jnp.arange(L, dtype=blk.dtype)
+    val, pos = jax.lax.top_k(cand.reshape(C, B, k * L), k)
+    return val, jnp.take_along_axis(gidx.reshape(C, B, k * L), pos, axis=-1)
+
+
 def _rank_topk(dist, gids, qmask, k):
     """(C, B, G) distances -> ((C, B, k) ids, (C, B, k) distances)."""
-    C, B, _ = dist.shape
+    C, B, G = dist.shape
     dist = jnp.where((gids >= 0)[:, None, :], dist, _PAD_DIST)
-    negd, idx = jax.lax.top_k(-dist, k)
+    if topk_rows(G, k) < G:
+        negd, idx = _blocked_top_k(-dist, k)
+    else:
+        negd, idx = jax.lax.top_k(-dist, k)
     ids = jnp.take_along_axis(gids, idx.reshape(C, B * k),
                               axis=1).reshape(C, B, k)
     ids = jnp.where(qmask[..., None] > 0, ids, -1)
@@ -374,11 +415,18 @@ class RetrievalEngine:
                       h2d_bytes=4 * (np.size(qp) + np.size(qmask))) as sp:
             qp, qmask = sp.sync((jnp.asarray(qp, jnp.float32),
                                  jnp.asarray(qmask, jnp.float32)))
-        with obs.span("serve.launch", cat="stage"):
+        with obs.span("serve.launch", cat="stage",
+                      topk_rows=self._topk_rows(k)):
             ids, d = self._launch(qp, qmask, k)
         with obs.span("serve.readback", cat="stage",
                       d2h_bytes=obs.device_nbytes(ids, d)):
             return np.asarray(ids), np.asarray(d)
+
+    def _topk_rows(self, k):
+        """Rows per query entering the launch's final top-k (static)."""
+        if self.mode == "ivf":
+            return self.nprobe * self.index.bcap
+        return topk_rows(self.index.capacity, k)
 
     def _launch(self, qp, qmask, k):
         """Dispatch the mode's query program (no sync): device (ids, d)."""

@@ -191,5 +191,7 @@ def test_batcher_step_spans_under_their_parents(kw, slots):
     n_cam, B = batcher._qmask.shape
     Dp = batcher._qp.shape[-1]
     assert spans["serve.upload"]["h2d_bytes"] == n_cam * B * (Dp + 1) * 4
+    # G = 40 rows: too few blocks, one top-k over every row
+    assert spans["serve.launch"]["topk_rows"] == 40
     # (C, B, k) int32 ids + float32 distances
     assert spans["serve.readback"]["d2h_bytes"] == n_cam * B * 5 * 8

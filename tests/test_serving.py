@@ -8,6 +8,9 @@
   * exact rank parity: the fp32 serving program returns the numpy
     retrieval oracle's ids verbatim (stable-tie order included);
   * int8 fidelity: mAP delta vs fp32 bounded on the synthetic bench;
+  * exact blocked top-k: ``_rank_topk`` bit-identical to one
+    ``lax.top_k`` over all gallery rows, and no full-row sort in the
+    serving program's lowering;
   * batch-composition invariance (the frozen-BN contract the continuous
     batcher relies on), batcher coalescing, and incremental head updates.
 """
@@ -19,6 +22,8 @@ import pytest
 from repro.core import edge_model as EM
 from repro.kernels import ops
 from repro.kernels import ref as REF
+from repro.obs import trace as obs
+from repro.serving import engine as E
 from repro.serving import (ContinuousBatcher, GalleryIndex, RetrievalEngine,
                            map_from_ranked_ids)
 from repro.serving.index import refresh_host
@@ -225,3 +230,118 @@ def test_map_from_ranked_ids_semantics():
     # masked-out query dropped even if it would match
     assert map_from_ranked_ids(ids, np.array([7, 1]),
                                qmask=np.array([1.0, 0.0])) == pytest.approx(5 / 6)
+
+
+def _plain_rank(dist, gids, qmask, k):
+    """The single-``lax.top_k`` ranking the blocked path must equal."""
+    C, B, _ = dist.shape
+    dist = jnp.where((gids >= 0)[:, None, :], dist, E._PAD_DIST)
+    negd, idx = jax.lax.top_k(-dist, k)
+    ids = jnp.take_along_axis(gids, idx.reshape(C, B * k),
+                              axis=1).reshape(C, B, k)
+    return jnp.where(qmask[..., None] > 0, ids, -1), -negd
+
+
+def _rank_case(case, G, k):
+    """(dist, gids, qmask) of one named case, C=2 cameras x B=8 queries."""
+    rng = np.random.default_rng([G, k, len(case)])
+    C, B = 2, 8
+    dist = rng.random((C, B, G), dtype=np.float32) * 4
+    gids = np.arange(G, dtype=np.int32)[None].repeat(C, 0)   # row = id
+    qmask = np.ones((C, B), np.float32)
+    if case == "one_block":                  # the whole top k in block 5
+        dist[:, :, 5 * 128 + 7:5 * 128 + 7 + 3 * k:3] = 1e-3 * np.arange(k)
+    elif case == "edge_ties":                # equal rows across block edges
+        for e in (128, 256, 1024):
+            dist[:, :, e - 3:e + 3] = 0.25
+        dist[:, :, G - 2:] = 0.25
+    elif case == "rank_ties":                # blocks rank against index
+        dist += 2.0                          # order; ties among their rows
+        nb = min(6, G // 128)
+        for j in range(nb):
+            dist[:, :, j * 128] = 0.1 * (nb - j)        # later block better
+            dist[:, :, j * 128 + 60] = 0.75             # tied across blocks
+    elif case == "zero_block":               # one -0.0 in a block of +0.0
+        dist[:, :, :128] = 0.0
+        dist[:, :, 5] = -0.0
+        dist[:, :, 128:] = -0.0
+    elif case == "nan_rows":                 # broken rows rank last, as
+        dist[:, :, 128:1280:5] = np.nan      # lax.top_k ranks them
+        dist[:, :, 130] = 1e-4
+    elif case == "int_ties":                 # heavy integer ties everywhere
+        dist = rng.integers(0, 3, (C, B, G)).astype(np.float32)
+    elif case == "constant":
+        dist[:] = 2.0
+    elif case == "signed_zero":              # -0.0 and +0.0 in one block
+        dist = rng.choice(np.array([0.0, -0.0, 1.0], np.float32), (C, B, G))
+    elif case == "few_valid":                # fewer valid rows than k
+        gids[:] = -1
+        gids[0, [3, 700, G - 1]] = [3, 700, G - 1]
+        gids[1, 200] = 200
+    elif case == "masked":                   # padded query slots
+        qmask[0, 5:] = 0.0
+        qmask[1, :] = 0.0
+        gids[:, rng.random(G) < 0.2] = -1
+    return jnp.asarray(dist), jnp.asarray(gids), jnp.asarray(qmask)
+
+
+@pytest.mark.parametrize("G,k,blocked", [(4096, 10, True), (8192, 1, True),
+                                         (8192, 10, True), (4000, 10, False),
+                                         (1280, 10, False), (1408, 10, True)])
+@pytest.mark.parametrize("case", ["random", "one_block", "edge_ties",
+                                  "rank_ties", "zero_block", "nan_rows",
+                                  "int_ties", "constant", "signed_zero",
+                                  "few_valid", "masked"])
+def test_blocked_rank_topk_bit_identical(case, G, k, blocked):
+    """``_rank_topk`` == one ``lax.top_k`` over the same masked distances:
+    ids and distance bits identical, ties to the lowest gallery index. The
+    blocked path runs where 128 | G and G/128 > k, the plain one elsewhere."""
+    assert (E.topk_rows(G, k) < G) == blocked
+    dist, gids, qmask = _rank_case(case, G, k)
+    ids, d = jax.jit(E._rank_topk, static_argnums=3)(dist, gids, qmask, k)
+    ids0, d0 = _plain_rank(dist, gids, qmask, k)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(ids0))
+    np.testing.assert_array_equal(np.asarray(d).view(np.int32),
+                                  np.asarray(d0).view(np.int32))
+
+
+def test_query_int8_lowering_has_no_full_row_sort():
+    """At the serving size (C=4, B=64, G=131,072) no top-k or sort of the
+    int8 query program runs over the gallery axis."""
+    S = jax.ShapeDtypeStruct
+    C, B, G = 4, 64, 131072
+    theta = jax.eval_shape(lambda key: EM.init_adaptive_layers(key, CFG),
+                           jax.random.PRNGKey(0))
+    theta = jax.tree_util.tree_map(lambda s: S((C,) + s.shape, s.dtype),
+                                   theta)
+    F = CFG.feat_dim
+    args = (theta, S((C, F), jnp.float32), S((C, F), jnp.float32),
+            S((C, B, CFG.proto_dim), jnp.float32), S((C, B), jnp.float32),
+            S((C, G, F), jnp.int8), S((C, G), jnp.float32),
+            S((C, G), jnp.float32), S((C, G), jnp.int32))
+    text = E.query_int8_program.lower(*args, k=10, backend="ref").as_text()
+    ranks = [line for line in text.splitlines()
+             if "chlo.top_k" in line or "stablehlo.sort" in line]
+    assert any("4x64x1280xf32" in line for line in ranks)
+    for line in ranks:
+        assert f"x{G}x" not in line and f"x{G}>" not in line, line
+
+
+def test_blocked_engine_matches_host_oracle():
+    """An fp32 engine at G = 4,096 (ragged fills, so padded rows) takes the
+    blocked path, records it on ``serve.launch`` and returns the numpy
+    oracle's ids."""
+    index, rng = _mk_index(C=2, G=4096, seed=4)
+    eng = RetrievalEngine(index, _stack_thetas(2, seed=4), k=5, mode="fp32")
+    qp = rng.standard_normal((2, 6, CFG.proto_dim)).astype(np.float32)
+    qmask = np.ones((2, 6), np.float32)
+    qmask[1, 4:] = 0.0
+    tracer = obs.Tracer()
+    with obs.active(tracer):
+        ids_d, dist_d = eng.query_batch(qp, qmask)
+    launch = [e for e in tracer.events if e.get("name") == "serve.launch"]
+    assert [e["topk_rows"] for e in launch] == [5 * 128]
+    ids_h, dist_h = eng.query_host(qp, qmask)
+    np.testing.assert_array_equal(ids_d, ids_h)
+    np.testing.assert_allclose(dist_d[qmask > 0], dist_h[qmask > 0],
+                               atol=1e-5)
