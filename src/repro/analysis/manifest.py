@@ -139,6 +139,19 @@ def _register_fedstil() -> None:
         # whole epoch scan); measured ~584 MB at C=100 on this estimator
         carry=(0, 1), donate=(0, 1), budget_bytes=640 << 20)
 
+    # every client's nearest-mean exemplars at the fleet cell's task size
+    # (144 train prototypes per client): adaptive forward + three sorts
+    n_task = 144
+    register_runtime(
+        "federated.fedstil_exemplar_select", strat._exemplar_fn(),
+        abstract_args=lambda: ((
+            _stretch(_sds_like(theta_example)),
+            _SDS((_C, n_task, D), _F32),
+            _SDS((_C, n_task), _I32)), {}),
+        module="repro.core.fedstil",
+        oracle="repro.core.rehearsal.PrototypeMemory.add_task",
+        budget_bytes=64 << 20)
+
     # flatten/unflatten stages ride along so the full staged-jit server
     # structure (see the ROADMAP note on why it is NOT one mega-jit) stays
     # under analysis

@@ -122,6 +122,14 @@ def adaptive_forward_frozen(theta, protos, mu, sd):
     return adaptive_bn_apply(theta, adaptive_pre_bn(theta, protos), mu, sd)
 
 
+def adaptive_features(theta, protos):
+    """prototypes -> retrieval features: ``adaptive_forward``'s first
+    output, bit for bit, without the classifier head."""
+    f = adaptive_pre_bn(theta, protos)
+    mu, sd = adaptive_bn_stats(f, jnp.ones((protos.shape[0],), jnp.float32))
+    return adaptive_bn_apply(theta, f, mu, sd)
+
+
 def adaptive_forward(theta, protos):
     """prototypes -> (retrieval features, class logits)."""
     return adaptive_forward_masked(

@@ -28,7 +28,7 @@ from repro.common.pytree import (tree_bytes, tree_flatten_stacked,
 from repro.core import edge_model as EM
 from repro.core.adaptive import combine, init_adaptive
 from repro.core.aggregation import personalized_aggregate
-from repro.core.rehearsal import PrototypeMemory
+from repro.core.rehearsal import PrototypeMemory, select_exemplars
 from repro.core.relevance import (DeviceRingHistory, RelevanceTracker,
                                   normalize_rows)
 from repro.core.tying import tying_loss
@@ -75,6 +75,8 @@ def sharded_fused_aggregate(w, thetas, mesh, *, backend=None):
 
 
 _SHARDED_AGG_CACHE: dict = {}
+
+_adaptive_features = jax.jit(EM.adaptive_features)
 
 
 class FedSTIL(Strategy):
@@ -152,9 +154,12 @@ class FedSTIL(Strategy):
         theta = self._eval_theta(state)
         state.extras["reg_prev_theta"] = theta
 
-        # store exemplar prototypes (nearest-mean, Fig. 4)
+        # store exemplar prototypes (nearest-mean, Fig. 4); the features
+        # are jitted as in the stacked engine's ``_exemplar_fn``: fused and
+        # op-by-op arithmetic differ in the last bit, enough to reorder two
+        # rows of one identity (always tied in exact arithmetic)
         if self.use_rehearsal:
-            outputs, _ = EM.adaptive_forward(theta, jnp.asarray(protos))
+            outputs = _adaptive_features(theta, jnp.asarray(protos))
             mem.add_task(protos, labels, np.asarray(outputs), task_id=rnd)
 
         # upload: adaptive-layer params + the tiny task feature (Eq. 3)
@@ -254,22 +259,18 @@ class FedSTIL(Strategy):
 
         C = len(protos_list)
         if self.use_rehearsal:
-            # host memories exist only for the C real clients; on a mesh
-            # theta carries Cp >= C padded rows, so slice before the vmap
-            N = len(protos_list[0])
+            protos = np.stack(protos_list)                    # (C, N, D)
+            labels = np.stack(labels_list)                    # (C, N)
             with obs.span("local.forward", cat="stage", round=rnd,
-                          h2d_bytes=obs.device_nbytes(*protos_list),
-                          d2h_bytes=C * N * self.cfg.feat_dim * 4):
-                theta_real = jax.tree.map(lambda l: l[:C], theta)
-                protos = jnp.asarray(np.stack(protos_list))  # (C, N, D)
-                outputs = np.asarray(jax.vmap(
-                    lambda th, p: EM.adaptive_forward(th, p)[0])(theta_real,
-                                                                 protos))
+                          h2d_bytes=obs.device_nbytes(protos, labels),
+                          d2h_bytes=C * (protos.shape[1] + 1) * 4):
+                order, count = jax.device_get(
+                    self._exemplar_fn()(theta, protos, labels))
             with obs.span("local.rehearsal", cat="stage", round=rnd,
-                          rows=C * N):
+                          rows=int(count.sum())):
                 for c, mem in enumerate(stacked.host["memory"]):
-                    mem.add_task(protos_list[c], labels_list[c], outputs[c],
-                                 task_id=rnd)
+                    mem.add_selected(protos_list[c], labels_list[c],
+                                     order[c, :count[c]], task_id=rnd)
 
         lead = jax.tree.leaves(theta)[0].shape[0]
         D = np.shape(protos_list[0])[-1]
@@ -285,6 +286,26 @@ class FedSTIL(Strategy):
                     [feats, np.zeros((lead - C, D), np.float32)])
             task_feature = sp.sync(jnp.asarray(feats))
         return stacked, {"theta": theta, "task_feature": task_feature}
+
+    def _exemplar_fn(self):
+        """One jit: every client's adaptive-layer features of its task's
+        prototypes and their nearest-mean exemplars (``select_exemplars``),
+        what ``PrototypeMemory.add_task`` picks client by client on the
+        host. ``(theta, protos (C, N, D), labels (C, N)) -> (order (C, N),
+        count (C,))``; only the selection comes back to the host."""
+        if "exemplar_select" not in self._jit_cache:
+            per_identity = self.per_identity
+
+            @jax.jit
+            def run(theta, protos, labels):
+                # on a mesh theta carries Cp >= C padded rows; the host
+                # memories exist only for the C real clients
+                C = protos.shape[0]
+                theta = jax.tree.map(lambda l: l[:C], theta)
+                feats = jax.vmap(EM.adaptive_features)(theta, protos)
+                return select_exemplars(feats, labels, per_identity)
+            self._jit_cache["exemplar_select"] = run
+        return self._jit_cache["exemplar_select"]
 
     def _stacked_server_fns(self, theta_example):
         """Staged jitted pieces of the stacked server round.

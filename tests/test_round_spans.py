@@ -20,6 +20,7 @@ from repro.obs import trace as obs
 from repro.serving import ContinuousBatcher, GalleryIndex, RetrievalEngine
 
 C, ROUNDS, EPOCHS, BATCH = 3, 2, 2, 8
+PER_IDENTITY = 2      # below the rows per identity: selection drops rows
 
 # parent -> children recorded once under each instance of the parent
 CHILDREN = {
@@ -41,7 +42,7 @@ def _run(bench, trace):
     """Run the stacked engine; returns the final state on the host."""
     cfg = EdgeModelConfig(n_classes=bench.n_classes)
     strat = FedSTIL(cfg, n_clients=C, epochs=EPOCHS, batch=BATCH,
-                    codec="topk+int8", seed=5)
+                    per_identity=PER_IDENTITY, codec="topk+int8", seed=5)
     seen = {}
     apply = strat.apply_dispatch_stacked
 
@@ -103,7 +104,7 @@ def test_declared_bytes_equal_the_arrays_moved(traced, bench):
     tracer, _, _ = traced
     cfg = EdgeModelConfig(n_classes=bench.n_classes)
     N = bench.task(0, 0).train_x.shape[0]
-    D, F = cfg.proto_dim, cfg.feat_dim
+    D = cfg.proto_dim
     spans = _spans(tracer)
     rnd = _round_of(spans)
 
@@ -118,11 +119,16 @@ def test_declared_bytes_equal_the_arrays_moved(traced, bench):
         assert e["h2d_bytes"] == C * EPOCHS * rows(rnd[e["id"]]) * (D + 1) * 4
     for e in named("gather.sample"):
         assert e["rows"] == C * EPOCHS * rows(rnd[e["id"]])
+    # prototypes and labels up, each client's exemplar order and count back
     for e in named("local.forward"):
-        assert e["h2d_bytes"] == C * N * D * 4
-        assert e["d2h_bytes"] == C * N * F * 4
+        assert e["h2d_bytes"] == C * N * (D + 1) * 4
+        assert e["d2h_bytes"] == C * (N + 1) * 4
+    # the kept rows: every row of an identity with at most per_identity
+    kept = sum(np.minimum(np.unique(bench.task(c, 0).train_y,
+                                    return_counts=True)[1], PER_IDENTITY).sum()
+               for c in range(C))
     for e in named("local.rehearsal"):
-        assert e["rows"] == C * N
+        assert e["rows"] == kept
     for e in named("local.task_feature"):
         assert e["h2d_bytes"] == C * D * 4
     for e in named("server.readback"):
@@ -132,7 +138,8 @@ def test_declared_bytes_equal_the_arrays_moved(traced, bench):
             assert e["d2h_bytes"] == C * C * 4    # last_W: (C, C) f32
     moved = sum(e.get("h2d_bytes", 0) + e.get("d2h_bytes", 0)
                 for e in spans if rnd[e["id"]] == 1)
-    assert moved == (C * EPOCHS * rows(1) * (D + 1) * 4 + C * N * (D + F) * 4
+    assert moved == (C * EPOCHS * rows(1) * (D + 1) * 4
+                     + C * N * (D + 1) * 4 + C * (N + 1) * 4
                      + C * D * 4 + C * C * 4 + C)
 
 

@@ -158,3 +158,23 @@ def test_client_step_gradients_are_not_compiler_reductions(one_chip):
              if re.search(r"= f32\[[^\]]*\]\S* reduce\(", line)
              and "transpose(" in line]
     assert not grads, grads[:3]
+
+
+def test_exemplar_selection_compiles_for_v5e(one_chip):
+    """The stacked engine's exemplar program at the fleet's size (100
+    clients x 144 prototypes): forward, the identity loop and three sorts
+    on one chip, with only the (C, N) order and (C,) count as outputs."""
+    from repro.core import FedSTIL
+    from repro.core import edge_model as EM
+    from repro.core.edge_model import EdgeModelConfig
+
+    cfg, n = EdgeModelConfig(), 144
+    theta = jax.eval_shape(lambda k: jax.vmap(
+        lambda kk: EM.init_adaptive_layers(kk, cfg))(jax.random.split(k, C)),
+        jax.random.PRNGKey(0))
+    theta = jax.tree.map(lambda l: _sds(one_chip, l.shape, l.dtype), theta)
+    compiled = FedSTIL(cfg, n_clients=C)._exemplar_fn().lower(
+        theta, _sds(one_chip, (C, n, cfg.proto_dim)),
+        _sds(one_chip, (C, n), jnp.int32)).compile()
+    assert compiled.as_text().count(" sort(") == 3
+    assert compiled.memory_analysis().output_size_in_bytes < C * (n + 1) * 8
