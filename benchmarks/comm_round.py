@@ -30,6 +30,7 @@ from repro.comm.codec import make_codec
 from repro.common.pytree import tree_size
 from repro.core import edge_model as EM
 from repro.core.edge_model import EdgeModelConfig
+from repro.sharding.specs import engine_mesh
 
 DEFAULT_OUT = Path(__file__).resolve().parent.parent / "BENCH_comm_round.json"
 SPEC = "topk+int8"
@@ -57,7 +58,8 @@ def _bench_host(mat: np.ndarray, iters: int) -> float:
 
 def _bench_batched(mat: np.ndarray, iters: int):
     import jax.numpy as jnp
-    codec = BatchedCodec(make_codec(SPEC, delta=False), mat.shape[1])
+    codec = BatchedCodec(make_codec(SPEC, delta=False), mat.shape[1],
+                         engine_mesh(jax.devices()[:1]))
     dev = jnp.asarray(mat)
     wire = codec.per_client_bytes(codec.encode(dev))
     def one_round():
@@ -74,7 +76,8 @@ def _parity_check(mat: np.ndarray) -> None:
     """Host codec and batched device program must produce the same wire
     bytes and the same reconstruction (delta off: single-shot parity)."""
     host = make_codec(SPEC, delta=False)
-    batched = BatchedCodec(make_codec(SPEC, delta=False), mat.shape[1])
+    batched = BatchedCodec(make_codec(SPEC, delta=False), mat.shape[1],
+                           engine_mesh(jax.devices()[:1]))
     buffers = batched.encode(np.asarray(mat))
     dec_b = np.asarray(batched.decode(buffers))
     per_client_b = batched.per_client_bytes(buffers)

@@ -36,6 +36,7 @@ from repro.federated.simulation import (_EvalCache, _eval_round,
                                         _eval_round_device,
                                         _pre_extract_prototypes)
 from repro.lifelong import STL
+from repro.sharding.specs import engine_mesh
 from repro.train.metrics import LifelongTracker
 
 DEFAULT_OUT = Path(__file__).resolve().parent.parent / "BENCH_eval_round.json"
@@ -52,7 +53,7 @@ def _setup(C: int, n_tasks: int):
     g_params = EM.init_extraction(g_key, cfg)
     states = {c: strat.init_client(client_keys[c]) for c in range(C)}
     protos = _pre_extract_prototypes(bench, g_params)
-    cache = _EvalCache(bench, protos)
+    cache = _EvalCache(bench, protos, engine_mesh(jax.devices()[:1]), C)
     return bench, strat, states, g_params, protos, cache
 
 
@@ -98,7 +99,7 @@ def bench_eval_round(Cs=(5, 20, 100), *, n_tasks=2, iters=4,
         # stacked engine keeps them resident and is strictly cheaper)
         tr_d = LifelongTracker(C)
         dev_s = _time(lambda r: _eval_round_device(
-            strat, strat.stack_eval_thetas(states), cache, tr_d, r, t),
+            strat.stack_eval_thetas(states), cache, tr_d, r, t),
             iters)
 
         # same tracker metrics from all paths (allclose guard, not a perf op)
@@ -139,7 +140,7 @@ def smoke():
     theta = strat.stack_eval_thetas(states)
     tr_h, tr_d = LifelongTracker(5), LifelongTracker(5)
     _eval_round(strat, lambda c: states[c], bench, cache, tr_h, 0, 1)
-    _eval_round_device(strat, theta, cache, tr_d, 0, 1)
+    _eval_round_device(theta, cache, tr_d, 0, 1)
     for key in ("mAP", "R1"):
         np.testing.assert_allclose(tr_h.mean_accuracy(0, key),
                                    tr_d.mean_accuracy(0, key), atol=2e-3)

@@ -49,22 +49,22 @@ def _per_device_peak(strat, lead: int):
     """Per-device peak bytes of the sharded aggregate program: XLA's
     ``memory_analysis`` when the backend exposes it, else the analytic
     layout footprint (resident shards + the f32 upcast + outputs)."""
-    agg = strat._jit_cache.get("sharded_aggregate")
-    mesh = strat.mesh
-    d = mesh.shape["data"]
-    wire = jnp.bfloat16 if strat.wire_dtype == "bfloat16" else jnp.float32
-    if agg is not None:
-        try:
-            args = (jax.ShapeDtypeStruct((lead, lead), jnp.float32),
-                    jax.ShapeDtypeStruct((lead, P_DIM), wire))
-            mem = agg.lower(*args).compile().memory_analysis()
-            total = (int(mem.temp_size_in_bytes)
-                     + int(mem.argument_size_in_bytes)
-                     + int(mem.output_size_in_bytes))
-            if total > 0:
-                return {"source": "xla", "bytes": total // d}
-        except Exception:
-            pass
+    agg = strat._jit_cache["server"][2]
+    d = strat.mesh.shape["data"]
+    # the flatten emits the bf16 wire form only across shard boundaries
+    wire = (jnp.bfloat16 if strat.wire_dtype == "bfloat16" and d > 1
+            else jnp.float32)
+    try:
+        args = (jax.ShapeDtypeStruct((lead, lead), jnp.float32),
+                jax.ShapeDtypeStruct((lead, P_DIM), wire))
+        mem = agg.lower(*args).compile().memory_analysis()
+        total = (int(mem.temp_size_in_bytes)
+                 + int(mem.argument_size_in_bytes)
+                 + int(mem.output_size_in_bytes))
+        if total > 0:
+            return {"source": "xla", "bytes": total // d}
+    except Exception:
+        pass
     itemsize = jnp.dtype(wire).itemsize
     per_dev = (lead // d) * P_DIM * (itemsize + 4 + 4)  # wire + upcast + B
     per_dev += lead * lead * 4 * 2                      # W in + Wn out (repl.)
@@ -81,18 +81,17 @@ def _one_engine(C: int, iters: int, *, sharded: bool):
     rng = np.random.default_rng(0)
     theta = {"w": jnp.asarray(rng.standard_normal((C, P_DIM)), jnp.float32)}
     feats = rng.standard_normal((iters + 1, C, D)).astype(np.float32)
-    valid, lead = None, C
-    if sharded:
-        mesh = shard_specs.engine_mesh()
-        strat.mesh = mesh
-        lead = shard_specs.padded_clients(C, mesh)
-        theta = pad_client_rows(theta, lead)
-        theta = jax.device_put(theta, shard_specs.named_shardings(
-            mesh, shard_specs.stacked_tree_specs(theta)))
-        valid = jnp.concatenate([jnp.ones((C,), jnp.float32),
-                                 jnp.zeros((lead - C,), jnp.float32)])
-        valid = jax.device_put(valid, jax.sharding.NamedSharding(
-            mesh, shard_specs.client_row_spec(1)))
+    mesh = shard_specs.engine_mesh(
+        jax.devices() if sharded else jax.devices()[:1])
+    strat.mesh = mesh
+    lead = shard_specs.padded_clients(C, mesh)
+    theta = pad_client_rows(theta, lead)
+    theta = jax.device_put(theta, shard_specs.named_shardings(
+        mesh, shard_specs.stacked_tree_specs(theta)))
+    valid = jnp.concatenate([jnp.ones((C,), jnp.float32),
+                             jnp.zeros((lead - C,), jnp.float32)])
+    valid = jax.device_put(valid, jax.sharding.NamedSharding(
+        mesh, shard_specs.client_row_spec(1)))
 
     last = {}
 
@@ -101,7 +100,7 @@ def _one_engine(C: int, iters: int, *, sharded: bool):
         if lead > C:
             f = np.concatenate([f, np.zeros((lead - C, D), np.float32)])
         upload = {"theta": theta, "task_feature": jnp.asarray(f)}
-        d = strat.server_round_stacked(r, upload, valid=valid)
+        d = strat.server_round_stacked(r, upload, valid)
         jax.block_until_ready(jax.tree.leaves(d["B"]))
         last["B"] = d["B"]["w"]
 

@@ -30,6 +30,7 @@ from repro.core import edge_model as EM
 from repro.core.edge_model import EdgeModelConfig
 from repro.core.fedstil import FedSTIL
 from repro.obs import trace as obs
+from repro.sharding.specs import engine_mesh
 
 DEFAULT_OUT = Path(__file__).resolve().parent.parent / "BENCH_server_round.json"
 
@@ -56,14 +57,21 @@ def _bench_host(C, cfg, thetas, feats, iters):
     return (time.perf_counter() - t0) / iters
 
 
+def _one_device(strat, C):
+    """Put ``strat`` on a one-device engine mesh; the all-valid mask."""
+    strat.mesh = engine_mesh(jax.devices()[:1])
+    return jnp.ones((C,), jnp.float32)
+
+
 def _bench_stacked(C, cfg, thetas, feats, iters):
     strat = FedSTIL(cfg, n_clients=C)
+    valid = _one_device(strat, C)
     stacked_theta = tree_stack(thetas)       # resident between rounds
     feats_dev = jnp.asarray(feats)
     def one_round(r):
         upload = {"theta": stacked_theta,
                   "task_feature": feats_dev[r % len(feats)]}
-        d = strat.server_round_stacked(r, upload)
+        d = strat.server_round_stacked(r, upload, valid)
         jax.block_until_ready(jax.tree.leaves(d["B"]))
     one_round(0)                             # warmup (jit compile)
     t0 = time.perf_counter()
@@ -87,13 +95,14 @@ def measure_overhead(C=100, *, D=128, iters=8, repeats=3):
     thetas = _client_thetas(C, cfg)
     feats = rng.standard_normal((iters + 1, C, D)).astype(np.float32)
     strat = FedSTIL(cfg, n_clients=C)
+    valid = _one_device(strat, C)
     stacked_theta = tree_stack(thetas)
     feats_dev = jnp.asarray(feats)
 
     def one_round(r):
         upload = {"theta": stacked_theta,
                   "task_feature": feats_dev[r % (iters + 1)]}
-        d = strat.server_round_stacked(r, upload)
+        d = strat.server_round_stacked(r, upload, valid)
         jax.block_until_ready(jax.tree.leaves(d["B"]))
 
     one_round(0)                             # warmup (jit compile)

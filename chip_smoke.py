@@ -241,8 +241,6 @@ def phase_serve(C=4, G=131072, batch=64):
 # --------------------------------------------------------------------------
 
 def phase_sharded(C=100, n_tasks=2, rounds=2):
-    import jax
-
     from repro.core import FedSTIL
     from repro.core.edge_model import EdgeModelConfig
     from repro.data import FederatedReIDBenchmark
@@ -259,15 +257,14 @@ def phase_sharded(C=100, n_tasks=2, rounds=2):
         t0 = time.perf_counter()
         res = run_simulation(strat, bench, engine=engine, rounds=rounds,
                              eval_every=rounds, seed=SEED, trace=tracer)
-        log(f"{engine}: {jax.device_count() if engine == 'sharded' else 1}"
-            f" device(s) wall={time.perf_counter() - t0:.1f}s (traced) "
+        log(f"{engine}: {strat.mesh.devices.size} device(s) "
+            f"wall={time.perf_counter() - t0:.1f}s (traced) "
             f"mAP={res.final('mAP'):.6f} c2s={res.comm.total_c2s} "
             f"s2c={res.comm.total_s2c} device_eval={res.eval_on_device}")
         return res, [e for e in tracer.events if e["kind"] == "metric"]
 
     sharded, sm = run("sharded")
-    with jax.default_device(jax.devices()[0]):
-        stacked, tm = run("stacked")
+    stacked, tm = run("stacked")
     _report_divergence(sharded, sm, stacked, tm, C)
     gap = abs(sharded.final("mAP") - stacked.final("mAP"))
     log(f"sharded vs stacked: |dmAP|={gap:.3g}")

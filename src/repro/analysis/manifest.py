@@ -2,7 +2,7 @@
 
 The decorator in ``registry`` covers module-level programs; the engines'
 hottest programs, though, are closures built per-strategy-instance
-(``Strategy._stacked_train_fn``, ``FedSTIL._stacked_server_fns``) or
+(``Strategy._stacked_train_fn``, ``FedSTIL._server_fns``) or
 per-payload-size (``comm.batched.BatchedCodec``'s encode/decode jits).
 This module constructs them with tiny concrete configs (bench-scale
 abstract shapes, C=100 where the BENCH_*.json sweeps top out) and
@@ -16,7 +16,6 @@ training step ever runs.
 """
 from __future__ import annotations
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -42,19 +41,23 @@ def _register_fedstil() -> None:
 
     from repro.core.edge_model import EdgeModelConfig
     from repro.core.fedstil import FedSTIL
-    from repro.kernels import ops
 
     cfg = EdgeModelConfig()
     D = cfg.proto_dim
     strat = FedSTIL(cfg, n_clients=_C, epochs=2)
-    # tiny concrete states: _stacked_server_fns flattens an example theta
-    # eagerly, and stack_states is the cheapest way to an exact opt-state
-    # / extras structure. C is small here; the abstract args re-shape to _C.
+    # the server programs are built against the engine mesh; on one device
+    # (what the fleet cell runs) Θ never crosses a shard boundary, so the
+    # flatten emits f32 and no wire cast appears in the traces
+    strat.mesh = engine_mesh(jax.devices()[:1])
+    # tiny concrete states: _server_fns flattens an example theta eagerly,
+    # and stack_states is the cheapest way to an exact opt-state / extras
+    # structure. C is small here; the abstract args re-shape to _C.
     C0 = 4
     states = {c: strat.init_client(jax.random.PRNGKey(c)) for c in range(C0)}
     stacked = strat.stack_states(states)
     theta_example = strat.eval_theta_stacked(stacked)       # (C0, ...) pytree
-    relevance, flatten, unflatten = strat._stacked_server_fns(theta_example)
+    relevance, flatten, aggregate, unflatten = strat._server_fns(
+        theta_example)
     P = int(np.sum([np.prod(l.shape[1:])
                     for l in jax.tree.leaves(theta_example)]))
 
@@ -62,9 +65,8 @@ def _register_fedstil() -> None:
         return jax.tree.map(lambda l: _SDS((_C,) + l.shape[1:], l.dtype),
                             tree)
 
-    # the ring push takes the per-client participation mask (all-ones on
-    # the stacked engine, the client-validity mask on the sharded engine)
-    # plus the per-client staleness counter it carries round-to-round
+    # the ring push takes the per-client validity mask plus the
+    # per-client staleness counter it carries round-to-round
     ring_args = (_SDS((_C, _HIST, D), _F32), _SDS((_C, _HIST), _F32),
                  _SDS((_C,), _F32),
                  _SDS((_C, D), _F32), _SDS((_C,), _F32))
@@ -76,52 +78,35 @@ def _register_fedstil() -> None:
         oracle="repro.core.relevance.RelevanceTracker.relevance",
         carry=(0, 1, 2), donate=(0, 1, 2), budget_bytes=64 << 20)
 
-    def server_round(buf, valid, stale, feats, mask, theta_flat):
-        """The full staged stacked server round (FedSTIL
-        ``server_round_stacked`` data path) as one traceable program:
-        ring push + Eq. 4/5 relevance (with its rider telemetry mets),
-        the fused Eq. 5→6 kernel, unflatten, and the nz row mask."""
+    def server_round(buf, valid, stale, feats, mask, theta):
+        """The full staged server round (FedSTIL ``server_round_stacked``
+        data path) as one traceable program: ring push + Eq. 4/5
+        relevance (with its rider telemetry mets), flatten, the fused
+        Eq. 5→6 aggregate, unflatten, and the nz row mask."""
         buf, valid, stale, w_raw, mets = relevance(buf, valid, stale,
                                                    feats, mask)
-        b_flat, wn = ops.fused_relevance_aggregate(w_raw, theta_flat,
-                                                   backend="ref")
+        b_flat, wn = aggregate(w_raw, flatten(theta))
         nz = jnp.sum(wn, axis=1) > 0
         return buf, valid, stale, unflatten(b_flat), nz, mets
 
     register_runtime(
         "federated.fedstil_server_round", server_round,
-        abstract_args=lambda: (ring_args + (_SDS((_C, P), _F32),), {}),
-        module="repro.core.fedstil",
-        oracle="repro.core.fedstil.FedSTIL.server_round",
-        carry=(0, 1, 2), donate=(0, 1, 2), budget_bytes=128 << 20)
-
-    # engine="sharded" server stages, built against a 1x1 engine mesh (the
-    # layouts are shape-preserving, so the trace is device-count
-    # independent). The composite crosses the flatten->aggregate stage
-    # boundary in wire_dtype: the f32->bf16->f32 pair is the sanctioned
-    # wire cast of common/precision.py, not convert churn.
-    from repro.common.precision import WIRE_CASTS
-    strat.mesh = engine_mesh(jax.devices()[:1])
-    flatten_wire, aggregate = strat._sharded_server_fns(theta_example)
-
-    def sharded_server_round(buf, valid, stale, feats, mask, theta):
-        buf, valid, stale, w_raw, mets = relevance(buf, valid, stale,
-                                                   feats, mask)
-        b_flat, wn = aggregate(w_raw, flatten_wire(theta))
-        nz = jnp.sum(wn, axis=1) > 0
-        return buf, valid, stale, unflatten(b_flat), nz, mets
-
-    register_runtime(
-        "federated.sharded_server_round", sharded_server_round,
         abstract_args=lambda: (
             ring_args + (_stretch(_sds_like(theta_example)),), {}),
         module="repro.core.fedstil",
         oracle="repro.core.fedstil.FedSTIL.server_round",
-        # 32 MiB over the stacked round: the shard_map'd aggregate holds
-        # the all-gathered (Cp, P) f32 Θ next to the shard's input block
-        # (23 MB at C=100); estimated 139 MB
-        carry=(0, 1, 2), donate=(0, 1, 2), budget_bytes=160 << 20,
-        sanctioned_casts=WIRE_CASTS)
+        # the shard_map'd aggregate holds the all-gathered (Cp, P) f32 Θ
+        # next to the shard's input block (23 MB at C=100)
+        carry=(0, 1, 2), donate=(0, 1, 2), budget_bytes=160 << 20)
+
+    # the aggregate stage alone, at a wider P than the model's
+    register_runtime(
+        "federated.sharded_aggregate", aggregate,
+        abstract_args=lambda: ((_SDS((_C, _C), _F32),
+                                _SDS((_C, 4096), _F32)), {}),
+        module="repro.core.fedstil",
+        oracle="repro.kernels.ref.fused_relevance_aggregate_ref",
+        budget_bytes=64 << 20)
 
     epochs, batch = strat.epochs, strat.batch
     register_runtime(
@@ -152,9 +137,9 @@ def _register_fedstil() -> None:
         oracle="repro.core.rehearsal.PrototypeMemory.add_task",
         budget_bytes=64 << 20)
 
-    # flatten/unflatten stages ride along so the full staged-jit server
-    # structure (see the ROADMAP note on why it is NOT one mega-jit) stays
-    # under analysis
+    # the flatten stage rides along so the full staged-jit server structure
+    # (see the ROADMAP note on why it is NOT one mega-jit) stays under
+    # analysis
     register_runtime(
         "federated.fedstil_server_flatten", flatten,
         abstract_args=lambda: ((_stretch(_sds_like(theta_example)),), {}),
@@ -168,7 +153,8 @@ def _register_comm() -> None:
     from repro.comm.codec import make_codec
 
     P = 4096
-    codec = BatchedCodec(make_codec("topk+int8"), P)
+    codec = BatchedCodec(make_codec("topk+int8"), P,
+                         engine_mesh(jax.devices()[:1]))
     enc_args = (_SDS((_C, P), _F32),)
     buffers_sds = jax.eval_shape(codec._enc_sparse, *enc_args)[0]
 
@@ -192,22 +178,12 @@ def _register_comm() -> None:
         budget_bytes=32 << 20)
 
 
-def _register_sharded() -> None:
-    # the engine's two standalone mesh programs (the launch CLIs are thin
-    # demo harnesses around these — exactly one sharded implementation)
-    from repro.core.fedstil import sharded_fused_aggregate
+def _register_eval() -> None:
+    # the engine's batched eval program (the launch/eval_round CLI is a
+    # thin harness around it)
     from repro.federated.base import sharded_eval_fn
 
     mesh = engine_mesh(jax.devices()[:1])
-    register_runtime(
-        "federated.sharded_aggregate",
-        functools.partial(sharded_fused_aggregate, mesh=mesh),
-        abstract_args=lambda: ((_SDS((_C, _C), _F32),
-                                _SDS((_C, 4096), _F32)), {}),
-        module="repro.core.fedstil",
-        oracle="repro.kernels.ref.fused_relevance_aggregate_ref",
-        budget_bytes=64 << 20)
-
     from repro.core.edge_model import EdgeModelConfig
     from repro.core import edge_model as EM
     cfg = EdgeModelConfig()
@@ -232,4 +208,4 @@ def _register_sharded() -> None:
 
 _register_fedstil()
 _register_comm()
-_register_sharded()
+_register_eval()

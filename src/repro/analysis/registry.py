@@ -18,7 +18,7 @@ Module-level functions register with the decorator::
     @functools.partial(jax.jit, static_argnames=("backend",))
     def fused_relevance_aggregate(w, thetas, *, backend=None): ...
 
-Closures built at runtime (``FedSTIL._stacked_server_fns``, the
+Closures built at runtime (``FedSTIL._server_fns``, the
 ``BatchedCodec`` encode/decode jits) cannot be decorated at import time;
 ``analysis/manifest.py`` constructs them with tiny concrete configs and
 registers them via ``register_runtime`` when ``load_all()`` runs.

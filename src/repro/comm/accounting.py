@@ -56,7 +56,7 @@ class CommLog:
     def log_s2c(self, rnd: int, payload, measured: Optional[int] = None):
         self._log(self.s2c, self.s2c_formula, rnd, payload, 1, measured, None)
 
-    # batched logging: the stacked engine moves C identical-size payloads
+    # batched logging: the device engine moves C identical-size payloads
     # per round — one accounting call instead of a per-client Python loop
     # (``payload``/``measured`` are per-client sizes, counted n times;
     # ``n_formula`` lets the formula oracle keep its own multiplicity when

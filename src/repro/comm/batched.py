@@ -1,4 +1,4 @@
-"""Device-resident batched wire codec for the stacked engine.
+"""Device-resident batched wire codec for the device engine.
 
 ``BatchedCodec`` runs the same stage stack as the host ``PipelineCodec``
 (delta -> topk -> {int8|bf16}) over ALL C clients' flattened (C, P)
@@ -32,11 +32,11 @@ from repro.kernels import ops
 class BatchedCodec:
     """One direction's (C, P) encode/decode program, built from the host
     codec's stage parameters. Stateful only when delta is on (device ref).
-    ``mesh``: the sharded engine's mesh — the programs then run per
-    shard of payload rows."""
+    ``mesh``: the engine mesh, payload rows on "data" — the programs run
+    per shard of rows."""
 
-    def __init__(self, like: PipelineCodec, p: int, *,
-                 backend: Optional[str] = None, mesh=None):
+    def __init__(self, like: PipelineCodec, p: int, mesh, *,
+                 backend: Optional[str] = None):
         if like.topk and like.group is None:
             raise ValueError(
                 "BatchedCodec needs the grouped top-k stage (group=N); "
@@ -59,12 +59,9 @@ class BatchedCodec:
         group, kg = self.group, self.kg
 
         def program(fn):
-            """jit ``fn``; on a mesh (the sharded engine, payload rows on
-            "data") run it per shard inside ``shard_map`` — every stage
-            is row-local, and a Pallas call cannot be partitioned by the
-            compiler."""
-            if mesh is None:
-                return jax.jit(fn)
+            """jit ``fn`` run per shard of payload rows inside
+            ``shard_map`` — every stage is row-local, and a Pallas call
+            cannot be partitioned by the compiler."""
             rows = P("data")
             return jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=rows,
                                             out_specs=rows, check_vma=False))

@@ -29,7 +29,7 @@ compose in a fixed canonical order over the flattened fp32 payload vector:
 ``make_codec("topk+int8")`` parses a ``+``-joined spec into a
 ``PipelineCodec``; ``WirePayload.nbytes`` is the measured byte count the
 simulation logs (formulas stay as the cross-check oracle — see
-``comm.accounting``). The host codec is pure numpy; the stacked engine
+``comm.accounting``). The host codec is pure numpy; the device engine
 runs the same stages as one jitted device program over all C clients
 (``comm.batched.BatchedCodec``, backed by the Pallas kernels in
 ``kernels/quantize.py`` and ``kernels/topk_pack.py``).

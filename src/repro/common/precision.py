@@ -82,8 +82,8 @@ def broadcast_rows(v, n: int):
     the TPU compiler lays that reduction out by the shape of the whole
     program: a vmapped client step holding 100 clients and one holding 25
     sum the same client's bias gradient in different orders. Through here
-    a client's gradient is the same at any client count — the sharded
-    engine's 25 clients per chip match the stacked engine's 100 on one."""
+    a client's gradient is the same at any client count — the engine's
+    25 clients per chip on four chips match its 100 on one."""
     return jnp.broadcast_to(v, (n,) + v.shape)
 
 

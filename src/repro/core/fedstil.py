@@ -32,7 +32,7 @@ from repro.core.rehearsal import PrototypeMemory, select_exemplars
 from repro.core.relevance import (DeviceRingHistory, RelevanceTracker,
                                   normalize_rows)
 from repro.core.tying import tying_loss
-from repro.federated.base import ClientState, Strategy
+from repro.federated.base import ClientState, Strategy, pad_client_rows
 from repro.kernels import ops
 from repro.obs import trace as obs
 
@@ -67,13 +67,6 @@ def sharded_aggregate_fn(mesh, *, backend=None):
     return _SHARDED_AGG_CACHE[key]
 
 
-def sharded_fused_aggregate(w, thetas, mesh, *, backend=None):
-    """Run ``sharded_aggregate_fn`` on (W, Θ): values match
-    ``ops.fused_relevance_aggregate`` on one device (tier-1 asserts it)."""
-    return sharded_aggregate_fn(mesh, backend=backend)(
-        jnp.asarray(w), jnp.asarray(thetas))
-
-
 _SHARDED_AGG_CACHE: dict = {}
 
 _adaptive_features = jax.jit(EM.adaptive_features)
@@ -90,11 +83,13 @@ class FedSTIL(Strategy):
                  tying=True, server_backend=None, wire_dtype="bfloat16",
                  **kw):
         super().__init__(cfg, **kw)
-        # sharded-engine precision rule (common/precision.py): the (C, P)
-        # flatten that crosses the shard boundary is emitted in wire_dtype
-        # (bf16 default — half the resident/resharded bytes) and upcast to
-        # f32 inside the aggregate; "float32" turns the cast off for
-        # bit-tight parity runs. Optimizer/BN state is always f32.
+        # precision rule (common/precision.py): on a mesh whose "data"
+        # axis spans more than one device, the (Cp, P) flatten that
+        # crosses the shard boundary is emitted in wire_dtype (bf16
+        # default — half the gathered bytes) and upcast to f32 inside the
+        # aggregate; "float32" turns the cast off for bit-tight parity
+        # runs. On one device nothing crosses and Θ stays f32.
+        # Optimizer/BN state is always f32.
         self.wire_dtype = wire_dtype
         self.n_clients = n_clients
         self.lam_tie = lam_tie
@@ -110,7 +105,7 @@ class FedSTIL(Strategy):
             n_clients, history_len=history_len,
             forgetting_ratio=forgetting_ratio, metric=metric,
             backend=server_backend)
-        # stacked engine: its own device-resident history (the host tracker
+        # device engine: its own device-resident history (the host tracker
         # stays untouched so engine="host" remains the allclose oracle)
         self._ring: Optional[DeviceRingHistory] = None
         self.last_W: Optional[np.ndarray] = None
@@ -155,7 +150,7 @@ class FedSTIL(Strategy):
         state.extras["reg_prev_theta"] = theta
 
         # store exemplar prototypes (nearest-mean, Fig. 4); the features
-        # are jitted as in the stacked engine's ``_exemplar_fn``: fused and
+        # are jitted as in the device engine's ``_exemplar_fn``: fused and
         # op-by-op arithmetic differ in the last bit, enough to reorder two
         # rows of one identity (always tied in exact arithmetic)
         if self.use_rehearsal:
@@ -231,7 +226,7 @@ class FedSTIL(Strategy):
         return (tree_bytes(state.theta) + tree_bytes(state.extras["reg_B"])
                 + mem.size_bytes)
 
-    # ---- stacked (device-resident) engine ------------------------------------
+    # ---- device engine --------------------------------------------------------
     def _gather_rehearsal(self, stacked, c):
         if not self.use_rehearsal:
             return None
@@ -276,14 +271,11 @@ class FedSTIL(Strategy):
         D = np.shape(protos_list[0])[-1]
         with obs.span("local.task_feature", cat="stage", round=rnd,
                       h2d_bytes=lead * D * 4) as sp:
-            feats = np.stack([np.asarray(p, np.float32).mean(0)
-                              for p in protos_list])
-            if lead > C:
-                # mesh padding rows: zero features — the validity mask
-                # keeps them out of the relevance ring, so the values
-                # never matter
-                feats = np.concatenate(
-                    [feats, np.zeros((lead - C, D), np.float32)])
+            # mesh padding rows: the validity mask keeps them out of the
+            # relevance ring, so their values never matter
+            feats = pad_client_rows(np.stack(
+                [np.asarray(p, np.float32).mean(0) for p in protos_list]),
+                lead)
             task_feature = sp.sync(jnp.asarray(feats))
         return stacked, {"theta": theta, "task_feature": task_feature}
 
@@ -298,7 +290,7 @@ class FedSTIL(Strategy):
 
             @jax.jit
             def run(theta, protos, labels):
-                # on a mesh theta carries Cp >= C padded rows; the host
+                # theta carries Cp >= C rows (mesh padding); the host
                 # memories exist only for the C real clients
                 C = protos.shape[0]
                 theta = jax.tree.map(lambda l: l[:C], theta)
@@ -307,129 +299,90 @@ class FedSTIL(Strategy):
             self._jit_cache["exemplar_select"] = run
         return self._jit_cache["exemplar_select"]
 
-    def _stacked_server_fns(self, theta_example):
-        """Staged jitted pieces of the stacked server round.
+    def _server_fns(self, theta_example):
+        """The server round's staged programs, built once against
+        ``self.mesh`` (client rows on "data"): ring push + Eq. 4/5
+        relevance, flatten, the fused Eq. 5→6 aggregate, unflatten. Zero
+        host round-trips between them.
 
         Deliberately NOT one mega-jit: on CPU, fusing the (C, P) flatten
         into the aggregate defeats XLA's fast GEMM path (measured ~2.5x
-        slower at C=100). Each stage is its own device program — ring push
-        + Eq. 4/5 relevance (tiny), flatten, the fused normalize+mask
-        Eq. 6 kernel (via ops), unflatten — with zero host round-trips
-        between them.
+        slower at C=100).
+
+        The relevance scores each shard's rows inside ``shard_map`` (see
+        ``ring_relevance``); the aggregate is ``sharded_aggregate_fn``:
+        one all-gather of Θ over "data", then the fused kernel per shard
+        on its own W rows. Θ crosses a shard boundary only when "data"
+        has more than one device; only then does the flatten emit it in
+        ``wire_dtype`` (``to_bf16`` halves the gathered bytes), and the
+        aggregate upcasts it back with ``to_f32``.
         """
-        if "stacked_relevance" not in self._jit_cache:
+        if "server" not in self._jit_cache:
             backend = (None if self.server_backend == "loop"
                        else self.server_backend)
             ratio = self.tracker.forgetting_ratio
             metric = self.tracker.metric
             mesh = self.mesh
+            wire = (self.wire_dtype == "bfloat16"
+                    and mesh.shape["data"] > 1)
+            agg = sharded_aggregate_fn(mesh, backend=backend)
 
             # the ring buffer/validity/staleness are the round-carried
             # server state: the caller overwrites all three with the
-            # returns, so donate them.
-            # ``mask`` is the per-client push mask — all-ones on the
-            # single-device stacked engine, the client-validity mask on the
-            # sharded engine (padding rows must never enter the ring: a
-            # zero mask keeps their history invalid, so their W rows AND
-            # columns stay zero and the nz machinery leaves them alone).
-            # The telemetry mets are (C,)-sized outputs of this same
-            # launch — the host only reads them back when a tracer is
-            # active (obs.metric is a no-op otherwise).
+            # returns, so donate them. ``mask`` is the client-validity
+            # mask: mesh-padding rows never enter the ring, so their W
+            # rows AND columns stay zero and nz leaves them alone. The
+            # telemetry mets are (C,)-sized outputs of this same launch —
+            # the host only reads them back when a tracer is active
+            # (obs.metric is a no-op otherwise).
             @functools.partial(jax.jit, donate_argnums=(0, 1, 2))
             def relevance(buf, valid, stale, feats, mask):
                 from repro.core.relevance import _ring_push, ring_relevance
                 from repro.obs import metrics as obsm
                 buf, valid, stale = _ring_push(buf, valid, stale, feats,
                                                mask)
-                W = ring_relevance(buf, valid, forgetting_ratio=ratio,
-                                   metric=metric, backend=backend, mesh=mesh)
+                W = ring_relevance(buf, valid, mesh=mesh,
+                                   forgetting_ratio=ratio, metric=metric,
+                                   backend=backend)
                 mets = obsm.relevance_metrics(W, valid, stale)
                 return buf, valid, stale, W, mets
 
-            _, meta = tree_flatten_stacked(theta_example)   # one eager call
-            self._jit_cache["stacked_relevance"] = relevance
-            self._jit_cache["stacked_flatten"] = jax.jit(
-                lambda th: tree_flatten_stacked(th)[0])
-            self._jit_cache["stacked_unflatten"] = jax.jit(
-                lambda m: tree_unflatten_stacked(m, meta))
-        return (self._jit_cache["stacked_relevance"],
-                self._jit_cache["stacked_flatten"],
-                self._jit_cache["stacked_unflatten"])
-
-    def _sharded_server_fns(self, theta_example):
-        """engine="sharded" variants of the flatten/aggregate stages, built
-        once against ``self.mesh``. The relevance stage is shared with the
-        stacked engine (on a mesh it scores each shard's rows inside
-        ``shard_map``; see ``ring_relevance``). Deltas:
-
-          * the flatten emits the wire form — ``to_bf16`` of the (Cp, P)
-            matrix (``common/precision.py``): that buffer is what crosses
-            the shard boundary into the aggregate, at half the bytes;
-          * the aggregate upcasts to f32 (``to_f32``) and runs
-            ``sharded_aggregate_fn``: one all-gather of Θ over "data",
-            then the fused Eq. 5→6 kernel per shard on its own W rows,
-            leaving B client-row sharded (Cp/d × P per device). The
-            f32→bf16→f32 pair is the sanctioned wire cast the analysis
-            lints accept.
-        """
-        if "sharded_aggregate" not in self._jit_cache:
-            backend = (None if self.server_backend == "loop"
-                       else self.server_backend)
-            wire = self.wire_dtype
-            agg = sharded_aggregate_fn(self.mesh, backend=backend)
-
-            def flatten_wire(th):
+            def flatten(th):
                 flat = tree_flatten_stacked(th)[0]
-                return to_bf16(flat) if wire == "bfloat16" else flat
+                return to_bf16(flat) if wire else flat
 
-            def aggregate(W, flat):
-                return agg(W, to_f32(flat))
+            _, meta = tree_flatten_stacked(theta_example)   # one eager call
+            self._jit_cache["server"] = (
+                relevance, jax.jit(flatten),
+                jax.jit(lambda W, flat: agg(W, to_f32(flat))),
+                jax.jit(lambda m: tree_unflatten_stacked(m, meta)))
+        return self._jit_cache["server"]
 
-            self._jit_cache["sharded_flatten_wire"] = jax.jit(flatten_wire)
-            self._jit_cache["sharded_aggregate"] = jax.jit(aggregate)
-        return (self._jit_cache["sharded_flatten_wire"],
-                self._jit_cache["sharded_aggregate"])
-
-    def server_round_stacked(self, rnd, upload, valid=None):
+    def server_round_stacked(self, rnd, upload, valid):
         """Eq. 4/5 → Eq. 6 as a device-resident program over the ring
-        buffer. No host round-trips besides the tiny (C, C) relevance
-        readback for ``last_W``. ``valid`` is the sharded engine's (Cp,)
-        client-validity mask (None on the single-device stacked engine):
-        it gates the ring push, so mesh-padding rows never acquire history
-        and their relevance rows/columns stay zero."""
+        buffer. No host round-trips besides the tiny (Cp, Cp) relevance
+        readback for ``last_W``. ``valid`` is the (Cp,) client-validity
+        mask: it gates the ring push, so mesh-padding rows never acquire
+        history and their relevance rows/columns stay zero."""
         if not self.st_integration:
             return None
-        feats = upload["task_feature"]                       # (C, D)
-        C = feats.shape[0]
+        feats = upload["task_feature"]                       # (Cp, D)
         if self._ring is None:
-            self._ring = DeviceRingHistory(C, self.tracker.history_len,
+            self._ring = DeviceRingHistory(feats.shape[0],
+                                           self.tracker.history_len,
                                            int(feats.shape[-1]))
-            if self.mesh is not None:
-                self._ring.place(self.mesh)
-        relevance, flatten, unflatten = self._stacked_server_fns(
+            self._ring.place(self.mesh)
+        relevance, flatten, aggregate, unflatten = self._server_fns(
             upload["theta"])
-        backend = (None if self.server_backend == "loop"
-                   else self.server_backend)
-        mask = (jnp.ones((C,), jnp.float32) if valid is None
-                else jnp.asarray(valid, jnp.float32))
         with obs.span("server.relevance", cat="stage", round=rnd) as sp:
             (self._ring.buf, self._ring.valid, self._ring.stale, W_raw,
              mets) = relevance(self._ring.buf, self._ring.valid,
-                               self._ring.stale, jnp.asarray(feats), mask)
+                               self._ring.stale, jnp.asarray(feats), valid)
             sp.sync(W_raw)
-        if self.mesh is not None:
-            flatten_wire, aggregate = self._sharded_server_fns(
-                upload["theta"])
-            with obs.span("server.flatten", cat="stage", round=rnd) as sp:
-                flat = sp.sync(flatten_wire(upload["theta"]))  # (Cp, P) wire
-            with obs.span("server.aggregate", cat="stage", round=rnd) as sp:
-                B_flat, Wn = sp.sync(aggregate(W_raw, flat))
-        else:
-            with obs.span("server.flatten", cat="stage", round=rnd) as sp:
-                flat = sp.sync(flatten(upload["theta"]))       # (C, P)
-            with obs.span("server.aggregate", cat="stage", round=rnd) as sp:
-                B_flat, Wn = sp.sync(ops.fused_relevance_aggregate(
-                    W_raw, flat, backend=backend))
+        with obs.span("server.flatten", cat="stage", round=rnd) as sp:
+            flat = sp.sync(flatten(upload["theta"]))          # (Cp, P)
+        with obs.span("server.aggregate", cat="stage", round=rnd) as sp:
+            B_flat, Wn = sp.sync(aggregate(W_raw, flat))
         # per-client round observables (staleness, ring fill, W row
         # mass/density) — computed inside the relevance launch above;
         # this is a no-op readback unless a tracer is active

@@ -6,7 +6,7 @@ the per-identity mean of the outputs, and store the prototypes whose outputs
 are closest to their identity's mean. Bounded memory, FIFO eviction across
 tasks (oldest task's exemplars shrink first), replayed during training.
 
-``PrototypeMemory.add_task`` is the host oracle. The stacked engine picks
+``PrototypeMemory.add_task`` is the host oracle. The device engine picks
 every client's exemplars in one device program (``select_exemplars``) and
 stores them through ``add_selected``.
 """

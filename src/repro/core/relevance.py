@@ -23,9 +23,9 @@ Two server implementations share this module:
     (``ref`` / ``pallas`` / ``interpret``); ``None`` picks the compiled
     kernel on TPU and the jnp oracle elsewhere.
 
-``decayed_relevance`` is the shared Eq. 4/5 primitive: the on-mesh server
-(``launch/fed_round.py``) calls it per-client inside shard_map and the
-parameter-server tracker calls it for all clients at once.
+``decayed_relevance`` is the shared Eq. 4/5 primitive: the device
+engine's ``ring_relevance`` calls it per shard inside ``shard_map`` and the
+host tracker calls it for all clients at once.
 """
 from __future__ import annotations
 
@@ -35,7 +35,9 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
+from repro.common import compat
 from repro.core.similarity import SIMILARITY_FNS, pairwise_similarity
 
 
@@ -88,36 +90,27 @@ def _ring_push(buf, valid, stale, feats, mask):
     return buf, valid, stale
 
 
-def ring_relevance(buf, valid, *, forgetting_ratio: float, metric: str = "kl",
-                   backend: Optional[str] = None, mesh=None):
+def ring_relevance(buf, valid, *, mesh, forgetting_ratio: float,
+                   metric: str = "kl", backend: Optional[str] = None):
     """Unnormalized (C, C) decayed relevance over a ring-buffer history:
     each client's latest feature (age 0) vs every history, rows without a
     current feature zeroed. Diagonal NOT masked — the fused aggregate
-    kernel owns that. jit-traceable; shared by ``DeviceRingHistory`` and
-    the stacked FedSTIL server program.
+    kernel owns that. jit-traceable; the FedSTIL device server program's
+    Eq. 4/5 stage.
 
-    With a ``mesh`` (the sharded engine, ring rows on "data") the rows are
+    The ring rows live on the ``mesh``'s "data" axis, and the rows are
     computed per shard inside ``shard_map``: each shard all-gathers the
     (small) histories and scores its own clients' current features
     against them, so the similarity kernel runs on local blocks. W comes
     back row-sharded."""
-    def rows(cur_buf, cur_valid, hist, hvalid):
-        k = hist.shape[1]
-        decay = forgetting_ratio ** jnp.arange(k, dtype=jnp.float32)
-        W = decayed_relevance(cur_buf[:, 0], hist, decay, hvalid,
-                              metric=metric, backend=backend)
-        return W * cur_valid[:, 0][:, None]
-
-    if mesh is None:
-        return rows(buf, valid, buf, valid)
-
-    from jax.sharding import PartitionSpec as P
-
-    from repro.common import compat
-
     def shard(b, v):
-        return rows(b, v, jax.lax.all_gather(b, "data", tiled=True),
-                    jax.lax.all_gather(v, "data", tiled=True))
+        hist = jax.lax.all_gather(b, "data", tiled=True)
+        hvalid = jax.lax.all_gather(v, "data", tiled=True)
+        decay = forgetting_ratio ** jnp.arange(hist.shape[1],
+                                               dtype=jnp.float32)
+        W = decayed_relevance(b[:, 0], hist, decay, hvalid, metric=metric,
+                              backend=backend)
+        return W * v[:, 0][:, None]
 
     return compat.shard_map(
         shard, mesh=mesh, in_specs=(P("data"), P("data")),
@@ -159,7 +152,7 @@ class DeviceRingHistory:
 
     def place(self, mesh):
         """Shard the ring's client rows over the mesh's "data" axis (the
-        engine="sharded" layout from ``sharding.specs``): the roll/scatter
+        device engine's layout from ``sharding.specs``): the roll/scatter
         push and Eq. 4/5 relevance then run as SPMD programs with each
         device updating only its resident client block. n_clients must
         already be the mesh-padded Cp."""
@@ -174,13 +167,6 @@ class DeviceRingHistory:
 
     def stacked(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         return self.buf, self.valid
-
-    def raw_relevance(self, *, forgetting_ratio: float, metric: str = "kl",
-                      backend: Optional[str] = None) -> jnp.ndarray:
-        """See ``ring_relevance`` (the shared Eq. 4/5 ring primitive)."""
-        return ring_relevance(self.buf, self.valid,
-                              forgetting_ratio=forgetting_ratio,
-                              metric=metric, backend=backend)
 
 
 @dataclasses.dataclass

@@ -118,17 +118,15 @@ def stacked_eval_program(theta, qp, qids, task_mask, gp, gids, gmask, *,
                                      max_matches=max_matches)
 
 
-# The engine's ONE sharded eval program: the same ``stacked_eval_program``
-# body the single-device engine jits, run per shard inside ``shard_map``
-# over the mesh's "data" axis — every input leads with the client dim
-# (layouts from sharding/specs), retrieval eval never mixes clients, so
-# each shard scores its own client block with no collective, and the
-# distance kernel sees local blocks (a Pallas call cannot be partitioned
-# by the compiler). The tiny (C, T) metric outputs come back row-sharded
-# for the host readback. Cached per (mesh, config) — both
-# ``Strategy.eval_round_stacked`` under ``engine="sharded"`` and the
-# ``launch/eval_round`` CLI call this, so there is exactly one sharded
-# eval implementation in the repo.
+# The ONE batched eval program: ``stacked_eval_program`` run per shard
+# inside ``shard_map`` over the engine mesh's "data" axis — every input
+# leads with the client dim (layouts from sharding/specs), retrieval eval
+# never mixes clients, so each shard scores its own client block with no
+# collective, and the distance kernel sees local blocks (a Pallas call
+# cannot be partitioned by the compiler). The tiny (C, T) metric outputs
+# come back row-sharded for the host readback. Cached per (mesh, config):
+# the simulation's device eval (both engines) and the
+# ``launch/eval_round`` CLI call this.
 _SHARDED_EVAL_CACHE: Dict[Any, Callable] = {}
 
 
@@ -151,13 +149,14 @@ def pad_client_rows(tree, n_to: int):
     the last row. Replication (not zeros) keeps padded clients numerically
     boring: their forward/backward passes and eval rows compute real values
     (no 0/0 BN statistics), and the relevance mask guarantees they never
-    influence a real client."""
+    influence a real client. Host (numpy) leaves are padded on the host."""
     def pad(l):
         C = l.shape[0]
         if C == n_to:
             return l
-        reps = jnp.broadcast_to(l[-1:], (n_to - C,) + l.shape[1:])
-        return jnp.concatenate([jnp.asarray(l), reps], axis=0)
+        xp = np if isinstance(l, np.ndarray) else jnp
+        reps = xp.broadcast_to(l[-1:], (n_to - C,) + l.shape[1:])
+        return xp.concatenate([l, reps], axis=0)
     return jax.tree.map(pad, tree)
 
 
@@ -192,7 +191,7 @@ class Strategy:
         self.upload_codec = make_codec(codec, **self.codec_opts)
         self.dispatch_codec = make_codec(codec, **self.codec_opts)
         self._wire_programs: Dict[Any, BatchedCodec] = {}
-        # engine="sharded": set by shard_stacked_state (None = stacked/host)
+        # the device engine's mesh, set by shard_stacked_state
         self.mesh = None
         self.padded_clients: Optional[int] = None
 
@@ -330,8 +329,7 @@ class Strategy:
         if key not in self._wire_programs:
             template = (self.upload_codec if which == "upload"
                         else self.dispatch_codec)
-            self._wire_programs[key] = BatchedCodec(template, p,
-                                                    mesh=self.mesh)
+            self._wire_programs[key] = BatchedCodec(template, p, self.mesh)
         return self._wire_programs[key]
 
     def _wire_roundtrip_stacked(self, which, tree, split, join):
@@ -391,26 +389,6 @@ class Strategy:
         """Stacked-engine counterpart of ``_eval_theta``: the (C, ...)
         eval-time params, straight off the resident state (no unstack)."""
         return stacked.trainable
-
-    def eval_round_stacked(self, theta, qp, qids, task_mask, gp, gids, gmask,
-                           *, ranks=(1, 3, 5), kernel_backend=None,
-                           max_matches=None):
-        """All C x T retrieval evaluations as one jitted device program
-        (feature heads + Pallas distance kernel + mAP/CMC). Under
-        ``engine="sharded"`` the same program runs client-row-sharded over
-        the engine mesh via ``sharded_eval_fn``."""
-        if self.mesh is not None:
-            fn = sharded_eval_fn(self.mesh, ranks=tuple(ranks),
-                                 kernel_backend=kernel_backend,
-                                 max_matches=max_matches)
-            return fn(theta, qp, qids, task_mask, gp, gids, gmask)
-        key = f"eval:{tuple(ranks)}:{kernel_backend}:{max_matches}"
-        if key not in self._jit_cache:
-            self._jit_cache[key] = jax.jit(functools.partial(
-                stacked_eval_program, ranks=tuple(ranks),
-                kernel_backend=kernel_backend, max_matches=max_matches))
-        return self._jit_cache[key](theta, qp, qids, task_mask, gp, gids,
-                                    gmask)
 
     def storage_bytes(self, state: ClientState) -> int:
         from repro.common.pytree import tree_bytes
@@ -500,7 +478,7 @@ class Strategy:
             shapes = {b.shape for b in bxs}
             if len(shapes) > 1:
                 raise ValueError(
-                    f"stacked engine needs uniform per-client batch shapes, "
+                    f"device engine needs uniform per-client batch shapes, "
                     f"got {sorted(shapes)} (ragged tasks/rehearsal pools)")
             return np.stack(bxs), np.stack(bys)
 
@@ -554,22 +532,21 @@ class Strategy:
         stacked.opt_state = opt_state
         return stacked, None
 
-    def server_round_stacked(self, rnd: int, upload, valid=None):
+    def server_round_stacked(self, rnd: int, upload, valid):
         """Device-resident server round over the stacked upload. ``valid``
-        is the sharded engine's (Cp,) client-validity mask (1.0 for real
-        clients, 0.0 for mesh-padding rows); None means every row is real
-        (the single-device stacked engine)."""
+        is the (Cp,) client-validity mask (1.0 for real clients, 0.0 for
+        mesh-padding rows)."""
         return None
 
     def apply_dispatch_stacked(self, stacked: StackedClientState, dispatch):
         return stacked
 
-    # ---- sharded (mesh-resident) engine API ----------------------------------
-    # engine="sharded" reuses the whole stacked round loop; the only deltas
-    # are (1) the stacked state/batches are padded to Cp (a multiple of the
-    # data-axis size) and placed with client-row NamedShardings so every
-    # stacked jit runs SPMD over the mesh, and (2) the server round gets a
-    # validity mask so padding rows never enter the relevance ring.
+    # ---- placement on the engine mesh ----------------------------------------
+    # The stacked state and batches are padded to Cp (a multiple of the
+    # data-axis size; Cp = C on one device) and placed with client-row
+    # NamedShardings, so every stacked jit runs SPMD over the mesh; the
+    # server round gets a validity mask so padding rows never enter the
+    # relevance ring.
 
     def shard_stacked_state(self, stacked: StackedClientState, mesh):
         """Pad the stacked state to Cp rows and place it row-sharded on the
@@ -590,22 +567,17 @@ class Strategy:
         stacked.trainable = place(stacked.trainable)
         stacked.opt_state = place(stacked.opt_state)
         stacked.extras = {k: place(v) for k, v in stacked.extras.items()}
-        valid = jnp.concatenate([jnp.ones((C,), jnp.float32),
-                                 jnp.zeros((Cp - C,), jnp.float32)])
+        valid = (np.arange(Cp) < C).astype(np.float32)
         valid = jax.device_put(valid, jax.sharding.NamedSharding(
             mesh, shard_specs.client_row_spec(1)))
         return stacked, valid
 
     def place_batches(self, bx, by):
-        """Pad this round's (C, epochs, B, ...) minibatch stacks to Cp rows
-        and place them row-sharded (no-op outside the sharded engine)."""
-        if self.mesh is None:
-            return bx, by
-        sh = shard_specs.named_shardings(
-            self.mesh, shard_specs.stacked_tree_specs((bx, by)))
-        return jax.device_put(
-            (pad_client_rows(bx, self.padded_clients),
-             pad_client_rows(by, self.padded_clients)), sh)
+        """Pad this round's host (C, epochs, B, ...) minibatch stacks to Cp
+        rows and upload them straight into their row sharding."""
+        batches = pad_client_rows((bx, by), self.padded_clients)
+        return jax.device_put(batches, shard_specs.named_shardings(
+            self.mesh, shard_specs.stacked_tree_specs(batches)))
 
     def stacked_upload_bytes(self, upload, n_clients: int) -> int:
         """Per-client C2S bytes (stacked leaves carry C copies)."""

@@ -17,33 +17,31 @@ bookkeeping inputs on device. ``eval_backend="host"`` retains the original
 per-(client, task) numpy loop as the allclose oracle (and the fallback for
 ragged benchmarks that cannot be stacked).
 
-Two interchangeable engines drive the rounds:
+Two engines drive the rounds:
 
   * ``engine="host"`` (default) — the original per-client Python loop: one
     jit dispatch per client per epoch, per-client state dicts, the server
     round over host lists of pytrees. Works for every strategy and is the
-    allclose oracle for the stacked engine.
-  * ``engine="stacked"`` — device-resident rounds for strategies that set
-    ``supports_stacked`` (FedSTIL, STL): all C client states live as one
-    stacked (C, ...) pytree, per-client minibatches are pre-gathered into
-    (C, epochs, B, D) arrays (same rng draw order as the host engine, so
-    both engines train on identical batches), local training for all C
-    clients is a single vmap-over-clients of a scan-over-epochs, and the
-    FedSTIL server round runs as one fused device program over a resident
-    (C, k, D) relevance ring buffer. Metrics match the host engine to
-    float tolerance; per-round wall time scales to C ≫ 100
-    (``benchmarks/run.py --bench server`` tracks the ratio).
-  * ``engine="sharded"`` — the stacked round, client-sharded over a
-    ``Mesh(("data", "model"))`` of every host device: state, batches, the
-    relevance ring and all eval inputs are placed row-sharded over "data"
-    (``sharding/specs.py`` is the layout source of truth; C is padded to a
-    multiple of the device count, padding rows masked out of the relevance
-    ring) and the same jitted programs re-specialize into SPMD; the
-    programs that call Pallas kernels (relevance, aggregate, codec, eval)
-    run them per shard inside ``shard_map``. Wire-bound
-    buffers cross shards in bf16 (``common/precision.py``); optimizer/BN
-    state stays f32. Metrics and measured comm bytes match the stacked
-    engine (``benchmarks/run.py --bench mesh`` scales C → 10k).
+    allclose oracle for the device engine.
+  * the device engine, for strategies that set ``supports_stacked``
+    (FedSTIL, STL, FedAvg/FedProx): all C client states live as one
+    stacked (C, ...) pytree on a ``Mesh(("data", "model"))``, per-client
+    minibatches are pre-gathered into (C, epochs, B, D) arrays (same rng
+    draw order as the host engine, so both engines train on identical
+    batches), local training for all C clients is a single
+    vmap-over-clients of a scan-over-epochs, and the FedSTIL server round
+    runs as staged device programs over a resident (C, k, D) relevance
+    ring buffer. State, batches, the ring and all eval inputs are placed
+    row-sharded over "data" (``sharding/specs.py`` is the layout source
+    of truth; C is padded to a multiple of the device count, padding rows
+    masked out of the relevance ring), and the programs that call Pallas
+    kernels (relevance, aggregate, codec, eval) run them per shard inside
+    ``shard_map``. The engine name picks the device list and nothing
+    else: ``engine="stacked"`` builds the mesh on one device,
+    ``engine="sharded"`` on every device. Θ crosses a shard boundary only
+    on more than one device, and only then travels in bf16
+    (``common/precision.py``); optimizer/BN state stays f32. Metrics and
+    measured comm bytes do not depend on the device count.
 
 Strategies that need raw images (iCaRL) or non-batchable local steps
 (EWC/MAS consolidation, FedWeIT sparse uploads) simply keep the default
@@ -55,7 +53,7 @@ upload/dispatch is encoded to real wire buffers, the comm log records the
 MEASURED buffer bytes next to the analytic formulas
 (``SimulationResult.comm_breakdown()``), and the receiver operates on the
 decoded — possibly lossy — payload, so compression fidelity shows up in
-the metrics. The stacked engine encodes all C clients' payload rows in one
+the metrics. The device engine encodes all C clients' payload rows in one
 jitted device program (kernels/topk_pack + kernels/quantize).
 """
 from __future__ import annotations
@@ -65,15 +63,15 @@ import inspect
 from typing import Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.comm.accounting import CommLog
 from repro.core import edge_model as EM
 from repro.data.synthetic import FederatedReIDBenchmark
 from repro.evalreid import evaluate_retrieval
-from repro.federated.base import Strategy
+from repro.federated.base import Strategy, pad_client_rows, sharded_eval_fn
 from repro.obs import trace as obs
+from repro.sharding import specs as shard_specs
 from repro.train.metrics import LifelongTracker
 
 EVAL_RANKS = (1, 3, 5)
@@ -143,13 +141,19 @@ class _EvalCache:
     into (C, G_max, D) + validity masks (padded to the t = T-1 gallery
     size, so the jitted device eval program compiles exactly once per
     simulation). Galleries for past tasks are evicted as t advances —
-    the task stream is monotone, they are never needed again.
+    the task stream is monotone, they are never needed again. Every
+    device stack is padded to the mesh-padded ``padded`` client rows
+    (edge-replicating the last client — padding rows are computed but
+    never read back) and placed row-sharded on ``mesh``, so the eval
+    program runs SPMD with each device scoring its own client block.
     """
 
-    def __init__(self, bench: FederatedReIDBenchmark, protos,
-                 device: bool = True):
+    def __init__(self, bench: FederatedReIDBenchmark, protos, mesh,
+                 padded: int, device: bool = True):
         self.bench = bench
         self.protos = protos
+        self.mesh = mesh
+        self.padded = padded
         C, T = bench.n_clients, bench.n_tasks
         qshapes = {protos[(c, t)][2].shape for c in range(C) for t in range(T)}
         self.uniform = len(qshapes) == 1
@@ -159,15 +163,13 @@ class _EvalCache:
         self._host_gal: Dict[Tuple[int, int], Tuple] = {}
         self._dev_t: Optional[int] = None
         self._dev_gal = None
-        self._mesh = None
-        self._padded: Optional[int] = None
         if self.device_ready:
-            self.qp = jnp.asarray(np.stack(
-                [np.stack([protos[(c, t)][2] for t in range(T)])
-                 for c in range(C)]).astype(np.float32))        # (C, T, Q, D)
-            self.qids = jnp.asarray(np.stack(
-                [np.stack([protos[(c, t)][3] for t in range(T)])
-                 for c in range(C)]).astype(np.int32))          # (C, T, Q)
+            qp = np.stack([np.stack([protos[(c, t)][2] for t in range(T)])
+                           for c in range(C)]).astype(np.float32)
+            qids = np.stack([np.stack([protos[(c, t)][3] for t in range(T)])
+                             for c in range(C)]).astype(np.int32)
+            self.qp = self._place_rows(qp)                     # (Cp, T, Q, D)
+            self.qids = self._place_rows(qids)                 # (Cp, T, Q)
             self.g_max = sum(protos[k][2].shape[0]
                              for k in bench.gallery_members(0, T - 1))
             # static per-query match bound for the counting-based ranking,
@@ -176,34 +178,16 @@ class _EvalCache:
             from repro.evalreid.batched import max_match_bound
             self.max_matches = max(
                 max_match_bound(
-                    np.asarray(self.qids[c])[None],
+                    qids[c][None],
                     np.concatenate([protos[k][3] for k in
                                     bench.gallery_members(c, T - 1)])[None])
                 for c in range(C))
 
-    def place(self, mesh, padded: int):
-        """engine="sharded": pad every stacked eval input's client dim to
-        the mesh-padded Cp (edge-replicating the last client row — padding
-        rows are computed but never read back) and pin it to the client-row
-        sharding from ``sharding.specs``, so the one jitted eval program
-        runs SPMD with each device scoring its own client block."""
-        if not self.device_ready:
-            return
-        self._mesh, self._padded = mesh, padded
-        self.qp = self._place_rows(self.qp)
-        self.qids = self._place_rows(self.qids)
-        self._dev_t = None      # rebuild galleries padded + placed
-
-    def _place_rows(self, arr):
-        if self._mesh is None:
-            return arr
-        from repro.sharding import specs as shard_specs
-        pad = self._padded - arr.shape[0]
-        if pad:
-            arr = jnp.concatenate([arr] + [arr[-1:]] * pad)
-        sh = jax.sharding.NamedSharding(
-            self._mesh, shard_specs.client_row_spec(arr.ndim))
-        return jax.device_put(arr, sh)
+    def _place_rows(self, arr: np.ndarray):
+        """Upload a host (C, ...) stack padded to Cp rows, row-sharded."""
+        arr = pad_client_rows(arr, self.padded)
+        return jax.device_put(arr, jax.sharding.NamedSharding(
+            self.mesh, shard_specs.client_row_spec(arr.ndim)))
 
     def host_gallery(self, c: int, t: int):
         """(gallery prototypes, gallery ids) for client c at task t —
@@ -236,15 +220,15 @@ class _EvalCache:
                 gids[c, :len(y)] = y
                 gmask[c, :len(p)] = 1.0
             self._dev_t = t
-            self._dev_gal = tuple(
-                self._place_rows(jnp.asarray(a)) for a in (gp, gids, gmask))
+            self._dev_gal = tuple(self._place_rows(a)
+                                  for a in (gp, gids, gmask))
         return self._dev_gal
 
     def task_mask(self, t: int):
         C, T = self.bench.n_clients, self.bench.n_tasks
         m = np.zeros((C, T), np.float32)
         m[:, :t + 1] = 1.0
-        return self._place_rows(jnp.asarray(m))
+        return self._place_rows(m)
 
 
 def _round_summary(tracker, rnd):
@@ -272,16 +256,18 @@ def _eval_round(strategy, get_state, bench, cache, tracker, rnd, t):
     return _round_summary(tracker, rnd)
 
 
-def _eval_round_device(strategy, theta_stacked, cache, tracker, rnd, t):
+def _eval_round_device(theta_stacked, cache, tracker, rnd, t):
     """Device eval block: every (client, trained task) mAP/CMC in ONE jitted
-    program — vmapped feature heads over the stacked eval pytree, all
-    distance matrices through the kernels/pairwise_dist path, metric math
-    on device. Only the tiny (C, T, metrics) result is read back to feed
-    the lifelong tracker (the Eq. 8 forgetting bookkeeping)."""
+    program (``sharded_eval_fn`` on the cache's mesh) — vmapped feature
+    heads over the stacked eval pytree, all distance matrices through the
+    kernels/pairwise_dist path, metric math on device. Only the tiny
+    (C, T, metrics) result is read back to feed the lifelong tracker (the
+    Eq. 8 forgetting bookkeeping)."""
     gp, gids, gmask = cache.device_gallery(t)
-    out = strategy.eval_round_stacked(
-        theta_stacked, cache.qp, cache.qids, cache.task_mask(t),
-        gp, gids, gmask, ranks=EVAL_RANKS, max_matches=cache.max_matches)
+    fn = sharded_eval_fn(cache.mesh, ranks=EVAL_RANKS,
+                         max_matches=cache.max_matches)
+    out = fn(theta_stacked, cache.qp, cache.qids, cache.task_mask(t),
+             gp, gids, gmask)
     out = {k: np.asarray(v) for k, v in out.items()}
     for c in range(cache.bench.n_clients):
         for tt in range(t + 1):
@@ -350,27 +336,26 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
     comm = CommLog()
     eval_rounds: List[Dict[str, float]] = []
 
+    # the engine name picks the device list and nothing else: "sharded"
+    # spans every device, "stacked" (and the host oracle's batched eval)
+    # one. "Computation follows data": the stacked state and eval inputs
+    # are padded to Cp rows and placed row-sharded over "data", and every
+    # jitted round program runs SPMD over the mesh. Padding clients train
+    # on replicated data; their validity-mask zero keeps them out of the
+    # relevance ring (W rows/cols zero, nz False), and byte accounting /
+    # eval read back only [:C].
+    mesh = shard_specs.engine_mesh(
+        jax.devices() if engine == "sharded" else jax.devices()[:1])
+    lead = shard_specs.padded_clients(C, mesh)   # leading dim of payloads
     protos = _pre_extract_prototypes(bench, g_params)
-    cache = _EvalCache(bench, protos, device=eval_backend == "device")
+    cache = _EvalCache(bench, protos, mesh, lead,
+                       device=eval_backend == "device")
     # ragged benchmarks cannot be stacked — fall back to the host oracle
     eval_dev = cache.device_ready
 
-    if engine in ("stacked", "sharded"):
-        stacked = strategy.stack_states(states)
-        valid_mask = None
-        lead = C      # leading client dim of stacked payloads (Cp on a mesh)
-        if engine == "sharded":
-            # "computation follows data": build the engine mesh, pad + place
-            # the stacked state / eval inputs row-sharded over "data", and
-            # every existing jitted round program re-specializes into SPMD.
-            # Padding clients train on replicated data; their validity-mask
-            # zero keeps them out of the relevance ring (W rows/cols zero,
-            # nz False), and byte accounting / eval read back only [:C].
-            from repro.sharding import specs as shard_specs
-            mesh = shard_specs.engine_mesh()
-            stacked, valid_mask = strategy.shard_stacked_state(stacked, mesh)
-            lead = strategy.padded_clients
-            cache.place(mesh, lead)
+    if engine != "host":
+        stacked, valid_mask = strategy.shard_stacked_state(
+            strategy.stack_states(states), mesh)
         for rnd in range(rounds):
             t = min(rnd // rounds_per_task, T - 1)
             protos_list = [protos[(c, t)][0] for c in range(C)]
@@ -380,16 +365,15 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
                                                        labels_list)
                 with obs.span("gather.upload", cat="stage", round=rnd,
                               h2d_bytes=obs.device_nbytes(bx, by)) as sp:
-                    bx, by = sp.sync(strategy.place_batches(
-                        jnp.asarray(bx), jnp.asarray(by)))
+                    bx, by = sp.sync(strategy.place_batches(bx, by))
             with obs.span("round.local_train", cat="phase", round=rnd) as sp:
                 stacked, upload = strategy.local_train_stacked(
                     stacked, bx, by, protos_list, labels_list, rnd)
                 sp.sync(stacked.trainable)
             if upload is not None:
-                # per-client formula from the ACTUAL leading dim (Cp on a
-                # mesh), logged for the C real clients — so measured and
-                # formula bytes are engine-invariant at any device count
+                # per-client formula from the ACTUAL leading dim Cp,
+                # logged for the C real clients — so measured and formula
+                # bytes are invariant at any device count
                 formula = strategy.stacked_upload_bytes(upload, lead)
                 if strategy.upload_codec is not None:
                     # one batched device encode/decode for all C rows; the
@@ -404,7 +388,7 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
             if strategy.uses_server and upload is not None:
                 with obs.span("round.server", cat="phase", round=rnd) as sp:
                     dispatch = strategy.server_round_stacked(
-                        rnd, upload, valid=valid_mask)
+                        rnd, upload, valid_mask)
                     if dispatch is not None:
                         sp.sync(dispatch)   # dict shape is strategy-specific
                 if dispatch is not None:
@@ -447,8 +431,8 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
                 with obs.span("round.eval", cat="phase", round=rnd):
                     if eval_dev:
                         per_round = _eval_round_device(
-                            strategy, strategy.eval_theta_stacked(stacked),
-                            cache, tracker, rnd, t)
+                            strategy.eval_theta_stacked(stacked), cache,
+                            tracker, rnd, t)
                     else:
                         per_round = _eval_round(
                             strategy,
@@ -456,7 +440,7 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
                             bench, cache, tracker, rnd, t)
                 eval_rounds.append(per_round)
                 if verbose:
-                    print(f"  [{strategy.name}/stacked] round {rnd}: "
+                    print(f"  [{strategy.name}/{engine}] round {rnd}: "
                           f"mAP={per_round['mAP']:.4f} "
                           f"R1={per_round['R1']:.4f} "
                           f"F={per_round['forgetting_mAP']:.4f}")
@@ -514,8 +498,8 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
             with obs.span("round.eval", cat="phase", round=rnd):
                 if eval_dev:
                     per_round = _eval_round_device(
-                        strategy, strategy.stack_eval_thetas(states), cache,
-                        tracker, rnd, t)
+                        strategy.stack_eval_thetas(states), cache, tracker,
+                        rnd, t)
                 else:
                     per_round = _eval_round(strategy, lambda c: states[c],
                                             bench, cache, tracker, rnd, t)

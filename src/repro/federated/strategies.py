@@ -20,13 +20,14 @@ from repro.common.pytree import tree_bytes, tree_zeros_like
 from repro.core import edge_model as EM
 from repro.core.aggregation import fedavg_aggregate
 from repro.federated.base import ClientState, Strategy
+from repro.sharding import specs as shard_specs
 
 
 class FedAvg(Strategy):
     name = "fedavg"
     uses_server = True
-    # the uniform mean is trivially batchable: the stacked/sharded engines
-    # run it as one masked-mean device program over the (C|Cp, ...) rows
+    # the uniform mean is trivially batchable: the device engine runs it
+    # as one masked-mean device program over the (Cp, ...) rows
     supports_stacked = True
 
     def local_train(self, client, state, protos, labels, rnd, **_):
@@ -43,7 +44,7 @@ class FedAvg(Strategy):
         state.opt_state = None          # fresh optimizer on new global params
         return state
 
-    # ---- stacked / sharded engine -------------------------------------------
+    # ---- device engine ------------------------------------------------------
     def local_train_stacked(self, stacked, bx, by, protos_list, labels_list,
                             rnd):
         stacked, _ = super().local_train_stacked(stacked, bx, by,
@@ -51,14 +52,12 @@ class FedAvg(Strategy):
                                                  rnd)
         return stacked, {"theta": stacked.trainable}
 
-    def server_round_stacked(self, rnd, upload, valid=None):
+    def server_round_stacked(self, rnd, upload, valid):
         """The FedAvg mean as one device program over the stacked rows.
         ``valid`` masks mesh-padding rows out of both the numerator and
         the denominator, so the mean is over the C real clients exactly as
         on the host; every row (padding included) receives the broadcast
         mean, matching the host's uniform dispatch."""
-        theta = upload["theta"]
-        lead = jax.tree.leaves(theta)[0].shape[0]
         if "stacked_mean" not in self._jit_cache:
             @jax.jit
             def mean_fn(th, mask):
@@ -70,19 +69,15 @@ class FedAvg(Strategy):
                     return jnp.broadcast_to(mu, l.shape)
                 return jax.tree.map(m, th)
             self._jit_cache["stacked_mean"] = mean_fn
-        mask = (jnp.ones((lead,), jnp.float32) if valid is None
-                else jnp.asarray(valid, jnp.float32))
-        return {"theta": self._jit_cache["stacked_mean"](theta, mask)}
+        return {"theta": self._jit_cache["stacked_mean"](upload["theta"],
+                                                         valid)}
 
     def apply_dispatch_stacked(self, stacked, dispatch):
+        # keep the round-carried state client-row-sharded: the broadcast
+        # mean comes back replicated, so re-pin the engine layout
         theta = dispatch["theta"]
-        if self.mesh is not None:
-            # keep the round-carried state client-row-sharded: the broadcast
-            # mean comes back replicated, so re-pin the engine layout
-            from repro.sharding import specs as shard_specs
-            theta = jax.device_put(theta, shard_specs.named_shardings(
-                self.mesh, shard_specs.stacked_tree_specs(theta)))
-        stacked.trainable = theta
+        stacked.trainable = jax.device_put(theta, shard_specs.named_shardings(
+            self.mesh, shard_specs.stacked_tree_specs(theta)))
         # fresh optimizer on new global params (host: opt_state = None)
         stacked.opt_state = jax.vmap(self.opt.init)(stacked.trainable)
         return stacked

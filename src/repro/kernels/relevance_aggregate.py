@@ -88,7 +88,7 @@ def fused_relevance_aggregate(w, thetas, row0=0, *, p_block: int = P_BLOCK,
     diagonal masking, row normalization (zero-row safe), and B = Wn @ Θ.
 
     w: (R, C) raw decayed relevance, rows row0..row0+R-1 of the (C, C)
-    matrix (R = C, row0 = 0 for the whole matrix; the sharded engine
+    matrix (R = C, row0 = 0 for the whole matrix; on a mesh the engine
     passes each device's row block; diagonal ignored); thetas: (C, P).
     Returns (B: (R, P), Wn: (R, C) fp32 normalized relevance). Each row's
     result does not depend on which block it rides in.

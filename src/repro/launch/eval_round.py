@@ -4,8 +4,8 @@ The device-resident eval program (``federated.base.stacked_eval_program``:
 vmapped feature heads → all distance matrices → mAP/CMC on device) is
 embarrassingly parallel over clients: every input carries a leading C dim
 and no stage contracts it. The one sharded implementation is
-``federated.base.sharded_eval_fn`` — the engine path that
-``run_simulation(engine="sharded")`` uses — which runs the program per
+``federated.base.sharded_eval_fn`` — the path ``run_simulation``'s
+device eval uses on every engine — which runs the program per
 shard inside ``shard_map``: inputs are placed with
 ``sharding.specs.stacked_eval_specs`` client-row shardings, each device
 scores one block of clients (the distance kernel sees local blocks) and

@@ -131,7 +131,7 @@ def tree_param_specs(cfg: ModelConfig, tree, **kw):
 
 
 # ---------------------------------------------------------------------------
-# federated engine mesh: the layout source of truth for engine="sharded"
+# federated engine mesh: the layout source of truth for the device engine
 # ---------------------------------------------------------------------------
 #
 # Axis names are fixed repo-wide: "data" shards the client dim (every
@@ -158,7 +158,7 @@ def auto_mesh(shape, names, devices=None):
 def engine_mesh(devices=None, *, model: int = 1):
     """The engine's Mesh(("data", "model")): all devices on the client
     axis by default. ``run_simulation(engine="sharded")`` builds exactly
-    this; tests/benches pass an explicit device list to shrink it."""
+    this, ``engine="stacked"`` passes ``jax.devices()[:1]``."""
     if devices is None:
         devices = jax.devices()
     n = len(devices)

@@ -16,6 +16,7 @@ from repro.core import FedSTIL
 from repro.core.edge_model import EdgeModelConfig
 from repro.data import FederatedReIDBenchmark
 from repro.federated import FedAvg, run_simulation
+from repro.sharding.specs import engine_mesh
 
 
 def _tree(rng, scale=1.0):
@@ -153,7 +154,8 @@ def test_host_vs_batched_parity(spec):
     rng = np.random.default_rng(6)
     C, P = 4, 999
     host = make_codec(spec)
-    batched = BatchedCodec(make_codec(spec), P)
+    batched = BatchedCodec(make_codec(spec), P,
+                           engine_mesh(jax.devices()[:1]))
     for r in range(3):
         mat = rng.standard_normal((C, P)).astype(np.float32) * (1 + r)
         buffers = batched.encode(jnp.asarray(mat))
@@ -168,7 +170,8 @@ def test_host_vs_batched_parity(spec):
 
 def test_batched_rejects_global_topk():
     with pytest.raises(ValueError):
-        BatchedCodec(make_codec("topk", k=10), 100)
+        BatchedCodec(make_codec("topk", k=10), 100,
+                     engine_mesh(jax.devices()[:1]))
 
 
 # ---- accounting: measured vs formula ---------------------------------------

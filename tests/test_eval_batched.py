@@ -135,7 +135,7 @@ def test_gallery_prototype_cache_matches_extraction():
     cfg = EdgeModelConfig(n_classes=bench.n_classes)
     g_params = EM.init_extraction(jax.random.PRNGKey(0), cfg)
     protos = _pre_extract_prototypes(bench, g_params)
-    cache = _EvalCache(bench, protos)
+    cache = _EvalCache(bench, protos, engine_mesh(jax.devices()[:1]), 3)
     for c in range(3):
         gal_x, gal_y = bench.gallery(c, 1)
         gal_p = np.asarray(EM.extract_prototypes(g_params, gal_x))

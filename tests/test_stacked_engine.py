@@ -18,7 +18,7 @@ from repro.core import FedSTIL
 from repro.core.aggregation import personalized_aggregate
 from repro.core.edge_model import EdgeModelConfig
 from repro.core.relevance import (DeviceRingHistory, RelevanceTracker,
-                                  normalize_rows)
+                                  normalize_rows, ring_relevance)
 from repro.data import FederatedReIDBenchmark
 from repro.federated import run_simulation
 from repro.kernels import ops
@@ -58,7 +58,9 @@ def test_ring_empty_and_never_pushed_rows():
     ring.push_all(feats, np.array([1.0, 0.0, 0.0], np.float32))
     valid = np.asarray(ring.valid)
     assert valid[0, 0] == 1.0 and (valid[1:] == 0).all()
-    W = np.asarray(ring.raw_relevance(forgetting_ratio=0.5))
+    W = np.asarray(ring_relevance(ring.buf, ring.valid,
+                                  mesh=engine_mesh(jax.devices()[:1]),
+                                  forgetting_ratio=0.5))
     assert (W[1:] == 0).all()          # rows without a current feature
 
 
@@ -247,13 +249,13 @@ def test_fused_aggregate_row_block_matches_full():
 
 
 def test_sharded_fused_aggregate_matches_kernel():
-    from repro.core.fedstil import sharded_fused_aggregate
+    from repro.core.fedstil import sharded_aggregate_fn
 
     mesh = engine_mesh(jax.devices()[:1])
     rng = np.random.default_rng(9)
     w = jnp.asarray(rng.random((8, 8)).astype(np.float32))
     thetas = jnp.asarray(rng.standard_normal((8, 512)).astype(np.float32))
-    B, Wn = sharded_fused_aggregate(w, thetas, mesh)
+    B, Wn = sharded_aggregate_fn(mesh)(w, thetas)
     B_ref, Wn_ref = ops.fused_relevance_aggregate(w, thetas, backend="ref")
     np.testing.assert_allclose(np.asarray(Wn), np.asarray(Wn_ref), atol=1e-6)
     np.testing.assert_allclose(np.asarray(B), np.asarray(B_ref), atol=1e-5)
