@@ -137,6 +137,20 @@ def _register_fedstil() -> None:
         oracle="repro.core.rehearsal.PrototypeMemory.add_task",
         budget_bytes=64 << 20)
 
+    # the round's minibatch gather at the fleet cell's sizes: each
+    # client's task rows and its task-row draws, its rehearsal pool and
+    # its pool-row draws, in one tuple
+    register_runtime(
+        "federated.round_gather", strat._gather_fn(),
+        abstract_args=lambda: (((
+            _SDS((_C, n_task, D), _F32), _SDS((_C, n_task), _I32),
+            _SDS((_C, epochs, batch), _I32), _SDS((_C, batch, D), _F32),
+            _SDS((_C, batch), _I32),
+            _SDS((_C, epochs, batch // 2), _I32)),), {}),
+        module="repro.federated.base",
+        oracle="repro.federated.base.Strategy._run_epochs",
+        budget_bytes=64 << 20)
+
     # the flatten stage rides along so the full staged-jit server structure
     # (see the ROADMAP note on why it is NOT one mega-jit) stays under
     # analysis

@@ -13,12 +13,12 @@ log measures those payloads, reproducing the paper's S2C/C2S accounting.
 Strategies that set ``supports_stacked = True`` additionally implement the
 *stacked* engine API: all C clients' trainable pytrees, optimizer states,
 and loss extras live as ONE pytree whose leaves carry a leading (C, ...)
-dim (``StackedClientState``), per-client minibatches are pre-gathered on
-host into (C, epochs, B, D) arrays (drawing from ``self.rng`` in exactly
-the per-client order the host path uses, so both engines see identical
-batches), and local training for all C clients runs as a single
-``jax.vmap``-over-clients of a ``lax.scan`` over epochs — one jit dispatch
-per round instead of C×epochs.
+dim (``StackedClientState``), per-client minibatches are gathered on the
+device into (C, epochs, B, D) arrays from indices the host draws (from
+``self.rng`` in exactly the per-client order the host path uses, so both
+engines see identical batches), and local training for all C clients
+runs as a single ``jax.vmap``-over-clients of a ``lax.scan`` over epochs
+— one jit dispatch per round instead of C×epochs.
 """
 from __future__ import annotations
 
@@ -160,6 +160,36 @@ def pad_client_rows(tree, n_to: int):
     return jax.tree.map(pad, tree)
 
 
+def _stack_rows(arrays) -> np.ndarray:
+    """Stack per-client (N_c, ...) host arrays into one (C, max N_c, ...)
+    array; rows past a client's own N_c are zero."""
+    n = max(len(a) for a in arrays)
+    out = np.zeros((len(arrays), n) + np.shape(arrays[0])[1:],
+                   np.asarray(arrays[0]).dtype)
+    for c, a in enumerate(arrays):
+        out[c, :len(a)] = a
+    return out
+
+
+def gather_batches(rows, labels, idx, pool_rows=None, pool_labels=None,
+                   ridx=None):
+    """Every client's epoch minibatches, gathered by index.
+
+    rows (C, N, D) / labels (C, N): each client's task rows; idx
+    (C, E, b): each epoch's task-row draw. With a rehearsal pool,
+    pool_rows (C, P, D) / pool_labels (C, P) and ridx (C, E, r) add r
+    pool rows after each epoch's b task rows. Returns (C, E, b + r, D)
+    rows and (C, E, b + r) labels: what indexing each client's host
+    arrays with its draws and concatenating gives, bit for bit.
+    """
+    take = jax.vmap(lambda a, i: a[i])
+    bx, by = take(rows, idx), take(labels, idx)
+    if ridx is not None:
+        bx = jnp.concatenate([bx, take(pool_rows, ridx)], axis=2)
+        by = jnp.concatenate([by, take(pool_labels, ridx)], axis=2)
+    return bx, by
+
+
 class Strategy:
     """Base: plain local training (STL)."""
 
@@ -194,6 +224,9 @@ class Strategy:
         # the device engine's mesh, set by shard_stacked_state
         self.mesh = None
         self.padded_clients: Optional[int] = None
+        # the task rows the device engine's gather reads: (the host arrays
+        # they came from, their (Cp, N, ...) device copies)
+        self._held_task: Optional[Tuple[List[Any], Any]] = None
 
     # ---- default loss: CE on adaptive layers --------------------------------
     def make_theta(self, trainable, extras):
@@ -395,11 +428,12 @@ class Strategy:
         return tree_bytes(state.theta)
 
     # ---- stacked (device-resident) engine API --------------------------------
-    # One round = gather_round_batches (host rng, same draw order as the
-    # host engine) -> local_train_stacked (single jit: vmap over clients of
-    # a scan over epochs) -> server_round_stacked / apply_dispatch_stacked
-    # (device-resident server program). ``client_view`` materialises one
-    # client's slice for evaluation / storage accounting.
+    # One round = gather_round_batches (host rng draws, same order as the
+    # host engine; one device gather) -> local_train_stacked (single jit:
+    # vmap over clients of a scan over epochs) -> server_round_stacked /
+    # apply_dispatch_stacked (device-resident server program).
+    # ``client_view`` materialises one client's slice for evaluation /
+    # storage accounting.
 
     def stack_states(self, states: Dict[int, "ClientState"]) -> StackedClientState:
         """Stack C per-client states into one (C, ...) pytree. Array-valued
@@ -443,44 +477,76 @@ class Strategy:
 
     def gather_round_batches(self, stacked: StackedClientState,
                              protos_list, labels_list):
-        """Pre-gather every client's epoch minibatches as dense host
-        arrays: (C, epochs, B, D) prototypes + (C, epochs, B) labels (the
-        round loop uploads them).
+        """Every client's epoch minibatches for this round, on the device:
+        (Cp, epochs, rows, D) prototypes + (Cp, epochs, rows) labels in
+        the engine mesh's row sharding, where rows = min(batch, N) task
+        rows, then ``batch // 2`` rehearsal rows when the clients hold a
+        pool.
 
-        Draws from ``self.rng`` in the host engine's exact order (client-
-        major, then epoch; rehearsal pool first, then per-epoch batch and
-        rehearsal indices) so both engines train on identical batches.
+        The host draws only indices, from ``self.rng`` in the host
+        engine's exact order (client-major, then epoch; rehearsal pool
+        first, then per-epoch batch and rehearsal indices), so both
+        engines train on identical batches. One device program
+        (``gather_batches``) gathers the rows: the task's rows stay on the
+        device until ``protos_list``/``labels_list`` hold other arrays,
+        and each round uploads the indices and the rehearsal pools.
         """
-        C = len(protos_list)
-        rows = C * self.epochs * (min(self.batch, len(protos_list[0]))
-                                  + self._rehearsal_rows(stacked))
+        C, E, B = len(protos_list), self.epochs, self.batch
+        sizes = [len(p) for p in protos_list]
+        b = min(B, sizes[0])
+        rows = C * E * (b + self._rehearsal_rows(stacked))
         with obs.span("gather.sample", cat="stage", rows=rows):
-            bxs, bys = [], []
-            for c in range(C):
-                p, l = protos_list[c], labels_list[c]
-                n = len(p)
-                reh = self._gather_rehearsal(stacked, c)
-                ex, ey = [], []
-                for _ in range(self.epochs):
-                    idx = self.rng.choice(n, size=min(self.batch, n),
-                                          replace=n < self.batch)
-                    px, py = p[idx], l[idx]
-                    if reh is not None:
-                        rx, ry = reh
-                        ridx = self.rng.choice(len(rx), size=self.batch // 2,
-                                               replace=True)
-                        px = np.concatenate([px, rx[ridx]])
-                        py = np.concatenate([py, ry[ridx]])
-                    ex.append(px)
-                    ey.append(py)
-                bxs.append(np.stack(ex))
-                bys.append(np.stack(ey))
-            shapes = {b.shape for b in bxs}
-            if len(shapes) > 1:
+            if any(min(B, n) != b for n in sizes):
                 raise ValueError(
                     f"device engine needs uniform per-client batch shapes, "
-                    f"got {sorted(shapes)} (ragged tasks/rehearsal pools)")
-            return np.stack(bxs), np.stack(bys)
+                    f"got task batches of {sorted({min(B, n) for n in sizes})}"
+                    f" rows (ragged tasks)")
+            idx = np.empty((C, E, b), np.int32)
+            ridx = np.empty((C, E, B // 2), np.int32)
+            pools = []
+            for c, n in enumerate(sizes):
+                pool = self._gather_rehearsal(stacked, c)
+                pools.append(pool)
+                for e in range(E):
+                    idx[c, e] = self.rng.choice(n, size=b, replace=n < B)
+                    if pool is not None:
+                        # choice(len, size, replace=True) draws exactly these
+                        ridx[c, e] = self.rng.integers(0, len(pool[0]),
+                                                       size=B // 2)
+            with_pool = [p is not None for p in pools]
+            if any(with_pool) != all(with_pool):
+                raise ValueError(
+                    "device engine needs uniform per-client batch shapes: "
+                    "some clients have a rehearsal pool and some do not "
+                    "(ragged rehearsal pools)")
+            sent = ((idx, _stack_rows([p[0] for p in pools]),
+                     _stack_rows([p[1] for p in pools]), ridx)
+                    if all(with_pool) else (idx,))
+        held = self._held_task
+        resident = held is not None and len(held[0]) == 2 * C and all(
+            a is h for a, h in zip(list(protos_list) + list(labels_list),
+                                   held[0]))
+        task = () if resident else (_stack_rows(protos_list),
+                                    _stack_rows(labels_list))
+        with obs.span("gather.upload", cat="stage",
+                      h2d_bytes=obs.device_nbytes(*sent, *task),
+                      task_resident=int(resident)) as sp:
+            if not resident:
+                self._held_task = (list(protos_list) + list(labels_list),
+                                   self._place_rows(task))
+            sent = sp.sync(self._place_rows(sent))
+        with obs.span("gather.device", cat="stage", rows=rows) as sp:
+            return sp.sync(self._gather_fn()((*self._held_task[1], *sent)))
+
+    def _gather_fn(self):
+        """``gather_batches`` per shard of the engine mesh: each client's
+        rows, indices and pool live on its own shard, so no collective."""
+        if "gather" not in self._jit_cache:
+            rows = shard_specs.client_row_spec(1)
+            self._jit_cache["gather"] = jax.jit(compat.shard_map(
+                lambda arrays: gather_batches(*arrays), mesh=self.mesh,
+                in_specs=(rows,), out_specs=rows, check_vma=False))
+        return self._jit_cache["gather"]
 
     def _stacked_loss_extras(self, stacked: StackedClientState):
         ex = {k: v for k, v in stacked.extras.items() if k.startswith("reg_")}
@@ -557,27 +623,28 @@ class Strategy:
         Cp = shard_specs.padded_clients(C, mesh)
         self.mesh = mesh
         self.padded_clients = Cp
-
-        def place(tree):
-            padded = pad_client_rows(tree, Cp)
-            sh = shard_specs.named_shardings(
-                mesh, shard_specs.stacked_tree_specs(padded))
-            return jax.device_put(padded, sh)
-
-        stacked.trainable = place(stacked.trainable)
-        stacked.opt_state = place(stacked.opt_state)
-        stacked.extras = {k: place(v) for k, v in stacked.extras.items()}
+        self._held_task = None
+        stacked.trainable = self._place_rows(stacked.trainable)
+        stacked.opt_state = self._place_rows(stacked.opt_state)
+        stacked.extras = {k: self._place_rows(v)
+                          for k, v in stacked.extras.items()}
         valid = (np.arange(Cp) < C).astype(np.float32)
         valid = jax.device_put(valid, jax.sharding.NamedSharding(
             mesh, shard_specs.client_row_spec(1)))
         return stacked, valid
 
     def place_batches(self, bx, by):
-        """Pad this round's host (C, epochs, B, ...) minibatch stacks to Cp
-        rows and upload them straight into their row sharding."""
-        batches = pad_client_rows((bx, by), self.padded_clients)
-        return jax.device_put(batches, shard_specs.named_shardings(
-            self.mesh, shard_specs.stacked_tree_specs(batches)))
+        """Pad this round's (C, epochs, B, ...) minibatch stacks to Cp rows
+        in their row sharding; arrays already placed there stay as they
+        are."""
+        return self._place_rows((bx, by))
+
+    def _place_rows(self, tree):
+        """Pad every (C, ...) leaf of ``tree`` to Cp rows and put it in
+        the engine mesh's row sharding."""
+        padded = pad_client_rows(tree, self.padded_clients)
+        return jax.device_put(padded, shard_specs.named_shardings(
+            self.mesh, shard_specs.stacked_tree_specs(padded)))
 
     def stacked_upload_bytes(self, upload, n_clients: int) -> int:
         """Per-client C2S bytes (stacked leaves carry C copies)."""

@@ -26,15 +26,16 @@ Two engines drive the rounds:
   * the device engine, for strategies that set ``supports_stacked``
     (FedSTIL, STL, FedAvg/FedProx): all C client states live as one
     stacked (C, ...) pytree on a ``Mesh(("data", "model"))``, per-client
-    minibatches are pre-gathered into (C, epochs, B, D) arrays (same rng
-    draw order as the host engine, so both engines train on identical
-    batches), local training for all C clients is a single
-    vmap-over-clients of a scan-over-epochs, and the FedSTIL server round
-    runs as staged device programs over a resident (C, k, D) relevance
-    ring buffer. State, batches, the ring and all eval inputs are placed
-    row-sharded over "data" (``sharding/specs.py`` is the layout source
-    of truth; C is padded to a multiple of the device count, padding rows
-    masked out of the relevance ring), and the programs that call Pallas
+    minibatches are gathered on the device into (C, epochs, B, D) arrays
+    from indices the host draws (same rng draw order as the host engine,
+    so both engines train on identical batches), local training for all
+    C clients is a single vmap-over-clients of a scan-over-epochs, and
+    the FedSTIL server round runs as staged device programs over a
+    resident (C, k, D) relevance ring buffer. State, batches, the ring
+    and all eval inputs are placed row-sharded over "data"
+    (``sharding/specs.py`` is the layout source of truth; C is padded to
+    a multiple of the device count, padding rows masked out of the
+    relevance ring), and the programs that call Pallas
     kernels (relevance, aggregate, codec, eval) run them per shard inside
     ``shard_map``. The engine name picks the device list and nothing
     else: ``engine="stacked"`` builds the mesh on one device,
@@ -361,11 +362,8 @@ def _run_simulation(strategy: Strategy, bench: FederatedReIDBenchmark,
             protos_list = [protos[(c, t)][0] for c in range(C)]
             labels_list = [protos[(c, t)][1] for c in range(C)]
             with obs.span("round.gather", cat="phase", round=rnd):
-                bx, by = strategy.gather_round_batches(stacked, protos_list,
-                                                       labels_list)
-                with obs.span("gather.upload", cat="stage", round=rnd,
-                              h2d_bytes=obs.device_nbytes(bx, by)) as sp:
-                    bx, by = sp.sync(strategy.place_batches(bx, by))
+                bx, by = strategy.place_batches(*strategy.gather_round_batches(
+                    stacked, protos_list, labels_list))
             with obs.span("round.local_train", cat="phase", round=rnd) as sp:
                 stacked, upload = strategy.local_train_stacked(
                     stacked, bx, by, protos_list, labels_list, rnd)

@@ -24,7 +24,7 @@ PER_IDENTITY = 2      # below the rows per identity: selection drops rows
 
 # parent -> children recorded once under each instance of the parent
 CHILDREN = {
-    "round.gather": {"gather.sample", "gather.upload"},
+    "round.gather": {"gather.sample", "gather.upload", "gather.device"},
     "round.local_train": {"local.train", "local.forward", "local.rehearsal",
                           "local.task_feature"},
     "round.encode": {"comm.flatten", "comm.unflatten"},
@@ -115,20 +115,35 @@ def test_declared_bytes_equal_the_arrays_moved(traced, bench):
         # round 0 has no rehearsal pool yet; later rounds add B // 2 rows
         return min(BATCH, N) + (BATCH // 2 if r else 0)
 
+    # the kept rows: every row of an identity with at most per_identity
+    kept = [np.minimum(np.unique(bench.task(c, 0).train_y,
+                                 return_counts=True)[1], PER_IDENTITY).sum()
+            for c in range(C)]
+    pool = max(min(BATCH, k) for k in kept)   # rehearsal rows drawn from
+
+    def gathered(r):
+        # task-row indices every round; the task's rows and labels on its
+        # first round; from round 1 the pools' rows and labels and the
+        # pool-row indices
+        sent = C * EPOCHS * min(BATCH, N)
+        if r == 0:
+            sent += C * N * (D + 1)
+        else:
+            sent += C * pool * (D + 1) + C * EPOCHS * (BATCH // 2)
+        return sent * 4
+
     for e in named("gather.upload"):
-        assert e["h2d_bytes"] == C * EPOCHS * rows(rnd[e["id"]]) * (D + 1) * 4
-    for e in named("gather.sample"):
-        assert e["rows"] == C * EPOCHS * rows(rnd[e["id"]])
+        assert e["h2d_bytes"] == gathered(rnd[e["id"]])
+        assert e["task_resident"] == int(rnd[e["id"]] > 0)
+    for name in ("gather.sample", "gather.device"):
+        for e in named(name):
+            assert e["rows"] == C * EPOCHS * rows(rnd[e["id"]])
     # prototypes and labels up, each client's exemplar order and count back
     for e in named("local.forward"):
         assert e["h2d_bytes"] == C * N * (D + 1) * 4
         assert e["d2h_bytes"] == C * (N + 1) * 4
-    # the kept rows: every row of an identity with at most per_identity
-    kept = sum(np.minimum(np.unique(bench.task(c, 0).train_y,
-                                    return_counts=True)[1], PER_IDENTITY).sum()
-               for c in range(C))
     for e in named("local.rehearsal"):
-        assert e["rows"] == kept
+        assert e["rows"] == sum(kept)
     for e in named("local.task_feature"):
         assert e["h2d_bytes"] == C * D * 4
     for e in named("server.readback"):
@@ -138,8 +153,7 @@ def test_declared_bytes_equal_the_arrays_moved(traced, bench):
             assert e["d2h_bytes"] == C * C * 4    # last_W: (C, C) f32
     moved = sum(e.get("h2d_bytes", 0) + e.get("d2h_bytes", 0)
                 for e in spans if rnd[e["id"]] == 1)
-    assert moved == (C * EPOCHS * rows(1) * (D + 1) * 4
-                     + C * N * (D + 1) * 4 + C * (N + 1) * 4
+    assert moved == (gathered(1) + C * N * (D + 1) * 4 + C * (N + 1) * 4
                      + C * D * 4 + C * C * 4 + C)
 
 

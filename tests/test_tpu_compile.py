@@ -178,3 +178,30 @@ def test_exemplar_selection_compiles_for_v5e(one_chip):
         _sds(one_chip, (C, n), jnp.int32)).compile()
     assert compiled.as_text().count(" sort(") == 3
     assert compiled.memory_analysis().output_size_in_bytes < C * (n + 1) * 8
+
+
+def test_round_gather_compiles_for_v5e(topo):
+    """The stacked engine's round gather at the fleet's size (100 clients
+    x 144 task rows, 5 epochs of 64 task + 32 pool rows) on a one-chip
+    mesh: every client's rows, draws and pool sit on its own shard, so
+    the program holds no collective."""
+    from repro.core import FedSTIL
+    from repro.core.edge_model import EdgeModelConfig
+    from repro.sharding import specs
+
+    cfg, n, E, B = EdgeModelConfig(), 144, 5, 64
+    D, i32 = cfg.proto_dim, jnp.int32
+    strat = FedSTIL(cfg, n_clients=C, epochs=E, batch=B)
+    strat.mesh = specs.engine_mesh(topo.devices[:1])
+    rows = NamedSharding(strat.mesh, specs.client_row_spec(1))
+    compiled = strat._gather_fn().lower((
+        _sds(rows, (C, n, D)), _sds(rows, (C, n), i32),
+        _sds(rows, (C, E, B), i32), _sds(rows, (C, B, D)),
+        _sds(rows, (C, B), i32), _sds(rows, (C, E, B // 2), i32))).compile()
+    text = compiled.as_text()
+    for op in ("all-gather", "all-reduce", "all-to-all",
+               "collective-permute"):
+        assert op not in text, op
+    assert " gather(" in text
+    out = C * E * (B + B // 2) * (D + 1) * 4
+    assert compiled.memory_analysis().output_size_in_bytes >= out
